@@ -7,9 +7,7 @@ builders may lay out vtables differently).
 """
 
 import random
-import subprocess
 import uuid
-from pathlib import Path
 
 import pytest
 
@@ -28,16 +26,12 @@ from worldql_server_tpu.protocol.types import (
     Vector3,
 )
 
-ROOT = Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(scope="module")
-def native() -> NativeCodec:
-    lib = ROOT / "native" / "libwqlcodec.so"
-    if not lib.exists():
-        subprocess.run(["make", "-C", str(ROOT / "native")], check=True)
+def native(native_lib) -> NativeCodec:
     n = load()
-    assert n is not None, "native codec failed to build/load"
+    assert n is not None, "native codec failed to load"
     return n
 
 

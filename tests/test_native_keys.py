@@ -6,8 +6,6 @@ divergence — especially on the golden quantizer's edge cases — would
 silently mis-route messages. Bit-exact agreement is the contract.
 """
 
-import subprocess
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -15,22 +13,13 @@ import pytest
 from worldql_server_tpu.spatial import native_keys
 from worldql_server_tpu.spatial.native_keys import numpy_query_keys
 
-ROOT = Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(scope="module")
-def native():
-    # always make (idempotent): the .so is gitignored, and a stale
-    # build from before spatial.cpp existed lacks the symbol
-    subprocess.run(["make", "-C", str(ROOT / "native")], check=True)
+def native(native_lib):
     n = native_keys.load()
-    assert n is not None, "native key kernel failed to build/load"
-    # module-level _native resolved at import, possibly before the lib
-    # existed — point the dispatch path at the fresh load for the test
-    old = native_keys._native
-    native_keys._native = n
-    yield n
-    native_keys._native = old
+    assert n is not None, "native key kernel failed to load"
+    return n
 
 
 EDGE_COORDS = [

@@ -15,8 +15,6 @@ Three pins:
 
 from __future__ import annotations
 
-import subprocess
-from pathlib import Path
 
 import pytest
 
@@ -30,7 +28,6 @@ from wire_fixtures import (
 GOOD = sorted(set(CASES) - BAD_CASES)
 BAD = sorted(BAD_CASES)
 
-ROOT = Path(__file__).resolve().parent.parent
 
 
 def fixture_bytes(name: str) -> bytes:
@@ -69,12 +66,9 @@ def test_python_codec_rejects_contract_violations(name):
 
 
 @pytest.fixture(scope="module")
-def native():
-    lib = ROOT / "native" / "libwqlcodec.so"
-    if not lib.exists():
-        subprocess.run(["make", "-C", str(ROOT / "native")], check=True)
+def native(native_lib):
     n = load()
-    assert n is not None, "native codec failed to build/load"
+    assert n is not None, "native codec failed to load"
     return n
 
 
