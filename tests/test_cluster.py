@@ -22,8 +22,7 @@ shard SERVER subprocesses (``python -m worldql_server_tpu
   behind the dispatch instead of serializing in front of it.
 
 No device mesh is involved anywhere: shards run the CPU backend, so
-this suite runs (rather than skips) on the jax-0.4.37 container whose
-CPU backend refuses multi-process collectives.
+this suite needs no multi-process collectives.
 """
 
 import asyncio

@@ -779,6 +779,16 @@ class WorldQLServer:
                 self.supervisor.spawn("checkpoint", self._checkpoint_loop)
         self._restore_index_snapshot()
         self._precompile_tiers()
+        if hasattr(self.backend, "device_stats"):
+            # once, loudly: `--spatial-backend tpu` on a chip-less host
+            # serves the "device" engine from the CPU platform
+            stats = self.backend.device_stats()
+            logger.info(
+                "spatial index on platform=%s device_kind=%s "
+                "device_count=%s (%s subscriptions)",
+                stats.get("platform"), stats.get("device_kind"),
+                stats.get("device_count"), stats.get("subscriptions"),
+            )
 
         if self.loop_monitor is not None:
             # loop-health probe: supervised (a dead probe restarts, and

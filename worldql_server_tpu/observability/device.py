@@ -45,7 +45,7 @@ from ..utils.retrace import GUARD
 
 logger = logging.getLogger(__name__)
 
-#: the backend-compile duration event jax 0.4.x emits once per XLA
+#: the backend-compile duration event jax emits once per XLA
 #: compilation (jaxpr tracing / MLIR lowering emit their own events —
 #: the backend compile is the expensive leg and the one-per-variant
 #: signal the retrace accounting wants)

@@ -59,9 +59,7 @@ from ..spatial.tpu_backend import (
     run_remainders_np,
 )
 
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pre-0.4.38 releases: not yet graduated
-    from jax.experimental.shard_map import shard_map as _shard_map
+_shard_map = jax.shard_map
 
 
 def split_at_run_boundaries(keys: np.ndarray, n_shards: int) -> list[int]:
@@ -651,7 +649,25 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
 
     # endregion
 
+    def _index_devices(self) -> list:
+        if self._base_bundle is None:
+            return sorted(self.mesh.devices.flat, key=lambda d: d.id)
+        return super()._index_devices()
+
     def device_stats(self) -> dict:
         stats = super().device_stats()
         stats["mesh"] = {"batch": self.n_batch, "space": self.n_space}
+        # bytes of the space-sharded base each device actually holds —
+        # a mesh that put everything on its first device shows here
+        per_device: dict[int, int] = {}
+        if self._base_bundle is not None:
+            for arr in self._base_bundle["dev"]:
+                for shard in arr.addressable_shards:
+                    per_device[shard.device.id] = (
+                        per_device.get(shard.device.id, 0)
+                        + shard.data.nbytes
+                    )
+        stats["base_bytes_per_device"] = {
+            str(d): per_device[d] for d in sorted(per_device)
+        }
         return stats

@@ -3044,8 +3044,23 @@ class TpuSpatialBackend(SpatialBackend):
         ]).astype(np.int64)
         return list(self._world_ids), self._peer_list, wid, cube, pid
 
+    def _index_devices(self) -> list:
+        """The devices the index arrays live on (where an empty index's
+        first upload will land, before there is one)."""
+        for bundle in (self._base_bundle, self._delta_bundle):
+            if bundle is not None:
+                return sorted(bundle["dev"][0].devices(), key=lambda d: d.id)
+        return jax.local_devices()[:1]
+
     def device_stats(self) -> dict:
+        devices = self._index_devices()
         return {
+            # what actually holds the index: `--spatial-backend tpu` on
+            # a chip-less host serves from the CPU platform, and this
+            # is where that shows
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
             "subscriptions": self.subscription_count(),
             "capacity": (
                 (0 if self._base_bundle is None else self._base_bundle["cap"])
