@@ -22,6 +22,7 @@ COPY worldql_server_tpu ./worldql_server_tpu
 # Native wire codec (pure-Python fallback exists, but ship the fast path)
 RUN make -C native
 
+# dependency versions: pyproject.toml states the stack (jax 0.9.0) once
 RUN pip install --no-cache-dir --prefix=/install .
 
 # ---

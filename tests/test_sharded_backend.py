@@ -89,6 +89,29 @@ def test_sharded_mutation_then_requery():
     assert stats["mesh"] == {"batch": 2, "space": 4}
 
 
+@pytest.mark.parametrize("loaded", [False, True], ids=["empty", "loaded"])
+def test_sharded_device_stats_show_every_device_holding_a_shard(loaded):
+    _require_devices(8)
+    # a low compaction threshold folds the bulk load into the base
+    b = ShardedTpuSpatialBackend(16, make_fanout_mesh(2, 4),
+                                 compact_threshold=16)
+    if loaded:
+        rng = random.Random(3)
+        b.bulk_add_subscriptions(
+            W, [uuid.uuid4() for _ in range(4096)],
+            [(rng.randrange(-64, 64), rng.randrange(-64, 64),
+              rng.randrange(-64, 64)) for _ in range(4096)],
+        )
+        b.flush()
+    stats = b.device_stats()
+    assert (stats["platform"], stats["device_count"]) == ("cpu", 8)
+    per_device = stats["base_bytes_per_device"]
+    if loaded:
+        assert len(per_device) == 8 and len(set(per_device.values())) == 1
+    else:
+        assert per_device == {}
+
+
 def test_non_pow2_batch_axis():
     """Batch padding must stay divisible by a non-power-of-two batch
     axis (regression: device_put raised on cap=8, n_batch=3)."""

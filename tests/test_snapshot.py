@@ -73,6 +73,29 @@ def test_snapshot_roundtrip(tmp_path, make):
     assert_equivalent(src, dst, peers, pos, worlds)
 
 
+@pytest.mark.parametrize("make", [
+    lambda: CpuSpatialBackend(16),
+    lambda: TpuSpatialBackend(16),
+], ids=["cpu", "tpu"])
+def test_snapshot_drop_peers_equals_evicting_them_first(tmp_path, make):
+    """Shutdown drops never-reconnected peers from the EXPORT; the file
+    must be what evicting them from the index and saving would give."""
+    src = make()
+    peers, pos, worlds = populate(src)
+    ghosts = peers[::3]
+    path = str(tmp_path / "dropped.npz")
+    saved = save_snapshot(src, path, drop_peers=ghosts)
+
+    for peer in ghosts:
+        src.remove_peer(peer)
+    assert saved == src.subscription_count()
+    dst = make()
+    restored, restored_peers = load_snapshot(dst, path)
+    assert restored == saved
+    assert not set(restored_peers) & set(ghosts)
+    assert_equivalent(src, dst, peers, pos, worlds)
+
+
 def test_snapshot_cross_backend(tmp_path):
     """A CPU-built snapshot restores into the TPU backend and vice
     versa — the format carries semantics, not layout."""

@@ -175,3 +175,15 @@ def test_device_stats(b):
     assert stats["capacity"] >= 1
     assert stats["peers"] == 1
     assert not stats["dirty"]
+
+
+@pytest.mark.parametrize("loaded", [False, True], ids=["empty", "loaded"])
+def test_device_stats_name_the_platform_the_index_lives_on(b, loaded):
+    """`--spatial-backend tpu` on a chip-less host serves from the CPU
+    platform; the gauge is where that shows (chip_smoke.py reads it)."""
+    if loaded:
+        b.add_subscription(W, uuid.uuid4(), Vector3(1, 1, 1))
+        b.flush()
+    stats = b.device_stats()
+    assert (stats["platform"], stats["device_kind"],
+            stats["device_count"]) == ("cpu", "cpu", 1)

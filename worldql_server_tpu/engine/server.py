@@ -965,15 +965,20 @@ class WorldQLServer:
         path = self.config.index_snapshot
         if not path:
             return
-        # Complete any pending restored-peer sweep synchronously first:
-        # a restart shorter than the staleness window must not
-        # re-persist ghost rows forever. Periodic checkpoints pass
+        # Complete any pending restored-peer sweep first: a restart
+        # shorter than the staleness window must not re-persist ghost
+        # rows forever. The ghosts are dropped from the EXPORT, not
+        # evicted from the index — this runs at shutdown, and a
+        # per-peer eviction of a million restored rows held SIGTERM
+        # for twenty minutes. Periodic checkpoints pass
         # sweep_restored=False — mid-serving, restored peers may still
         # be inside their reconnect grace window.
+        ghosts = []
         if sweep_restored:
-            for peer in self._restored_peers:
-                if self.peer_map.get(peer) is None:
-                    self.backend.remove_peer(peer)
+            ghosts = [
+                peer for peer in self._restored_peers
+                if self.peer_map.get(peer) is None
+            ]
             self._restored_peers = []
         if self._snapshot_save_disabled:
             logger.warning(
@@ -984,7 +989,7 @@ class WorldQLServer:
         from ..spatial.snapshot import save_snapshot
 
         try:
-            save_snapshot(self.backend, path)
+            save_snapshot(self.backend, path, drop_peers=ghosts)
         except Exception:
             logger.exception("index snapshot %s failed to save", path)
 

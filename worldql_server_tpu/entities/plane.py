@@ -315,11 +315,16 @@ class EntityPlane:
         self._sub_refs: Counter = Counter()
 
         # one jitted tick fn; shape (= capacity tier) keys its compile
-        # cache, which the retrace GUARD audits under entities.sim_tick
+        # cache, which the retrace GUARD audits under entities.sim_tick.
+        # The neighbor resolve is the fused Pallas kernel on a TPU and
+        # the XLA stencil elsewhere — chosen HERE, by the rule
+        # ops/tick.py would apply, so that the choice can be logged at
+        # the first tick and read from the entity_sim gauge.
+        self.pallas = jax.devices()[0].platform == "tpu"
         self._tick_fn = jax.jit(
             make_tick_fn(
                 cube_size=cube_size, k=self.k, dt=self.dt,
-                bounds=self.bounds,
+                bounds=self.bounds, pallas=self.pallas,
             )
         )
         GUARD.register("entities.sim_tick", self._tick_fn)
@@ -999,6 +1004,11 @@ class EntityPlane:
         """The pre-delta full path: ship dirty slots to the persistent
         twin, run the fused kernel over the WHOLE capacity tier."""
         state = self._upload_state(cap)
+        if not self.full_sim_ticks:
+            logger.info(
+                "entity sim first tick: pallas=%s k=%d capacity=%d on %s",
+                self.pallas, self.k, cap, jax.devices()[0].device_kind,
+            )
         new_state, targets, counts = self._tick_fn(state)
         # device twin for the NEXT tick: integrated positions; the
         # UPLOADED (host-authoritative) velocity — the in-tick bounce
@@ -1578,6 +1588,7 @@ class EntityPlane:
             "peers": len(self._peer_slots),
             "worlds": len(self._world_names),
             "k": self.k,
+            "pallas": self.pallas,
             "registered": self.entities_registered,
             "removed": self.entities_removed,
             "updates": self.updates,

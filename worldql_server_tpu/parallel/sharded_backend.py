@@ -24,7 +24,7 @@ mesh (the distributed delivery path consumes CSR).
 
 Query arrays enter as numpy with explicit ``in_shardings``, so every
 H2D transfer rides the ONE jitted dispatch — no per-array
-``device_put`` round-trips (they dominate on tunneled devices).
+``device_put`` round-trips.
 """
 
 from __future__ import annotations

@@ -372,7 +372,7 @@ def compact_sparse(tgt, *, c: int):
     query indices with >= 1 target, their target rows, and the true hit
     count (host re-fetches dense on the rare ``n_hits > c`` overflow).
     Cuts device→host result bytes by the hit rate — the dominant cost
-    on PCIe, decisive on tunneled devices."""
+    of a result fetch."""
     nz = jnp.any(tgt >= 0, axis=1)
     order = jnp.argsort(~nz, stable=True)  # hit rows first, in order
     rows = order[:c]
@@ -759,8 +759,7 @@ def _sort_segment_dev(keys, keys2, peers, n_buckets):
 @partial(jax.jit, static_argnames=("cap2", "n_buckets"))
 def _device_compact(bk, bk2, bp, dk, dk2, dp, cap2, n_buckets):
     """Fold base + delta into a fresh sorted base ENTIRELY on device —
-    zero host→device transfer (decisive on tunneled/remote devices
-    where a full index upload costs seconds).
+    zero host→device transfer.
 
     Dead rows (peer < 0) get their key rewritten to the padding
     sentinel, so the stable sort sinks them past every live run and the
@@ -939,8 +938,7 @@ class TpuSpatialBackend(SpatialBackend):
         # publishes the merged dict as ``last_device_timing`` for
         # DeviceTelemetry to tag onto the tick trace. These are
         # HOST-side brackets of the existing instrumentation points,
-        # not profiler truth — on a tunneled device the "compute" wall
-        # includes the link.
+        # not profiler truth.
         self._last_prefetch_ms = 0.0
         self.last_device_timing: dict = {}
         #: capacity tier of the LAST dispatch (retrace spans tag it —
@@ -2400,8 +2398,8 @@ class TpuSpatialBackend(SpatialBackend):
     def _dispatch(self, queries: tuple, segs, ks, kinds):
         """Run the padded query arrays against the device segments.
         Numpy args go straight into the jitted call so all H2D
-        transfers ride one dispatch — on tunneled/remote devices
-        per-array ``device_put`` round-trips dominate otherwise."""
+        transfers ride one dispatch instead of one ``device_put``
+        round-trip per array."""
         flat = [a for seg in segs for a in seg]
         return _match_dense_kernel(*flat, *queries, ks=ks)
 
@@ -2780,7 +2778,7 @@ class TpuSpatialBackend(SpatialBackend):
         total = int(total)  # wql: allow(jax-host-sync) — collect point
         # the total is the tick's designated device-wait point: the
         # scalar is only readable once the batch finished, so this
-        # wall is the compute leg (plus the link, on tunneled devices)
+        # wall is the compute leg
         timing["compute_ms"] = (time.perf_counter() - t_wait) * 1e3
         if total > t_cap:
             # Rare: the tick's fan-out outgrew the hint — re-resolve
