@@ -12,10 +12,10 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
 SMALL = ["--rows", "20000", "--peers", "8", "--messages", "600",
-         "--rounds", "30"]
+         "--rounds", "30", "--entities", "2000"]
 
 
-def run_smoke(*extra, timeout=90):
+def run_smoke(*extra, timeout=240):   # ~50 s alone, slower under xdist
     return subprocess.run(
         [sys.executable, str(ROOT / "chip_smoke.py"), *SMALL, *extra],
         cwd=ROOT, capture_output=True, text=True, timeout=timeout,
@@ -33,6 +33,7 @@ def test_cpu_rehearsal_is_green_and_says_cpu(native_lib):
     }
     assert any("missing 0, extra 0, delivered twice 0" in ln for ln in lines)
     assert any("native legs live" in ln for ln in lines)
+    assert any("deltas refused 0, gaps 0" in ln for ln in lines)
 
 
 def test_refuses_to_report_a_chip_run_from_a_cpu_host(native_lib):

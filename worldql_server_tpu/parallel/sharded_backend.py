@@ -650,9 +650,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
     # endregion
 
     def _index_devices(self) -> list:
-        if self._base_bundle is None:
-            return sorted(self.mesh.devices.flat, key=lambda d: d.id)
-        return super()._index_devices()
+        return sorted(self.mesh.devices.flat, key=lambda d: d.id)
 
     def device_stats(self) -> dict:
         stats = super().device_stats()
