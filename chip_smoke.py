@@ -4,8 +4,10 @@
 Boots the real server (``python -m worldql_server_tpu``) as a child on
 one TPU with a 1,000,000-row subscription index restored from a
 snapshot, drives it with real ZeroMQ peers, and compares every
-delivery with a plain numpy reference of the same rows. The quickest
-proof that the system still starts on the accelerator:
+delivery with a plain numpy reference of the same rows; then boots it
+again with the entity plane and holds 100,000 entities' neighbor
+streams to a reference. The quickest proof that the system still
+starts on the accelerator:
 
     python chip_smoke.py                    # one chip; what the driver runs
     python chip_smoke.py --chips 4          # tpu vs sharded backend, 4 chips
