@@ -27,6 +27,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import contextlib
+import ctypes
 import json
 import os
 import signal
@@ -232,6 +233,13 @@ def cache_entries() -> int:
     return sum(1 for _ in d.iterdir()) if d.is_dir() else 0
 
 
+def die_with_parent() -> None:
+    """Runs in the child before exec: have the kernel SIGTERM the
+    server should this script die first (PR_SET_PDEATHSIG) — a killed
+    parent must not leave a process behind that holds the chip."""
+    ctypes.CDLL("libc.so.6").prctl(1, signal.SIGTERM)
+
+
 class Server:
     """One ``python -m worldql_server_tpu`` child. The only process of
     the run that touches jax, and so the only one that holds the chip."""
@@ -268,6 +276,7 @@ class Server:
         with open(self.log_path, "w") as log:
             self.proc = subprocess.Popen(
                 self.cmd, cwd=ROOT, stdout=log, stderr=subprocess.STDOUT,
+                preexec_fn=die_with_parent,
             )
         while True:
             require(
