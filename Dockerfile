@@ -59,6 +59,12 @@ EXPOSE 8081
 # write; override WQL_STORE_URL for anything durable.
 ENV WQL_STORE_URL=sqlite:///home/worldql/worldql.db
 
+# Compiled device programs persist here across restarts of a container
+# that keeps its home (mount a volume to keep them across containers):
+# an installed package has no writable checkout for the default
+# .jax_cache, and a cold boot with a large index compiles for minutes.
+ENV JAX_COMPILATION_CACHE_DIR=/home/worldql/.cache/jax
+
 # Define user and entrypoint
 USER worldql
 ENTRYPOINT ["worldql-server-tpu"]

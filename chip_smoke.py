@@ -47,6 +47,14 @@ CUBE = 16                 # the server's default sub_region_size
 OCCUPANCY_CAP = 256       # bench.py's config-5 crowd: Zipf, capped
 SPAN = 800.0              # crowd lives in ±SPAN per axis
 TICK = 0.05
+#: a cold 1M-row boot compiles for five minutes; none has come near this
+BOOT_TIMEOUT = 900.0
+#: once it serves, the server answers HTTP within this whatever else it
+#: is doing: every wait below polls it, so an event loop held by a
+#: compile or a sweep fails the run instead of being waited out. The
+#: longest honest hold is the entity plane's host-side apply leg: 6 s
+#: at the tick that first shows the peers 100,000 entities (ROADMAP S7)
+HTTP_TIMEOUT = 20.0
 #: what is too long for the end of the output: the server's log and its
 #: last /metrics (git-ignored; the chip tool brings this directory back)
 OUT = ROOT / "chiprun_out"
@@ -211,7 +219,7 @@ class World:
 # --------------------------------------------------------------------
 
 
-def http_json(port: int, path: str, timeout: float = 60.0) -> dict:
+def http_json(port: int, path: str, timeout: float = HTTP_TIMEOUT) -> dict:
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}{path}",
         headers={"Accept": "application/json"},
@@ -244,14 +252,13 @@ class Server:
     """One ``python -m worldql_server_tpu`` child. The only process of
     the run that touches jax, and so the only one that holds the chip."""
 
-    def __init__(self, workdir: Path, server_args: list[str],
-                 boot_timeout: float):
+    def __init__(self, workdir: Path, server_args: list[str]):
         from worldql_server_tpu.scenarios.client import free_port
 
         self.http_port = free_port()
         self.zmq_port = free_port()
         self.log_path = workdir / f"server-{self.http_port}.log"
-        self.boot_timeout = boot_timeout
+        self.slowest_answer = 0.0
         self.cmd = [
             sys.executable, "-m", "worldql_server_tpu", "-v",
             *server_args,
@@ -262,7 +269,8 @@ class Server:
             "--zmq-server-host", "127.0.0.1",
             "--zmq-server-port", str(self.zmq_port),
             # restored rows of peers that never connect are swept one
-            # staleness window after boot: keep the window past the run
+            # staleness window after boot, and the reference holds them:
+            # keep the window past the run (the driver's limit is 1200 s)
             "--zmq-timeout-secs", "3600",
         ]
         self.proc: subprocess.Popen | None = None
@@ -285,12 +293,12 @@ class Server:
                 + self.log_tail(),
             )
             require(
-                time.monotonic() - t0 < self.boot_timeout,
-                f"server not healthy after {self.boot_timeout}s:\n"
+                time.monotonic() - t0 < BOOT_TIMEOUT,
+                f"server not healthy after {BOOT_TIMEOUT}s:\n"
                 + self.log_tail(),
             )
             try:
-                http_json(self.http_port, "/healthz", timeout=10.0)
+                http_json(self.http_port, "/healthz")
                 break
             except OSError:
                 time.sleep(0.25)
@@ -298,8 +306,17 @@ class Server:
             f"{time.monotonic() - t0:.1f}")
         say(f"compile cache entries after boot: {cache_entries()}")
 
-    def metrics(self, timeout: float = 60.0) -> dict:
-        return http_json(self.http_port, "/metrics", timeout)
+    def metrics(self) -> dict:
+        t0 = time.monotonic()
+        try:
+            snap = http_json(self.http_port, "/metrics")
+        except OSError as e:
+            raise SystemExit(
+                f"chip_smoke FAILED: the server did not answer /metrics "
+                f"within {HTTP_TIMEOUT:.0f} s ({e}):\n" + self.log_tail()
+            )
+        self.slowest_answer = max(self.slowest_answer, time.monotonic() - t0)
+        return snap
 
     def log_tail(self, n: int = 40) -> str:
         lines = self.log_path.read_text(errors="replace").splitlines()
@@ -344,7 +361,8 @@ class Server:
         require(rc == 0, f"server exited {rc} on SIGTERM:\n{self.log_tail()}")
         leaked = held & set(os.listdir("/dev/shm"))
         require(not leaked, f"leaked shared memory: {sorted(leaked)}")
-        say("server stopped cleanly, /dev/shm clean")
+        say(f"server stopped cleanly, /dev/shm clean; its slowest /metrics "
+            f"answer took {self.slowest_answer:.2f} s")
 
 
 # --------------------------------------------------------------------
@@ -374,19 +392,30 @@ class Client:
         self.peer.close()
 
 
-async def wait_for(predicate, timeout: float, what: str) -> None:
+async def wait_for(server: Server, predicate, timeout: float,
+                   what: str) -> dict:
+    """Poll until ``predicate(fresh /metrics)`` holds; → that snapshot.
+    Each poll is an HTTP round trip held to HTTP_TIMEOUT, so however
+    long ``timeout`` is, a server that stops answering fails here."""
     deadline = time.monotonic() + timeout
-    while not predicate():
+    while True:
+        snap = await asyncio.to_thread(server.metrics)
+        if predicate(snap):
+            return snap
         require(time.monotonic() < deadline, f"timed out waiting for {what}")
-        await asyncio.sleep(0.05)
+        await asyncio.sleep(TICK / 2)
 
 
-def plan_traffic(world: World, client_rows: list[int], seed: int,
-                 n_messages: int, rounds: int) -> list[dict]:
-    """The seeded LocalMessage schedule: round-robin senders, 80% from
-    the sender's own position, 20% from a fresh random point (miss
-    traffic), every tenth IncludingSelf."""
+def plan_traffic(world: World, client_rows: list[int],
+                 seed: int) -> list[dict]:
+    """The seeded LocalMessage schedule: 20 messages a round, a round a
+    tick — 120 rounds (2,400 messages) at deployment size, fewer for a
+    rehearsal's small index; round-robin senders, 80% from the sender's
+    own position, 20% from a fresh random point (miss traffic), every
+    tenth IncludingSelf."""
     rng = np.random.default_rng(seed + 1)
+    rounds = min(120, max(30, world.rows // 5000))
+    n_messages = 20 * rounds
     plan = []
     for i in range(n_messages):
         row = client_rows[i % len(client_rows)]
@@ -439,11 +468,11 @@ async def drive(server: Server, world: World, client_rows: list[int],
             if i and m["round"] != plan[i - 1]["round"]:
                 # a round per tick, whatever a tick takes: the next
                 # round goes out once the ticker has flushed this one
-                while True:
-                    snap = await asyncio.to_thread(server.metrics)
-                    if snap["counters"].get("tick.messages", 0) >= i:
-                        break
-                    await asyncio.sleep(TICK / 4)
+                await wait_for(
+                    server,
+                    lambda snap: snap["counters"].get("tick.messages", 0) >= i,
+                    30.0, f"the ticker to flush the first {i} messages",
+                )
             x, y, z = (float(v) for v in m["position"])
             await by_row[m["row"]].peer.send(Message(
                 instruction=Instruction.LOCAL_MESSAGE,
@@ -461,8 +490,8 @@ async def drive(server: Server, world: World, client_rows: list[int],
                 if msg.instruction == Instruction.LOCAL_MESSAGE
             )
 
-        await wait_for(lambda: local_count() >= want, 60.0,
-                       f"{want} deliveries (have {local_count()})")
+        await wait_for(server, lambda _: local_count() >= want, 60.0,
+                       f"{want} deliveries")
         await asyncio.sleep(1.0)   # anything extra would arrive now
 
         # the rest of the protocol, once each
@@ -488,8 +517,9 @@ async def drive(server: Server, world: World, client_rows: list[int],
             return [m for m in c.received if m.instruction == instruction]
 
         await wait_for(
-            lambda: all(got(c, Instruction.GLOBAL_MESSAGE)
-                        for c in clients[1:])
+            server,
+            lambda _: all(got(c, Instruction.GLOBAL_MESSAGE)
+                          for c in clients[1:])
             and got(second, Instruction.HEARTBEAT)
             and got(first, Instruction.RECORD_REPLY),
             30.0, "global message, heartbeat echo and record reply",
@@ -546,8 +576,7 @@ ERROR_COUNTERS = (
 def served_run(args, world: World, snapshot: str, workdir: Path,
                backend_args: list[str], check_device) -> dict:
     client_rows = world.pick_clients(args.peers)
-    plan = plan_traffic(world, client_rows, args.seed, args.messages,
-                        args.rounds)
+    plan = plan_traffic(world, client_rows, args.seed)
     expected, resolved = reference_deliveries(world, client_rows, plan)
     say(f"reference: {len(plan)} messages resolve {resolved} targets, "
         f"{sum(len(e) for e in expected.values())} of them connected")
@@ -555,8 +584,7 @@ def served_run(args, world: World, snapshot: str, workdir: Path,
     # written per run: a stopping server saves its index back to the
     # file, minus the peers that never connected
     world.write_snapshot(snapshot)
-    server = Server(workdir, [*backend_args, "--index-snapshot", snapshot],
-                    args.boot_timeout)
+    server = Server(workdir, [*backend_args, "--index-snapshot", snapshot])
     with server.running():
         before = server.metrics()
         device = before["gauges"]["spatial_device"]
@@ -597,7 +625,7 @@ def served_run(args, world: World, snapshot: str, workdir: Path,
              "last_collect_ms")
         }))
         require(
-            flushes >= min(100, args.rounds),
+            flushes >= plan[-1]["round"] + 1,
             f"only {flushes} ticks carried traffic",
         )
         require(
@@ -673,6 +701,10 @@ async def drive_entities(server: Server, swarm: Swarm,
         for i in range(len(peer_uuids))
     ]
     say(f"{len(clients)} ZMQ peers connected")
+
+    def sim_of(snap: dict) -> dict:
+        return snap["gauges"]["entity_sim"]
+
     try:
         t_sent = time.monotonic()
         for c in clients:
@@ -699,24 +731,19 @@ async def drive_entities(server: Server, swarm: Swarm,
                 for o, e in zip(oracles, expected)
             )
 
-        # The plane precompiles its capacity tier AT BOOT (256 rows);
-        # the tiers this population reaches compile at their first tick,
-        # on the event loop — minutes on a cold cache, during which the
-        # server answers nothing, /metrics included.
-        await wait_for(settled, 900.0, "every peer's neighbor ledger")
-
-        async def entity_sim() -> dict:
-            snap = await asyncio.to_thread(server.metrics, 600.0)
-            return snap["gauges"]["entity_sim"]
-
-        sim = await entity_sim()
-        require(sim["entities"] == swarm.n, sim)
-        ticks_at_settle = sim["applied_ticks"]
-        while True:    # then 40 more ticks of steady state
-            sim = await entity_sim()
-            if sim["applied_ticks"] >= ticks_at_settle + 40:
-                break
-            await asyncio.sleep(TICK)
+        # 100,000 registrations in one burst: the plane compiled every
+        # tier the population grows through at boot and the recv path
+        # gives way, so the server ticks and answers while it takes
+        # them in
+        snap = await wait_for(server, lambda _: settled(), 180.0,
+                              "every peer's neighbor ledger")
+        require(sim_of(snap)["entities"] == swarm.n, sim_of(snap))
+        settled_at = sim_of(snap)["applied_ticks"]
+        sim = sim_of(await wait_for(    # then 40 ticks of steady state
+            server,
+            lambda snap: sim_of(snap)["applied_ticks"] >= settled_at + 40,
+            300.0, "40 ticks after the ledgers settled",
+        ))
         elapsed = time.monotonic() - t_sent + 1.0
 
         wrong = 0
@@ -764,11 +791,17 @@ def entity_run(args, workdir: Path, check_device) -> None:
         "--spatial-backend", "tpu", "--entity-sim", "--interest", "on",
         "--entity-k", str(ENTITY_K),
         "--entity-max", str(1 << max(args.entities - 1, 255).bit_length()),
-    ], args.boot_timeout)
+    ])
     with server.running():
-        check_device(server.metrics()["gauges"]["spatial_device"])
+        booted = server.metrics()["gauges"]
+        check_device(booted["spatial_device"])
+        say("boot precompile:", json.dumps(booted["precompile"]["entities"]))
         sim = asyncio.run(drive_entities(server, swarm, peer_uuids))
-        after = server.metrics(600.0)
+        after = server.metrics()
+        say("compiled while serving:", json.dumps({
+            k: round(after["gauges"]["device"][k] - booted["device"][k], 1)
+            for k in ("compiles", "compile_ms_total")
+        }))
         (OUT / f"metrics-{server.http_port}.json").write_text(
             json.dumps(after, indent=1))
         moved = {name: after["counters"].get(name, 0)
@@ -813,9 +846,6 @@ def main() -> None:
                     help="subscription rows (default 1,000,000; "
                          "640,000 with --chips 4)")
     ap.add_argument("--peers", type=int, default=64)
-    ap.add_argument("--messages", type=int, default=2400)
-    ap.add_argument("--rounds", type=int, default=120,
-                    help="tick intervals the messages are spread over")
     ap.add_argument("--entities", type=int, default=100_000,
                     help="entities of the second, entity-plane phase "
                          "(0 skips it)")
@@ -823,7 +853,6 @@ def main() -> None:
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearsal: accept a server whose index is not "
                          "on a TPU; the last line then says so")
-    ap.add_argument("--boot-timeout", type=float, default=900.0)
     args = ap.parse_args()
 
     t_start = time.monotonic()

@@ -11,8 +11,7 @@ import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
-SMALL = ["--rows", "20000", "--peers", "8", "--messages", "600",
-         "--rounds", "30", "--entities", "2000"]
+SMALL = ["--rows", "20000", "--peers", "8", "--entities", "2000"]
 
 
 def run_smoke(*extra, timeout=240):   # ~50 s alone, slower under xdist

@@ -340,6 +340,32 @@ def test_dispatch_scatters_only_dirty_rows(wire):
     assert h.wire_plane.h2d_full == 1  # never re-shipped the tier
 
 
+def test_precompile_walks_the_tick_up_to_the_max_entities_tier():
+    """Boot precompile reaches the --entity-max tier: growing through
+    the capacity tiers, and the delta sub-batches under them, compiles
+    no tick mid-serving (131,072 rows take the TPU compiler 80 s)."""
+    plane = make_plane(max_entities=1000, delta_ticks="on")
+    stats = plane.precompile()
+    assert stats["families"]["entities.sim_tick"] == 5    # 64 … 1024
+    assert stats["skipped_by_budget"] == 0
+    owner = uuid.uuid4()
+    rng = np.random.default_rng(3)
+    before = GUARD.counts()
+    for n in (300, 300, 400):    # 256 → 512 → 1024
+        pos = rng.uniform(-400, 400, (n, 3))
+        plane.ingest(ent_msg(owner, [
+            Entity(uuid=uuid.uuid4(), position=Vector3(*map(float, p)),
+                   world_name="w",
+                   flex=vel_flex(1.0) if i < 3 else None)
+            for i, p in enumerate(pos)
+        ]))
+        for _ in range(3):
+            plane.apply(plane.collect_tick(plane.dispatch_tick()))
+    assert plane.stats()["capacity"] == 1024
+    assert plane.delta_sim_ticks > 0
+    assert GUARD.delta(before).get("entities.sim_tick", 0) == 0
+
+
 def test_scatter_ladder_precompiles_and_stays_quiet(wire):
     plane = make_plane()
     stats = plane.precompile()

@@ -44,6 +44,23 @@ from worldql_server_tpu.protocol import deserialize_message
 # region: stamp grammar
 
 
+def test_delivering_a_pre_encoded_frame_never_decodes_it():
+    """The delivery path reads ``trace_ctx`` off every message; on a
+    pre-encoded frame that fell through to ``__getattr__`` and decoded
+    the whole frame to answer None — a million Entity objects a tick at
+    100K entities. These bytes are no message: a decode would raise."""
+    import asyncio
+
+    from worldql_server_tpu.engine.peers import PeerMap
+    from worldql_server_tpu.entities.plane import WireFrame
+    from worldql_server_tpu.interest.manager import _WireFrame
+
+    for frame in (WireFrame(b"\xff" * 8), _WireFrame(b"\xff" * 8)):
+        pairs = [(frame, [uuid.uuid4()])]
+        assert asyncio.run(PeerMap().deliver_batch(pairs)) == 0
+        assert frame.trace_ctx is None
+
+
 def test_stamp_roundtrip_and_fixed_width():
     s = stamp(PARAM_DELTA, 7, 300)
     assert s == "entity.frame.delta:00000007:0000012c"

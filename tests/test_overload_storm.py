@@ -157,9 +157,17 @@ def test_overload_storm_survival_accounting_recovery():
             )
             assert shed_total > 0, "storm shed nothing — not a real storm"
 
-            # drain: stop offering, let the pump chew through the rest
-            for _ in range(600):
-                if not server.ticker._queue and not server.ticker.inflight():
+            # drain: stop offering, let the recv loop and the pump chew
+            # through the rest (the recv loop gives the event loop a
+            # turn every 10 ms of backlog, so the socket can still hold
+            # messages when the flooders are done)
+            for _ in range(1500):
+                if (
+                    server.metrics.counters["messages.local_message"]
+                    == offered
+                    and not server.ticker._queue
+                    and not server.ticker.inflight()
+                ):
                     break
                 await asyncio.sleep(0.01)
             assert not server.ticker._queue

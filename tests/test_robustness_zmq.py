@@ -85,6 +85,49 @@ def test_recv_loop_survives_poison_message():
     assert run(scenario())
 
 
+def test_recv_backlog_gives_the_event_loop_a_turn():
+    """A recv on a socket with a backlog never suspends: a burst used
+    to be routed to its last message before the ticker or /healthz ran
+    again (100K entity registrations held a v5e host's loop for 17 s).
+    The recv path now gives way every 10 ms of uninterrupted work."""
+    import time
+
+    async def scenario():
+        server = make_server()
+        await server.start()
+        try:
+            client = await ZmqClient.connect(server.config.zmq_server_port)
+            n, handled, seen = 300, 0, set()
+
+            async def slow_handle(message):    # 1 ms of work a message
+                nonlocal handled
+                t0 = time.perf_counter()
+                while time.perf_counter() - t0 < 0.001:
+                    pass
+                handled += 1
+
+            server.router.handle_message = slow_handle
+
+            async def bystander():    # any other task of the loop
+                while True:
+                    seen.add(handled)
+                    await asyncio.sleep(0)
+
+            watch = asyncio.create_task(bystander())
+            for _ in range(n):
+                await client.send(Message(instruction=Instruction.HEARTBEAT))
+            assert await wait_for(lambda: handled == n, timeout=20.0)
+            watch.cancel()
+            # starved, the bystander sees the count before and after
+            assert len(seen - {0, n}) >= 5, sorted(seen)
+            await client.close()
+        finally:
+            await server.stop()
+        return True
+
+    assert run(scenario())
+
+
 def test_sweeper_continues_past_raising_removal_hook():
     """Regression (ISSUE 4 satellite): one peer whose removal hook
     raises used to abort the whole sweep (and kill the sweeper task).

@@ -96,6 +96,56 @@ def test_snapshot_drop_peers_equals_evicting_them_first(tmp_path, make):
     assert_equivalent(src, dst, peers, pos, worlds)
 
 
+def _resilient_tpu():
+    from worldql_server_tpu.robustness.resilient import ResilientBackend
+
+    return ResilientBackend(TpuSpatialBackend(16))
+
+
+@pytest.mark.parametrize("make", [
+    lambda: CpuSpatialBackend(16),
+    lambda: TpuSpatialBackend(16),
+    lambda: TpuSpatialBackend(16, compact_threshold=16),
+    _resilient_tpu,
+], ids=["cpu", "tpu", "tpu-compacted", "resilient"])
+def test_remove_peers_equals_removing_each(make):
+    """The restored-index sweep sheds its ghosts in one vectorized pass
+    (base and delta rows alike): same index as one remove_peer each."""
+    bulk, each = make(), make()
+    peers, pos, worlds = populate(bulk)
+    populate(each)
+    for b in (bulk, each):
+        if hasattr(b, "wait_compaction"):
+            b.wait_compaction()
+    # peers[3] left in populate() and holds nothing; one is unknown
+    ghosts = peers[::3] + [uuid.uuid4()]
+    held = sum(each.remove_peer(peer) for peer in ghosts)
+    assert bulk.remove_peers(ghosts) == held > 0
+    bulk.flush()
+    each.flush()
+    assert_equivalent(each, bulk, peers, pos, worlds)
+    assert bulk.remove_peers(ghosts) == 0
+
+
+def test_remove_peers_sheds_a_restored_index_in_one_pass():
+    """One remove_peer per ghost costs the device backend a millisecond
+    each — 200,000 of them would hold the event loop for minutes."""
+    import time
+
+    n = 200_000
+    rng = np.random.default_rng(11)
+    peers = [uuid.UUID(int=i + 1) for i in range(n)]
+    b = TpuSpatialBackend(16)
+    b.bulk_add_subscriptions("w", peers, rng.integers(-40, 40, (n, 3)))
+    b.flush()
+    t0 = time.perf_counter()
+    assert b.remove_peers(peers[64:]) == n - 64
+    assert time.perf_counter() - t0 < 20.0
+    b.flush()
+    assert b.subscription_count() == 64
+    assert b.query_world("w") == set(peers[:64])
+
+
 def test_snapshot_cross_backend(tmp_path):
     """A CPU-built snapshot restores into the TPU backend and vice
     versa — the format carries semantics, not layout."""
@@ -239,7 +289,10 @@ def test_zmq_peer_keeps_subscription_across_restart(tmp_path):
     assert asyncio.run(scenario())
 
 
-def test_restored_peers_swept_if_they_never_reconnect(tmp_path):
+@pytest.mark.parametrize("spatial_backend", ["cpu", "tpu"])
+def test_restored_peers_swept_if_they_never_reconnect(
+    tmp_path, spatial_backend
+):
     """Restored subscriptions must not leak across restart cycles:
     peers absent one staleness window after boot lose their rows
     (WS UUIDs are per-connection, so WS rows are always swept)."""
@@ -256,7 +309,7 @@ def test_restored_peers_swept_if_they_never_reconnect(tmp_path):
     config.http_enabled = False
     config.ws_enabled = False
     config.zmq_enabled = False
-    config.spatial_backend = "cpu"
+    config.spatial_backend = spatial_backend
     config.index_snapshot = snap
     config.zmq_timeout_secs = 0  # immediate sweep window for the test
 

@@ -998,11 +998,10 @@ class WorldQLServer:
         one staleness window after boot, any restored peer absent from
         the peer map loses its rows."""
         await asyncio.sleep(self.config.zmq_timeout_secs)
-        swept = 0
-        for peer in self._restored_peers:
-            if self.peer_map.get(peer) is None:
-                if self.backend.remove_peer(peer):
-                    swept += 1
+        swept = self.backend.remove_peers([
+            peer for peer in self._restored_peers
+            if self.peer_map.get(peer) is None
+        ])
         self._restored_peers = []
         if swept:
             logger.info(

@@ -68,7 +68,7 @@ from collections import Counter
 
 import numpy as np
 
-from ..spatial import jaxconf  # noqa: F401  (must precede jax import)
+from ..spatial import jaxconf  # must precede the jax import
 import jax
 import jax.numpy as jnp
 
@@ -120,6 +120,11 @@ class WireFrame:
     diagnostics-only; the delivery path never triggers it."""
 
     __slots__ = ("wire", "_msg")
+
+    #: born here, not routed in: no router trace context — and the
+    #: delivery path's ``getattr(message, "trace_ctx", None)`` must find
+    #: that HERE, not fall through to a decode of the whole frame
+    trace_ctx = None
 
     def __init__(self, wire: bytes):
         self.wire = wire
@@ -317,10 +322,10 @@ class EntityPlane:
         # one jitted tick fn; shape (= capacity tier) keys its compile
         # cache, which the retrace GUARD audits under entities.sim_tick.
         # The neighbor resolve is the fused Pallas kernel on a TPU and
-        # the XLA stencil elsewhere — chosen HERE, by the rule
-        # ops/tick.py would apply, so that the choice can be logged at
-        # the first tick and read from the entity_sim gauge.
-        self.pallas = jax.devices()[0].platform == "tpu"
+        # the XLA stencil elsewhere — read HERE from the one rule the
+        # ops apply (jaxconf.on_tpu), so that the choice can be logged
+        # at the first tick and read from the entity_sim gauge.
+        self.pallas = jaxconf.on_tpu()
         self._tick_fn = jax.jit(
             make_tick_fn(
                 cube_size=cube_size, k=self.k, dt=self.dt,
@@ -1006,8 +1011,10 @@ class EntityPlane:
         state = self._upload_state(cap)
         if not self.full_sim_ticks:
             logger.info(
-                "entity sim first tick: pallas=%s k=%d capacity=%d on %s",
-                self.pallas, self.k, cap, jax.devices()[0].device_kind,
+                "entity sim first tick: pallas=%s interpret=%s k=%d "
+                "capacity=%d on %s",
+                self.pallas, not jaxconf.on_tpu(), self.k, cap,
+                jax.devices()[0].device_kind,
             )
         new_state, targets, counts = self._tick_fn(state)
         # device twin for the NEXT tick: integrated positions; the
@@ -1154,53 +1161,54 @@ class EntityPlane:
     def precompile(self, max_compiles: int = 32) -> dict:
         """Boot-time shape precompilation for the sim kernels (the
         PR 8 tier-precompile discipline extended to the entity plane):
-        the tick kernel at the current capacity tier plus the
-        incremental-H2D scatter across its pow2 dirty-bucket ladder, so
-        steady-state serving re-traces nothing. Returns a stats dict in
-        the spatial/precompile.py shape."""
+        the tick kernel at every pow2 tier up to the one
+        ``max_entities`` reaches — the capacity tiers the plane grows
+        through and the delta sub-batch tiers under them are the same
+        shapes of the same function — plus the incremental-H2D
+        scatter's dirty-bucket ladder at the boot tier and at the top
+        tier, where a deployment sized by ``--entity-max`` lives.
+        Largest first under the budget: the top tier is the one whose
+        compile takes a minute and more on a TPU (80 s at 131,072
+        rows), and left to its first tick it holds the event loop —
+        /healthz included — that long. The scatter shapes of the tiers
+        in between still compile at first use (sub-second each).
+        Returns a stats dict in the spatial/precompile.py shape."""
         t0 = time.perf_counter()
         before = GUARD.counts()
-        cap = self._cap
+        budget = max(1, int(max_compiles))
         compiles = skipped = 0
-        zeros3 = jnp.zeros((cap, 3), jnp.float32)
-        ids = jnp.full(cap, -1, jnp.int32)
-        state = EntityState(zeros3, zeros3, ids, ids)
-        out = self._tick_fn(state)
-        jax.block_until_ready(out)
-        compiles += 1
-        bucket = _SCATTER_MIN_BUCKET
-        while bucket <= cap:
-            if compiles >= max(1, int(max_compiles)):
-                skipped += 1
-                bucket *= 2
-                continue
-            idx = np.full(bucket, cap, np.int32)
-            state = self._scatter_fn(
-                state, idx,
-                np.zeros((bucket, 3), np.float32),
-                np.zeros((bucket, 3), np.float32),
-                np.zeros(bucket, np.int32),
-                np.zeros(bucket, np.int32),
-            )
-            compiles += 1
-            bucket *= 2
-        if self._delta_ticks:
-            # delta-tick sub-batch ladder: the dirty-closure kernel is
-            # the SAME tick fn at smaller pow2 tiers — walk them so a
-            # low-churn steady state re-traces nothing mid-serving
-            tier = _DELTA_MIN_TIER
-            while tier < cap:
-                if compiles >= max(1, int(max_compiles)):
-                    skipped += 1
-                    tier *= 2
-                    continue
-                z3 = jnp.zeros((tier, 3), jnp.float32)
-                neg = jnp.full(tier, -1, jnp.int32)
-                out = self._tick_fn(EntityState(z3, z3, neg, neg))
-                jax.block_until_ready(out)
+
+        def blank(tier: int) -> EntityState:
+            zeros3 = jnp.zeros((tier, 3), jnp.float32)
+            ids = jnp.full(tier, -1, jnp.int32)
+            return EntityState(zeros3, zeros3, ids, ids)
+
+        top = max(_next_pow2(self.max_entities), self._cap)
+        tier = top
+        while tier >= (_DELTA_MIN_TIER if self._delta_ticks else self._cap):
+            if compiles < budget:
+                jax.block_until_ready(self._tick_fn(blank(tier)))
                 compiles += 1
-                tier *= 2
-        jax.block_until_ready(state)
+            else:
+                skipped += 1
+            tier //= 2
+        for cap in sorted({top, self._cap}, reverse=True):
+            state = blank(cap)
+            bucket = cap // 2   # a denser dirty set re-ships the tier
+            while bucket >= _SCATTER_MIN_BUCKET:
+                if compiles < budget:
+                    state = self._scatter_fn(
+                        state, np.full(bucket, cap, np.int32),
+                        np.zeros((bucket, 3), np.float32),
+                        np.zeros((bucket, 3), np.float32),
+                        np.zeros(bucket, np.int32),
+                        np.zeros(bucket, np.int32),
+                    )
+                    compiles += 1
+                else:
+                    skipped += 1
+                bucket //= 2
+            jax.block_until_ready(state)
         delta = GUARD.delta(before)
         stats = {
             "dispatches": compiles,

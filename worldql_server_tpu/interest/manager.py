@@ -119,6 +119,11 @@ class _WireFrame:
 
     __slots__ = ("wire", "_msg")
 
+    #: born here, not routed in: no router trace context — and the
+    #: delivery path's ``getattr(message, "trace_ctx", None)`` must find
+    #: that HERE, not fall through to a decode of the whole frame
+    trace_ctx = None
+
     def __init__(self, wire: bytes):
         self.wire = wire
         self._msg = None

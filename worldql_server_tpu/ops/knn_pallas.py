@@ -42,7 +42,7 @@ from __future__ import annotations
 
 from functools import partial
 
-from ..spatial import jaxconf  # noqa: F401  (must precede jax import)
+from ..spatial import jaxconf  # must precede the jax import
 import jax
 import jax.numpy as jnp
 
@@ -216,7 +216,7 @@ def knn_select(rid, peer, pos, *, k: int, tile: int = 512,
     -1-padded, nearest-first. Fused Pallas path; semantically identical
     to the XLA stencil in ops/tick.py."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = not jaxconf.on_tpu()
     return _knn_jit(
         rid.astype(jnp.int32), peer.astype(jnp.int32),
         pos.astype(jnp.float32), k, tile, interpret,

@@ -42,7 +42,7 @@ from __future__ import annotations
 from functools import partial
 from typing import NamedTuple
 
-from ..spatial import jaxconf  # noqa: F401  (must precede jax import)
+from ..spatial import jaxconf  # must precede the jax import
 import jax
 import jax.numpy as jnp
 
@@ -197,7 +197,7 @@ def simulation_tick(
     rid = jnp.cumsum(first.astype(jnp.int32))
 
     if pallas is None:
-        pallas = jax.devices()[0].platform == "tpu"
+        pallas = jaxconf.on_tpu()
     # k=1 rides the k=2 window, truncated to one target: a ±(k-1)
     # stencil at k=1 is empty and would silently return NO neighbors,
     # while ±1 finds the single nearest whenever occupancy <= 2 — the

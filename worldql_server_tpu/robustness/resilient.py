@@ -251,6 +251,15 @@ class ResilientBackend(SpatialBackend):
                 self._note_failure("mutate")
         return out
 
+    def remove_peers(self, peers) -> int:
+        out = self.mirror.remove_peers(peers)
+        if not self.failed_over:
+            try:
+                self.inner.remove_peers(peers)
+            except Exception:
+                self._note_failure("mutate")
+        return out
+
     def bulk_add_subscriptions(self, world, peers, cubes) -> int:
         out = self.mirror.bulk_add_subscriptions(world, peers, cubes)
         if not self.failed_over:

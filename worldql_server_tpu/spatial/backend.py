@@ -87,6 +87,12 @@ class SpatialBackend(abc.ABC):
         """Remove a disconnected peer from every world/cube
         (world_map.rs:41-61)."""
 
+    def remove_peers(self, peers: Sequence[uuid_mod.UUID]) -> int:
+        """``remove_peer`` for each of ``peers``; returns how many held
+        a subscription. Device backends override with one vectorized
+        pass."""
+        return sum(self.remove_peer(peer) for peer in peers)
+
     # endregion
 
     # region: queries

@@ -37,3 +37,11 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     )
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def on_tpu() -> bool:
+    """The one reading of the default device that picks the compiled
+    Pallas kernels over their XLA twins and over Pallas interpret mode
+    (ops/tick.py, ops/knn_pallas.py, entities/plane.py): who asks here
+    gets the same answer, so ``pallas=True`` in a log means Mosaic."""
+    return jax.devices()[0].platform == "tpu"

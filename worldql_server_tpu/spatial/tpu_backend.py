@@ -1158,8 +1158,31 @@ class TpuSpatialBackend(SpatialBackend):
             rows_d = rows_d[self._dp[rows_d] == pid]
         else:
             rows_d = np.empty(0, np.intp)
+        return self._retire_rows(rows_b, rows_d) > 0
+
+    def remove_peers(self, peers) -> int:
+        """``remove_peer`` over many peers at once: one mask over the
+        pid columns where the single form runs a search (and a
+        millisecond of numpy calls) per peer — a restored index sheds a
+        million never-reconnected peers in one sweep."""
+        gone = np.zeros(len(self._peer_list) + 1, bool)
+        for peer in peers:
+            pid = self._peer_ids.get(peer)
+            if pid is not None:
+                gone[pid] = True
+                self._delta_pid_rows.pop(pid, None)
+        # dead rows carry pid -1: the mask's spare last slot, never set
+        rows_b = np.flatnonzero(gone[self._bp])
+        rows_d = np.flatnonzero(gone[self._dp[:self._dn]])
+        return self._retire_rows(rows_b, rows_d)
+
+    def _retire_rows(self, rows_b: np.ndarray, rows_d: np.ndarray) -> int:
+        """Tombstone live base rows ``rows_b`` and delta rows ``rows_d``
+        of peers that are leaving every world they touch; returns how
+        many peers lost a row."""
         if rows_b.size == 0 and rows_d.size == 0:
-            return False
+            return 0
+        pids_b, pids_d = self._bp[rows_b], self._dp[rows_d]
         if self._delta_ticks:
             self._coherence.note_keys(np.concatenate([
                 self._bk[rows_b], self._dk[rows_d]
@@ -1168,17 +1191,16 @@ class TpuSpatialBackend(SpatialBackend):
         in_flight = self._compaction is not None
         if rows_b.size:
             self._bp[rows_b] = -1
-            self._pending_dead.extend(int(r) for r in rows_b)
+            self._pending_dead.extend(rows_b.tolist())
             self._base_dead += int(rows_b.size)
             self._base_live -= int(rows_b.size)
             if in_flight:
-                self._replay.extend(
-                    (int(self._bk[r]), pid) for r in rows_b
-                )
+                self._replay.extend(zip(
+                    self._bk[rows_b].tolist(), pids_b.tolist()
+                ))
         if rows_d.size:
             consumed = self._compaction["consumed_dn"] if in_flight else 0
-            for r in rows_d:
-                r = int(r)
+            for r, pid in zip(rows_d.tolist(), pids_d.tolist()):
                 self._dp[r] = -1
                 self._delta_index.pop((int(self._dk[r]), pid), None)
                 if r < self._delta_built_n:
@@ -1188,15 +1210,18 @@ class TpuSpatialBackend(SpatialBackend):
             self._delta_live -= int(rows_d.size)
             self._delta_stale = True
 
-        # world-level refcounts: drop this peer from every touched world
-        wids = np.unique(np.concatenate([
-            self._bw[rows_b], self._dw[rows_d]
-        ])) if rows_b.size or rows_d.size else ()
-        for wid in wids:
-            self._world_peers[int(wid)].pop(pid, None)
+        # world-level refcounts: drop each peer from every touched world
+        pairs = np.unique(
+            np.concatenate([self._bw[rows_b], self._dw[rows_d]])
+            .astype(np.int64) << 32
+            | np.concatenate([pids_b, pids_d]).astype(np.int64)
+        )
+        for wid, pid in zip((pairs >> 32).tolist(),
+                            (pairs & 0xFFFFFFFF).tolist()):
+            self._world_peers[wid].pop(pid, None)
 
         self._dirty = True
-        return True
+        return int(np.unique(pairs & 0xFFFFFFFF).size)
 
     def _delta_append(self, key: int, wid: int, cube: Cube, pid: int) -> None:
         if self._dn == self._dcap:

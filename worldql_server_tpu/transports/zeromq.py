@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 import uuid as uuid_mod
 
 import zmq
@@ -50,6 +51,10 @@ from ..protocol import (
 from ..robustness import failpoints
 
 logger = logging.getLogger(__name__)
+
+#: longest the recv loop works through a backlog before it lets the
+#: rest of the event loop (ticker, HTTP, senders) run
+_RECV_YIELD_SECS = 0.01
 
 
 def _valid_socket_addr(parameter: str) -> bool:
@@ -81,6 +86,7 @@ class ZmqTransport:
         # running tasks, so retain them or a GC pass could drop an
         # eviction mid-flight and leak the dead peer from the map.
         self._evictions: set[asyncio.Task] = set()
+        self._yielded = time.monotonic()  # see _give_way
 
     async def start(self) -> None:
         config = self.server.config
@@ -152,6 +158,7 @@ class ZmqTransport:
             # outside the containment: kills the LOOP, exercising the
             # supervisor's restart/escalate policy in the chaos suite
             failpoints.fire("zmq.recv")
+            await self._give_way()
             parts = await self._pull.recv_multipart()
             fast = getattr(self.server, "entity_ingest", None)
             if fast is None or not fast.active:
@@ -247,9 +254,21 @@ class ZmqTransport:
         if data is not None:
             await self._route_data(data)
 
+    async def _give_way(self) -> None:
+        """Let the rest of the event loop run once the recv path has
+        held it for ``_RECV_YIELD_SECS``. A recv on a socket with a
+        backlog completes without suspending, and a drained batch is
+        routed message after message, so a burst — 100,000 entity
+        registrations took a v5e host 17 s — would keep the ticker and
+        /healthz off the loop until its last message."""
+        if time.monotonic() - self._yielded > _RECV_YIELD_SECS:
+            await asyncio.sleep(0)
+            self._yielded = time.monotonic()
+
     async def _route_data(self, data: bytes,
                           ctx: tuple[int, int] | None = None,
                           epoch: int = 0) -> None:
+        await self._give_way()
         tracer = getattr(self.server, "tracer", None)
         if tracer is not None and tracer.enabled:
             # recv→decode→route under one span tree: the decode and the
