@@ -167,6 +167,7 @@ def test_overload_storm_survival_accounting_recovery():
                     == offered
                     and not server.ticker._queue
                     and not server.ticker.inflight()
+                    and not server.ticker._flushing.locked()
                 ):
                     break
                 await asyncio.sleep(0.01)
