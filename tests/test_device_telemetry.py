@@ -101,6 +101,7 @@ def test_forced_retrace_is_counted_and_leaves_a_loose_span():
         assert tagged["family"].startswith(("tpu_backend.", "sharded."))
         assert tagged["new_variants"] >= 1
         assert tagged["t_cap"] == FRESH_TIER
+        assert tagged["query_cap"] >= 1      # the compiled variant's
         assert "compile_ms" in tagged
         # steady state: same tier again — no retrace, no new span
         before = len(recorder.loose_snapshot())
@@ -234,3 +235,57 @@ def test_server_wires_device_telemetry_only_for_device_backends():
     )
     assert WorldQLServer(off, backend=make_backend()) \
         .device_telemetry is None
+
+
+def test_collect_decode_leg_rides_the_timing_and_its_histogram():
+    """ISSUE 24: the decode after the fetch (ids walked into UUID
+    lists) is a leg of its own beside compute_ms / d2h_ms, on every
+    collect path, and on_tick feeds it to ``device.decode_ms``."""
+    backend = make_backend()
+    tel, metrics, _ = make_telemetry(backend)
+    try:
+        [targets] = dispatch_collect(backend)
+        assert targets
+        timing = backend.last_device_timing
+        assert timing["path"] in ("csr", "dense", "overflow")
+        assert timing["decode_ms"] > 0.0
+        tracer = Tracer(enabled=True)
+        trace = tracer.begin("tick", tick=1)
+        tel.on_tick(trace)
+        trace.finish()
+        assert trace.tags["device_timing"]["decode_ms"] > 0.0
+        lat = metrics.snapshot()["latency"]
+        assert lat["device.decode_ms"]["count"] == 1
+        # the dense ceiling path brackets its decode too
+        backend._delivery_cap = 1 << 30
+        dispatch_collect(backend)
+        assert backend.last_device_timing["path"] == "dense"
+        assert backend.last_device_timing["decode_ms"] > 0.0
+    finally:
+        tel.uninstall()
+
+
+def test_compile_time_is_a_counter_a_windows_delta_can_be_taken_of():
+    """ISSUE 24: the ms compiles held their caller accumulate in the
+    ``device.compile_ms_total`` counter: whole ms, within 1 ms of the
+    gauge's float however many sub-ms compiles there were."""
+    import jax.numpy as jnp
+
+    tel, metrics, _ = make_telemetry(make_backend())
+    try:
+        # a function no other test compiled: the listener's own path
+        jax.jit(lambda x: x * 3.25 + 24.0)(jnp.ones(24)).block_until_ready()
+        snap = metrics.snapshot()
+        assert snap["counters"]["device.compiles"] >= 1
+        assert "device.compile_ms_total" in snap["counters"]
+        for _ in range(7):
+            tel._on_compile(0.0004)             # 0.4 ms each
+        tel._on_compile(1.2)
+        snap = metrics.snapshot()
+        counted = snap["counters"]["device.compile_ms_total"]
+        assert counted >= 1202
+        assert 0 <= tel.stats()["compile_ms_total"] - counted < 1.0
+        assert snap["latency"]["device.compile_ms"]["count"] == \
+            snap["counters"]["device.compiles"]
+    finally:
+        tel.uninstall()

@@ -115,6 +115,43 @@ def test_render_prometheus_passes_strict_scraper_grammar():
     assert count == 5
 
 
+def test_render_prometheus_renders_a_table_gauge_one_series_a_column():
+    """A gauge whose every value is a dict (``spans``: name -> {count,
+    wall_ms, ...}) is a table: one ``name``-labelled series a column,
+    so a column has ONE ``# TYPE`` line whatever the number of rows;
+    a mixed dict keeps the one-level flattening."""
+    from prom_parser import validate_exposition
+
+    m = Metrics()
+    m.gauge("spans", lambda: {
+        "zmq.recv": {"count": 5400, "wall_ms": 512.25, "loop_ms": 300.5},
+        "task:PeerMap._deliver_batch_local.<locals>.drain_peer":
+            {"count": 0, "wall_ms": 0.0, "loop_ms": 41.0},
+        "codec.decode": {"count": 5400, "wall_ms": 80.0, "note": "text"},
+    })
+    m.gauge("loop_time", lambda: {"busy_ms": 812.5, "ingest": 300.5})
+    m.gauge("supervisor", lambda: {"tasks_unhealthy": 0,
+                                   "tasks": {"zmq-recv": {"crashes": 0}}})
+    types, samples = validate_exposition(m.render_prometheus())
+    assert types["wql_spans_wall_ms"] == types["wql_spans_count"] == "gauge"
+    assert "wql_spans_note" not in types
+    wall = {labels["name"]: v for name, labels, v in samples
+            if name == "wql_spans_wall_ms"}
+    assert wall == {
+        "zmq.recv": 512.25, "codec.decode": 80.0,
+        "task:PeerMap._deliver_batch_local.<locals>.drain_peer": 0.0,
+    }
+    loop = [labels["name"] for name, labels, _ in samples
+            if name == "wql_spans_loop_ms"]
+    assert sorted(loop) == sorted(set(wall) - {"codec.decode"})
+    flat = {name: v for name, labels, v in samples if not labels}
+    assert flat["wql_loop_time_busy_ms"] == 812.5
+    assert flat["wql_supervisor_tasks_unhealthy"] == 0
+    assert not any(name.startswith("wql_supervisor_tasks_")
+                   and name != "wql_supervisor_tasks_unhealthy"
+                   for name in types)
+
+
 def test_counters_and_gauges():
     m = Metrics()
     m.inc("a")
@@ -191,7 +228,18 @@ def test_server_metrics_endpoint():
                 ) as resp:
                     return json.loads(resp.read())
 
-            assert (await asyncio.to_thread(health)) == {"status": "ok"}
+            # the ticker is supervised, so the body carries the
+            # supervisor's block beside the status: what an operator's
+            # probe relies on is the status and that no task is down
+            body = await asyncio.to_thread(health)
+            assert body["status"] == "ok"
+            assert body["tasks_unhealthy"] == 0
+            assert body["supervisor"]["tasks"]["tick-batcher"]["critical"]
+            # tracing is off here: the queue-wait clock still runs (the
+            # one series this path pays for), the loop is not accounted
+            assert snap["latency"]["tick.queue_wait_ms"]["count"] == 1
+            assert "loop_time" not in snap["gauges"]
+            assert "spans" not in snap["gauges"]
             await a.close()
             await b.close()
         finally:

@@ -38,6 +38,11 @@ LATENCY_BUCKETS_MS = (
 )
 
 
+def _numeric(value) -> bool:
+    """What the text exposition can carry (a bool is an int: not it)."""
+    return isinstance(value, (int, float)) and not isinstance(value, bool)
+
+
 class Histogram:
     __slots__ = ("buckets", "counts", "total", "sum_ms", "max_ms")
 
@@ -246,7 +251,9 @@ class Metrics:
         dots to underscores under a ``wql_`` prefix; histograms emit
         the standard ``_bucket``/``_sum``/``_count`` series (bucket
         bounds in seconds, per convention); dict-valued gauges flatten
-        one level, non-numeric leaves are skipped."""
+        one level, non-numeric leaves are skipped; a gauge whose every
+        value is a dict is a table and renders as one ``name``-labelled
+        series a column."""
         out: list[str] = []
 
         def name_of(raw: str) -> str:
@@ -282,12 +289,35 @@ class Metrics:
             out.append(f"{n}_sum {sum_ms / 1e3:.6f}")
             out.append(f"{n}_count {total}")
         for raw, value in sorted(self._eval_gauges().items()):
+            if (
+                isinstance(value, dict) and value
+                and all(isinstance(row, dict) for row in value.values())
+            ):
+                # a table (``spans``: name -> {count, wall_ms, ...}):
+                # one labelled series a column, so a column has ONE
+                # ``# TYPE`` line however many rows there are
+                columns: dict[str, list] = {}
+                for row_name, row in value.items():
+                    for column, v in row.items():
+                        if _numeric(v):
+                            columns.setdefault(column, []).append(
+                                (row_name, v)
+                            )
+                for column, cells in sorted(columns.items()):
+                    n = name_of(f"{raw}.{column}")
+                    out.append(f"# TYPE {n} gauge")
+                    for row_name, v in sorted(cells):
+                        label = row_name.replace("\\", "\\\\").replace(
+                            '"', '\\"'
+                        )
+                        out.append(f'{n}{{name="{label}"}} {v}')
+                continue
             leaves = (
                 {f"{raw}.{k}": v for k, v in value.items()}
                 if isinstance(value, dict) else {raw: value}
             )
             for leaf, v in sorted(leaves.items()):
-                if isinstance(v, bool) or not isinstance(v, (int, float)):
+                if not _numeric(v):
                     continue
                 n = name_of(leaf)
                 out.append(f"# TYPE {n} gauge")

@@ -19,6 +19,7 @@ import time
 import uuid as uuid_mod
 from typing import Awaitable, Callable, Iterable
 
+from ..observability.spans import Tracer
 from ..protocol import Instruction, Message, serialize_message
 
 logger = logging.getLogger(__name__)
@@ -149,10 +150,15 @@ class PeerMap:
     """
 
     def __init__(self, on_remove: OnRemove | None = None, metrics=None,
-                 plane=None, sessions=None):
+                 plane=None, sessions=None, tracer=None):
         self._map: dict[uuid_mod.UUID, Peer] = {}
         self._on_remove = on_remove
         self.metrics = metrics
+        # Span tracing of a batched delivery's three legs
+        # (deliver.outbox / deliver.write / deliver.drain, nested under
+        # the ticker's tick.deliver). Absent or disabled: the shared
+        # no-op span, one branch a leg a flush.
+        self._tracer = tracer if tracer is not None else Tracer()
         # Optional delivery plane (delivery/plane.py): when present,
         # deliver_batch groups worker-owned targets per shard and
         # writes each frame ONCE per shard ring; parent-owned peers
@@ -288,7 +294,6 @@ class PeerMap:
                     errors += 1
                     logger.debug("broadcast error: %s", result)
         if self.metrics is not None:
-            self.metrics.inc("broadcast.messages")
             self.metrics.inc("broadcast.sends", n - errors)
             if errors:
                 self.metrics.inc("broadcast.send_errors", errors)
@@ -374,15 +379,10 @@ class PeerMap:
             span.tag(messages=n_msgs, worker_sends=worker_sends)
         n = worker_sends
         if local_pairs:
-            # counts its own broadcast.messages/sends for these pairs
+            # counts its own broadcast.sends for these pairs
             n += await self._deliver_batch_local(local_pairs, t_ingress_ns)
-        if self.metrics is not None:
-            if n_msgs > len(local_pairs):
-                self.metrics.inc(
-                    "broadcast.messages", n_msgs - len(local_pairs)
-                )
-            if worker_sends:
-                self.metrics.inc("broadcast.sends", worker_sends)
+        if self.metrics is not None and worker_sends:
+            self.metrics.inc("broadcast.sends", worker_sends)
         return n
 
     async def _deliver_batch_local(
@@ -391,32 +391,39 @@ class PeerMap:
         t_ingress_ns: int = 0,
     ) -> int:
         t_start_ns = time.monotonic_ns()
+        tracer = self._tracer
         outbox: dict[Peer, list[FramedPayload]] = {}
         n = n_msgs = 0
-        for message, uuids in pairs:
-            n_msgs += 1
-            data = message.wire
-            framed = FramedPayload(
-                serialize_message(message) if data is None else data
-            )
-            ctx = getattr(message, "trace_ctx", None)
-            if ctx is not None:
-                framed.ctx = ctx
-            for u in uuids:
-                p = self._map.get(u)
-                if p is None:
-                    if self._sessions is not None:
-                        self._sessions.note_undelivered(u)
-                    if self.on_frame_loss is not None:
-                        self.on_frame_loss(u)
-                    continue
-                n += 1
-                self.bytes_delivered += len(framed.payload)
-                outbox.setdefault(p, []).append(framed)
+        with tracer.span("deliver.outbox") as span:
+            bytes_before = self.bytes_delivered
+            for message, uuids in pairs:
+                n_msgs += 1
+                data = message.wire
+                framed = FramedPayload(
+                    serialize_message(message) if data is None else data
+                )
+                ctx = getattr(message, "trace_ctx", None)
+                if ctx is not None:
+                    framed.ctx = ctx
+                for u in uuids:
+                    p = self._map.get(u)
+                    if p is None:
+                        if self._sessions is not None:
+                            self._sessions.note_undelivered(u)
+                        if self.on_frame_loss is not None:
+                            self.on_frame_loss(u)
+                        continue
+                    n += 1
+                    self.bytes_delivered += len(framed.payload)
+                    outbox.setdefault(p, []).append(framed)
+            span.tag(frames=n, peers=len(outbox),
+                     bytes=self.bytes_delivered - bytes_before)
         slow: list[tuple[Peer, list[FramedPayload]]] = []
-        for p, framed_list in outbox.items():
-            if not p.try_write_many(framed_list):
-                slow.append((p, framed_list))
+        with tracer.span("deliver.write") as span:
+            for p, framed_list in outbox.items():
+                if not p.try_write_many(framed_list):
+                    slow.append((p, framed_list))
+            span.tag(peers=len(outbox), slow_peers=len(slow))
         errors = 0
         if slow:
             # SEQUENTIAL per peer: concurrent send() calls on one
@@ -436,12 +443,17 @@ class PeerMap:
                     # next interest frame must be a full resync
                     self.on_frame_loss(p.uuid)
                 return failed
-            for failed in await asyncio.gather(
-                *(drain_peer(p, fl) for p, fl in slow)
+            # the slow-path gather: every peer without a sync fast
+            # path (each ZeroMQ peer: one awaited send a frame)
+            with tracer.span(
+                "deliver.drain", slow_peers=len(slow),
+                frames=sum(len(fl) for _, fl in slow),
             ):
-                errors += failed
+                for failed in await asyncio.gather(
+                    *(drain_peer(p, fl) for p, fl in slow)
+                ):
+                    errors += failed
         if self.metrics is not None:
-            self.metrics.inc("broadcast.messages", n_msgs)
             self.metrics.inc("broadcast.sends", n - errors)
             if errors:
                 self.metrics.inc("broadcast.send_errors", errors)

@@ -112,13 +112,15 @@ class WorldQLServer:
         # Observability: the tracer ALWAYS exists (router/transports
         # test one `enabled` flag, no None checks on the hot path);
         # the flight recorder + loop monitor only when tracing is on.
-        from ..observability import FlightRecorder, LoopMonitor, Tracer
+        from ..observability import (
+            FlightRecorder, LoopAccount, LoopMonitor, Tracer,
+        )
         from ..observability.export import ProfilerHook
 
         self.tracer = Tracer(enabled=config.trace_enabled)
         self.recorder = None
         self.loop_monitor = None
-        self.profiler = ProfilerHook()
+        self.profiler = ProfilerHook(tracer=self.tracer)
         if config.trace_enabled:
             self.loop_monitor = LoopMonitor(metrics=self.metrics)
             self.recorder = FlightRecorder(
@@ -129,6 +131,9 @@ class WorldQLServer:
                 context=self.loop_monitor.snapshot,
             )
             self.tracer.on_trace = self.recorder.record
+            # the event loop's account (observability/loop_time.py),
+            # installed on the loop by start()
+            self.tracer.loop = LoopAccount()
         if hasattr(self.backend, "_note_failure"):  # ResilientBackend
             self.backend.metrics = self.metrics
         # Device telemetry (observability/device.py): compile/retrace
@@ -196,6 +201,7 @@ class WorldQLServer:
         self.peer_map = PeerMap(
             on_remove=self._on_peer_remove, metrics=self.metrics,
             plane=self.delivery_plane, sessions=self.sessions,
+            tracer=self.tracer,
         )
         # Overload control plane (robustness/overload.py): admission
         # governor for router, ticker and entity plane. None with
@@ -532,6 +538,11 @@ class WorldQLServer:
             self.metrics.gauge("device", self.device_telemetry.stats)
         if self.recorder is not None:
             self.metrics.gauge("flight_recorder", self.recorder.stats)
+            # every span since boot by name (count, wall, loop time):
+            # what the rings above cannot keep at thousands a second
+            self.metrics.gauge("spans", self.tracer.span_totals)
+            # the loop's time by layer, its busy and unattributed time
+            self.metrics.gauge("loop_time", self.tracer.loop.snapshot)
         if self.slo is not None:
             # per-objective burn state: numeric levels flatten to
             # wql_slo_<objective> (0 ok / 1 warn / 2 burning) + worst
@@ -763,6 +774,11 @@ class WorldQLServer:
 
     async def start(self) -> None:
         """Bring up the store and all enabled transports (main.rs:106-207)."""
+        if self.tracer.loop is not None:
+            # account the event loop BEFORE the first task is spawned:
+            # only tasks made after this are timed. Tracing off: no
+            # account exists, no factory is set, nothing is wrapped.
+            self.tracer.loop.install()
         failpoints.fire("store.init")
         await self.store.init()
         if self.wal is not None:
@@ -1124,6 +1140,8 @@ class WorldQLServer:
             await self.wal.close()
         await self.supervisor.stop()
         await self.store.close()
+        if self.tracer.loop is not None:
+            self.tracer.loop.uninstall()
 
     async def run_forever(self) -> None:
         """Serve until SIGINT/SIGTERM — or a supervisor escalation —

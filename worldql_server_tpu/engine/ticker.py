@@ -206,6 +206,8 @@ class TickBatcher:
         return len(self._inflight)
 
     async def enqueue(self, message: Message, query: LocalQuery) -> None:
+        # queue-wait clock: closed by the flush that takes the message
+        message.t_enqueue_ns = time.monotonic_ns()
         gov = self._governor
         if gov is not None:
             # Governed ingest (--overload on): NEVER await a flush
@@ -380,6 +382,7 @@ class TickBatcher:
                 # window is a config choice, not pipeline latency),
                 # closed at delivery completion on whichever path
                 t_ingress_ns = time.monotonic_ns()
+                self._note_queue_wait(batch, t_ingress_ns, trace)
                 sim_handle = self._sim_dispatch(trace)
                 skip_frames = self._frame_skip(sim_handle)
                 handle = None
@@ -488,19 +491,24 @@ class TickBatcher:
         if targets is None and not sim_pairs:
             return
         try:
-            pairs = self._build_pairs(batch, targets or [])
-            pairs.extend(sim_pairs)
-            # awaited in place below (shield loop) — not a dangling
-            # loop, so it rides outside the supervisor
-            deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
-                self.peer_map.deliver_batch(pairs, t_ingress_ns)
-            )
+            with trace.span("tick.build_pairs"):
+                pairs = self._build_pairs(batch, targets or [])
+                pairs.extend(sim_pairs)
             td = time.perf_counter()
             # same shield-and-re-await discipline as the sequential
             # flush: a cancellation must not abort the delivery tail
             # half-sent (fast-path frames are already in transport
             # buffers; re-sending would duplicate)
             with trace.span("tick.deliver"):
+                # made INSIDE the span: the delivery task's context
+                # then has tick.deliver open, so the delivery's own
+                # spans nest under it and its loop time is charged to
+                # them, while this waiting task is charged none.
+                # Awaited in place below (shield loop) — not a dangling
+                # loop, so it rides outside the supervisor
+                deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
+                    self.peer_map.deliver_batch(pairs, t_ingress_ns)
+                )
                 while not deliver_task.done():
                     try:
                         await asyncio.shield(deliver_task)
@@ -568,9 +576,7 @@ class TickBatcher:
                 and st.epoch_ok()
             ):
                 cols = st.swap()
-                self.staged_flushes += 1
-                if self.metrics is not None:
-                    self.metrics.inc("tick.staged_flushes")
+                self.staged_flushes += 1  # the `tick` gauge exports it
                 return self.backend.dispatch_staged_batch(
                     *cols, fallback=batch
                 )
@@ -633,6 +639,7 @@ class TickBatcher:
             trace = self._begin_trace(len(batch))
             t0 = time.perf_counter()
             t_ingress_ns = time.monotonic_ns()  # frame clock (see above)
+            self._note_queue_wait(batch, t_ingress_ns, trace)
 
             dispatched = not batch
             deliver_task = None
@@ -676,7 +683,8 @@ class TickBatcher:
                                 "tick.collect_ms", self.last_collect_ms
                             )
                     self._note_collect_stats(trace)
-                pairs = self._build_pairs(batch, targets)
+                with trace.span("tick.build_pairs"):
+                    pairs = self._build_pairs(batch, targets)
                 if sim_handle is not None:
                     pairs.extend(
                         await self._sim_collect_apply(
@@ -690,10 +698,11 @@ class TickBatcher:
                 # cancel must not abort the awaited (slow-path) tail
                 # half-sent — fast-path frames are already in
                 # transport buffers and re-sending would duplicate.
-                deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
-                    self.peer_map.deliver_batch(pairs, t_ingress_ns)
-                )
                 with trace.span("tick.deliver"):
+                    # made inside the span (see _collect_deliver_inner)
+                    deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
+                        self.peer_map.deliver_batch(pairs, t_ingress_ns)
+                    )
                     await asyncio.shield(deliver_task)
                 if self._cluster is not None and pairs:
                     # cluster.e2e_ms close at socket-write-complete
@@ -750,17 +759,27 @@ class TickBatcher:
             # overload state rides every tick trace: a slow-tick dump
             # answers "was the governor shedding?" without a scrape
             trace.tag(overload=self._governor.state)
-        if trace is not NULL_TRACE:
-            stats_fn = getattr(self.backend, "device_stats", None)
-            if stats_fn is not None:
-                try:
-                    trace.tags["device_stats_at_dispatch"] = {
-                        k: v for k, v in stats_fn().items()
-                        if isinstance(v, (int, float))
-                    }
-                except Exception:
-                    pass  # diagnostics must never cost the tick
         return trace
+
+    def _note_queue_wait(self, batch, t_flush_ns: int, trace) -> None:
+        """Close the batch's queue-wait clocks (enqueue → this flush's
+        start: the term of the delivery latency no flush stage holds,
+        and the one a faster flush shrinks twice over). One histogram
+        write a flush: the batch's mean, weighted by its size."""
+        if not batch or self.metrics is None:
+            return
+        n = len(batch)
+        mean_ms = (
+            t_flush_ns - sum(m.t_enqueue_ns for m, _ in batch) / n
+        ) / 1e6
+        self.metrics.observe_ms_n("tick.queue_wait_ms", mean_ms, n)
+        trace.tag(
+            queue_wait_mean_ms=round(mean_ms, 3),
+            # the queue is FIFO: its head waited longest
+            queue_wait_max_ms=round(
+                (t_flush_ns - batch[0][0].t_enqueue_ns) / 1e6, 3
+            ),
+        )
 
     def _account(
         self, batch, t0, deliver_ms: float | None = None, trace=NULL_TRACE,

@@ -10,7 +10,8 @@ This module is the bridge between those device-side facts and the
 PR 5 observability substrate:
 
 * **Compile events** — a ``jax.monitoring`` duration listener counts
-  every backend compile (``device.compiles`` counter +
+  every backend compile (``device.compiles`` counter,
+  ``device.compile_ms_total`` counter of whole ms +
   ``device.compile_ms`` histogram). The listener is module-global and
   fans out to the live :class:`DeviceTelemetry` instances (jax's
   listener list is append-only — there is no unregister — so instances
@@ -26,9 +27,10 @@ PR 5 observability substrate:
   happened.
 * **Per-tick device split** — :meth:`on_tick` tags the tick root trace
   with the backend's ``last_device_timing`` (encode_ms / h2d_ms /
-  compute_ms / d2h_ms, host-side brackets of the dispatch/collect
-  instrumentation points — see spatial/tpu_backend.py) and feeds the
-  ``device.{encode,h2d,compute,d2h}_ms`` histograms.
+  compute_ms / d2h_ms / decode_ms, host-side brackets of the
+  dispatch/collect instrumentation points — see
+  spatial/tpu_backend.py) and feeds the
+  ``device.{encode,h2d,compute,d2h,decode}_ms`` histograms.
 * **Live buffer gauge** — :func:`live_device_bytes` sums live jax
   array footprints at scrape time (the ``device`` gauge), without ever
   importing jax on its own: a CPU-backend server that never loaded jax
@@ -109,6 +111,7 @@ class DeviceTelemetry:
         self._pending_compile_ms = 0.0   # drained by the next poll
         self.compiles = 0
         self.compile_ms_total = 0.0
+        self._compile_ms_counted = 0
         self.retraces = 0
         # baseline at construction: warmup compiles that happened
         # before telemetry existed are not "retraces"
@@ -140,8 +143,16 @@ class DeviceTelemetry:
             self.compiles += 1
             self.compile_ms_total += ms
             self._pending_compile_ms += ms
+            # whole ms not yet counted: the counter below stays within
+            # 1 ms of the float total however many compiles there were
+            whole = int(self.compile_ms_total) - self._compile_ms_counted
+            self._compile_ms_counted += whole
         if self.metrics is not None:
             self.metrics.inc("device.compiles")
+            # the seconds compiles held their caller (at first use of a
+            # kernel variant: the event loop), as a counter a window's
+            # delta can be taken of
+            self.metrics.inc("device.compile_ms_total", whole)
             self.metrics.observe_ms("device.compile_ms", ms)
 
     def _drain_compile_ms(self) -> float:
@@ -202,7 +213,8 @@ class DeviceTelemetry:
                 for k, v in timing.items()
             })
             if self.metrics is not None:
-                for leg in ("encode_ms", "h2d_ms", "compute_ms", "d2h_ms"):
+                for leg in ("encode_ms", "h2d_ms", "compute_ms", "d2h_ms",
+                            "decode_ms"):
                     value = timing.get(leg)
                     if isinstance(value, (int, float)):
                         self.metrics.observe_ms(

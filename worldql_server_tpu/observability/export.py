@@ -12,13 +12,22 @@ show the wall time disappearing INSIDE a dispatch/collect, a
 ``POST /debug/profile`` round captures a ``jax.profiler`` trace
 (viewable in xprof/tensorboard) without restarting the server. jax is
 imported lazily so the debug surface itself never forces device
-bring-up.
+bring-up. The capture runs WITHOUT jax's python tracer unless asked
+(it records every python call: millions of events a second on the
+event loop, and a ``stop_trace`` that stalls it for seconds); instead,
+while a capture is active, every span of the server's ``Tracer`` opens
+a ``jax.profiler.TraceAnnotation`` of its name, so the program's spans
+sit on the profiler's host line, on the profiler's clock, beside the
+device's operations.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
+import time
+
+from .spans import NOOP_SPAN
 
 logger = logging.getLogger(__name__)
 
@@ -88,12 +97,15 @@ class ProfilerHook:
     aiohttp handlers run on the loop but tests poke it directly.
     """
 
-    def __init__(self):
+    def __init__(self, tracer=None):
         self._lock = threading.Lock()
+        #: the server's Tracer: its spans annotate the capture
+        self.tracer = tracer
         self.active_dir: str | None = None
         self.captures = 0
+        self.last_stop_ms = 0.0
 
-    def start(self, log_dir: str) -> None:
+    def start(self, log_dir: str, python_tracer: bool = False) -> None:
         with self._lock:
             if self.active_dir is not None:
                 raise RuntimeError(
@@ -101,9 +113,16 @@ class ProfilerHook:
                 )
             import jax
 
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
             self.active_dir = log_dir
-            logger.info("jax profiler capture started → %s", log_dir)
+            if self.tracer is not None and self.tracer.enabled:
+                self.tracer.annotate = jax.profiler.TraceAnnotation
+            logger.info(
+                "jax profiler capture started → %s (python tracer %s)",
+                log_dir, "on" if python_tracer else "off",
+            )
 
     def stop(self) -> str:
         with self._lock:
@@ -111,11 +130,28 @@ class ProfilerHook:
                 raise RuntimeError("no profiler capture in flight")
             import jax
 
-            jax.profiler.stop_trace()
+            span = NOOP_SPAN
+            if self.tracer is not None:
+                self.tracer.annotate = None
+                span = self.tracer.span("profile.stop")
+            t0 = time.perf_counter()
+            # spanned: what stopping cost the loop stays readable after
+            # the fact as `spans["profile.stop"].wall_ms`
+            with span:
+                jax.profiler.stop_trace()
+            self.last_stop_ms = (time.perf_counter() - t0) * 1e3
             log_dir, self.active_dir = self.active_dir, None
             self.captures += 1
-            logger.info("jax profiler capture stopped → %s", log_dir)
+            logger.info(
+                "jax profiler capture stopped → %s (stop_trace %.1f ms)",
+                log_dir, self.last_stop_ms,
+            )
             return log_dir
 
     def status(self) -> dict:
-        return {"active_dir": self.active_dir, "captures": self.captures}
+        return {
+            "active_dir": self.active_dir, "captures": self.captures,
+            # how long the last stop_trace held the caller (the event
+            # loop, from the HTTP hook)
+            "last_stop_ms": round(self.last_stop_ms, 3),
+        }

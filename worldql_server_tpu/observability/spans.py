@@ -27,6 +27,17 @@ Two entry points:
   the current trace if one is active, else records a single-span
   "loose" trace (per-message router handles, WAL fsyncs); finished
   root traces are handed to ``tracer.on_trace`` (the flight recorder).
+
+Beside the trees, the tracer keeps what no ring can lose (ISSUE 24):
+every span exit adds to a cumulative per-name total (``count``,
+``wall_ms``), exported as the ``spans`` gauge. A span around an
+``await`` gets its wall time there; the time it held the EVENT LOOP
+is accounted by ``loop_time.LoopAccount`` (``tracer.loop``), which
+span enter/exit tell who the innermost open span is. While a
+``jax.profiler`` capture runs (``tracer.annotate`` set by the
+``ProfilerHook``) each span also opens a ``TraceAnnotation`` of its
+name, so the program's spans sit on the profiler's host line, on the
+profiler's clock.
 """
 
 from __future__ import annotations
@@ -35,7 +46,7 @@ import contextvars
 import threading
 import time
 
-#: (Trace, parent_span_id) of the innermost open span, per context
+#: (Trace, span id, span name) of the innermost open span, per context
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "wql_current_span", default=None
 )
@@ -76,14 +87,19 @@ class Trace:
     """A finished-or-in-flight span tree (one tick, or one loose op)."""
 
     __slots__ = (
-        "name", "tags", "wall_start", "perf_start", "dur_ms", "spans",
-        "_lock", "_next_id", "_on_finish", "_done",
+        "name", "tags", "wall_start", "mono_start_ns", "perf_start",
+        "dur_ms", "spans", "_lock", "_next_id", "_on_finish", "_done",
+        "_owner",
     )
 
-    def __init__(self, name: str, on_finish=None, **tags):
+    def __init__(self, name: str, on_finish=None, tracer=None, **tags):
         self.name = name
         self.tags = tags
         self.wall_start = time.time()
+        # CLOCK_MONOTONIC, the clock of the frame stamps
+        # (t_ingress_ns, the enqueue stamp) and of a load generator's
+        # payload stamps: ring dumps lie on one axis with them
+        self.mono_start_ns = time.monotonic_ns()
         self.perf_start = time.perf_counter()
         self.dur_ms = 0.0
         self.spans: list[Span] = []
@@ -91,6 +107,9 @@ class Trace:
         self._next_id = 0
         self._on_finish = on_finish
         self._done = False
+        #: the Tracer whose totals / loop account / annotation switch
+        #: this trace's spans feed (None for a bare Trace in tests)
+        self._owner = tracer
 
     def span(self, name: str, **tags) -> "_SpanCtx":
         """Open a child span in THIS trace (parented to the innermost
@@ -137,6 +156,7 @@ class Trace:
             "name": self.name,
             "tags": self.tags,
             "start_unix_s": round(self.wall_start, 6),
+            "start_mono_ns": self.mono_start_ns,
             "dur_ms": round(self.dur_ms, 3),
             "spans": spans,
         }
@@ -147,7 +167,8 @@ class _SpanCtx:
     parent-link context var for the duration so nested ``tracer.span``
     calls attach underneath."""
 
-    __slots__ = ("_trace", "_name", "_tags", "_span", "_token", "_root")
+    __slots__ = ("_trace", "_name", "_tags", "_span", "_token", "_root",
+                 "_annotation")
 
     def __init__(self, trace: Trace, name: str, tags: dict, root=False):
         self._trace = trace
@@ -156,16 +177,25 @@ class _SpanCtx:
         self._span = None
         self._token = None
         self._root = root
+        self._annotation = None
 
     def __enter__(self):
         trace = self._trace
+        name = self._name
         cur = _CURRENT.get()
         parent = cur[1] if cur is not None and cur[0] is trace else None
+        owner = trace._owner
+        if owner is not None:
+            if owner.loop is not None:
+                owner.loop.span_entered(name)
+            if owner.annotate is not None:
+                self._annotation = owner.annotate(name)
+                self._annotation.__enter__()
         self._span = Span(
-            trace._new_id(), parent, self._name, time.perf_counter(),
+            trace._new_id(), parent, name, time.perf_counter(),
             self._tags, threading.current_thread().name,
         )
-        self._token = _CURRENT.set((trace, self._span.id))
+        self._token = _CURRENT.set((trace, self._span.id, name))
         return self._span
 
     def __exit__(self, *exc) -> bool:
@@ -173,6 +203,16 @@ class _SpanCtx:
         span.dur_ms = (time.perf_counter() - span.t0) * 1e3
         _CURRENT.reset(self._token)
         self._trace.add(span)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        owner = self._trace._owner
+        if owner is not None:
+            owner._total(span.name, span.dur_ms)
+            if owner.loop is not None:
+                cur = _CURRENT.get()
+                owner.loop.span_exited(
+                    span.name, cur[2] if cur is not None else None
+                )
         if self._root:
             self._trace.finish()
         return False
@@ -224,18 +264,30 @@ class Tracer:
     the disabled hot path pays; ``on_trace`` receives every finished
     root trace (the flight recorder's ``record``)."""
 
-    __slots__ = ("enabled", "on_trace")
+    __slots__ = ("enabled", "on_trace", "loop", "annotate", "_totals",
+                 "_totals_lock")
 
     def __init__(self, enabled: bool = False, on_trace=None):
         self.enabled = enabled
         self.on_trace = on_trace
+        #: loop_time.LoopAccount while the server's event loop is
+        #: accounted (tracing on, between server start and stop)
+        self.loop = None
+        #: ``jax.profiler.TraceAnnotation`` while a profiler capture
+        #: is active (set and cleared by the ProfilerHook), else None
+        self.annotate = None
+        #: span name -> [count, wall ms], every span since boot: the
+        #: rings keep the last N trees, these lose none (spans close
+        #: on the loop, the collect worker and the WAL writer thread)
+        self._totals: dict[str, list] = {}
+        self._totals_lock = threading.Lock()
 
     def begin(self, name: str, **tags):
         """Start an explicit trace (the tick root). Returns the shared
         null trace when disabled — callers never branch."""
         if not self.enabled:
             return NULL_TRACE
-        return Trace(name, on_finish=self._emit, **tags)
+        return Trace(name, on_finish=self._emit, tracer=self, **tags)
 
     def span(self, name: str, **tags):
         """A span in the current context's trace; with no trace active
@@ -246,8 +298,34 @@ class Tracer:
         cur = _CURRENT.get()
         if cur is not None:
             return _SpanCtx(cur[0], name, tags)
-        trace = Trace(name, on_finish=self._emit, **tags)
+        trace = Trace(name, on_finish=self._emit, tracer=self, **tags)
         return _SpanCtx(trace, name, tags, root=True)
+
+    def _total(self, name: str, dur_ms: float) -> None:
+        with self._totals_lock:
+            total = self._totals.get(name)
+            if total is None:
+                self._totals[name] = [1, dur_ms]
+            else:
+                total[0] += 1
+                total[1] += dur_ms
+
+    def span_totals(self) -> dict:
+        """The ``spans`` gauge: per span name ``count`` and ``wall_ms``
+        since boot, and (with the loop accounted) the time the name
+        held the event loop: ``loop_ms``, ``steps``, ``max_step_ms``.
+        Loop time outside any span is under ``task:<task name>``."""
+        with self._totals_lock:
+            out = {
+                name: {"count": count, "wall_ms": round(wall_ms, 3)}
+                for name, (count, wall_ms) in self._totals.items()
+            }
+        if self.loop is not None:
+            for name, held in self.loop.by_name().items():
+                out.setdefault(name, {"count": 0, "wall_ms": 0.0}).update(
+                    held
+                )
+        return out
 
     def _emit(self, trace: Trace) -> None:
         if self.on_trace is not None:

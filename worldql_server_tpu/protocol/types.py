@@ -148,6 +148,10 @@ class Message:
     #: serialized. None everywhere outside a cluster shard — the
     #: single-process paths pay one attribute read at most.
     trace_ctx: tuple | None = field(default=None, compare=False, repr=False)
+    #: ``time.monotonic_ns()`` at ``TickBatcher.enqueue`` (0 = never
+    #: queued): the flush that takes the message observes enqueue →
+    #: flush start into ``tick.queue_wait_ms``. Never serialized.
+    t_enqueue_ns: int = field(default=0, compare=False, repr=False)
 
     def with_(self, **kwargs) -> "Message":
         """Copy with replacements (Rust struct-update syntax analog).

@@ -357,10 +357,11 @@ def _multi_match(flat_args, ks):
     nseg = len(ks)
     na = SEG_ARRAYS
     queries = flat_args[na * nseg:]
-    parts = [
-        match_core(flat_args[na * i:na * i + na], *queries, k=ks[i])
-        for i in range(nseg)
-    ]
+    with jax.named_scope("match_dense"):
+        parts = [
+            match_core(flat_args[na * i:na * i + na], *queries, k=ks[i])
+            for i in range(nseg)
+        ]
     return parts[0] if nseg == 1 else jnp.concatenate(parts, axis=1)
 
 
@@ -451,8 +452,13 @@ def match_run_csr(flat_args, nseg, t_cap):
     na = SEG_ARRAYS
     segs = [tuple(flat_args[na * i:na * i + na]) for i in range(nseg)]
     queries = flat_args[na * nseg:]
-    los, cnts = run_bounds_all(segs, queries)
-    return run_csr_assemble(segs, los, cnts, cnts, queries, t_cap)
+    # named scopes: the stages' names ride every op's metadata into a
+    # profiler capture. They change no computation and no compile-cache
+    # key (the key is taken with locations stripped)
+    with jax.named_scope("run_bounds"):
+        los, cnts = run_bounds_all(segs, queries)
+    with jax.named_scope("csr_assemble"):
+        return run_csr_assemble(segs, los, cnts, cnts, queries, t_cap)
 
 
 def _repl_mask(vals, sender_col, repl_col):
@@ -505,14 +511,15 @@ def run_csr_assemble(segs, los, cnts, cnts_local, queries, t_cap):
     offs8 = jnp.arange(CSR_ROW, dtype=jnp.int32)[None, :]
     zone_a_parts = []
     for s, seg in enumerate(segs):
-        vals_a = _window_gather(seg[2], los[s], CSR_ROW)
-        valid_a = (
-            (offs8 < jnp.minimum(cnts[s], CSR_ROW)[:, None])
-            & (cnts_local[s] > 0)[:, None]
-            & (vals_a >= 0)
-            & _repl_mask(vals_a, q_sender[:, None], q_repl[:, None])
-        )
-        zone_a_parts.append(jnp.where(valid_a, vals_a, -1))
+        with jax.named_scope("zone_a"):
+            vals_a = _window_gather(seg[2], los[s], CSR_ROW)
+            valid_a = (
+                (offs8 < jnp.minimum(cnts[s], CSR_ROW)[:, None])
+                & (cnts_local[s] > 0)[:, None]
+                & (vals_a >= 0)
+                & _repl_mask(vals_a, q_sender[:, None], q_repl[:, None])
+            )
+            zone_a_parts.append(jnp.where(valid_a, vals_a, -1))
     # interleave query-major: row q*nseg + s
     zone_a = (
         zone_a_parts[0] if nseg == 1
@@ -600,10 +607,11 @@ def run_csr_assemble(segs, los, cnts, cnts_local, queries, t_cap):
                             (tail_chunk, n_full * chunk, n_tail)):
         if count:
             starts = n0 + size * jnp.arange(count, dtype=jnp.int32)
-            zone_b_parts.append(
-                jax.lax.map(make_chunk_fn(size), starts)
-                .reshape(count * size, CSR_ROW_B)
-            )
+            with jax.named_scope("zone_b"):
+                zone_b_parts.append(
+                    jax.lax.map(make_chunk_fn(size), starts)
+                    .reshape(count * size, CSR_ROW_B)
+                )
     zone_b = jnp.concatenate(zone_b_parts)[:rows_cap_b]
 
     flat = jnp.concatenate([
@@ -675,7 +683,8 @@ def pack_csr(counts, flat, *, bucket: int):
 
 @partial(jax.jit, static_argnames=("bucket",))
 def _pack_csr_kernel(counts, flat, *, bucket):
-    return pack_csr(counts, flat, bucket=bucket)
+    with jax.named_scope("pack_csr"):
+        return pack_csr(counts, flat, bucket=bucket)
 
 
 def padded_slots(counts: np.ndarray) -> int:
@@ -2612,7 +2621,7 @@ class TpuSpatialBackend(SpatialBackend):
             self.last_device_timing = {
                 "encode_ms": (time.perf_counter() - t_start) * 1e3,
                 "h2d_ms": 0.0, "d2h_enqueue_ms": 0.0,
-                "compute_ms": 0.0, "d2h_ms": 0.0,
+                "compute_ms": 0.0, "d2h_ms": 0.0, "decode_ms": 0.0,
                 "path": "reuse", "staged": True, "query_cap": 0,
             }
             return (m, ("tc", reused, None, None, (), (), (), seq_now),
@@ -2796,7 +2805,9 @@ class TpuSpatialBackend(SpatialBackend):
                 counts, grow=False,
                 delta_sub=bool(timing.get("delta_sub")),
             )
-            return self._decode_csr(counts, flat, m)
+            return self._timed_decode(
+                timing, self._decode_csr, counts, flat, m
+            )
         _, t_cap, (counts, flat, total), ctx = payload
         delta_sub = bool(timing.get("delta_sub"))
         t_wait = time.perf_counter()
@@ -2832,7 +2843,9 @@ class TpuSpatialBackend(SpatialBackend):
             )
             self.last_device_timing = timing
             self._note_fetch(int(tgt.size), 0)
-            return self._decode_csr(*_dense_to_csr(tgt), m)
+            return self._timed_decode(
+                timing, self._decode_csr, *_dense_to_csr(tgt), m
+            )
         # counts stays UNTRIMMED: padding queries resolve 0 rows, and
         # the sharded decode needs the full padded layout to locate
         # its per-batch-shard flat regions
@@ -2845,12 +2858,26 @@ class TpuSpatialBackend(SpatialBackend):
         if packed is not None:
             timing["d2h_ms"] = (time.perf_counter() - t_fetch) * 1e3
             self.last_device_timing = timing
-            return self._decode_packed(counts, packed, m)
+            return self._timed_decode(
+                timing, self._decode_packed, counts, packed, m
+            )
         self._note_fetch(t_cap, 0)
         flat_host = np.asarray(flat)  # wql: allow(jax-host-sync, full-fetch-on-tick) — compaction fallback (small tick / no 2x win / shard imbalance)
         timing["d2h_ms"] = (time.perf_counter() - t_fetch) * 1e3
         self.last_device_timing = timing
-        return self._decode_csr(counts, flat_host, m)
+        return self._timed_decode(
+            timing, self._decode_csr, counts, flat_host, m
+        )
+
+    @staticmethod
+    def _timed_decode(timing: dict, decode, *args):
+        """The collect's last leg: the fetched ids walked into per-query
+        UUID lists, bracketed into ``decode_ms`` of the tick's timing
+        (``timing`` IS the published ``last_device_timing``)."""
+        t_decode = time.perf_counter()
+        out = decode(*args)
+        timing["decode_ms"] = (time.perf_counter() - t_decode) * 1e3
+        return out
 
     def _compact_applicable(self, t_cap: int) -> bool:
         """Whether a tick at this capacity tier is worth compacting:

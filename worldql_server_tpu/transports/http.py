@@ -12,7 +12,9 @@ server→clients bridge (e.g. webhooks).
 
 from __future__ import annotations
 
+import asyncio
 import logging
+import time
 
 from aiohttp import web
 
@@ -234,7 +236,9 @@ class HttpTransport:
         """Device-level escalation: JSON ``{"action": "start", "dir":
         PATH}`` begins a jax.profiler capture, ``{"action": "stop"}``
         ends it (trace lands in the start dir, viewable with xprof/
-        tensorboard)."""
+        tensorboard). The server's spans annotate the capture's host
+        line; ``"python_tracer": true`` on start adds jax's python
+        tracer (every python frame: the loop crawls while it runs)."""
         if not self._authorized(request):
             return web.Response(status=401)
         try:
@@ -251,9 +255,11 @@ class HttpTransport:
                         {"error": "start requires a 'dir' string"},
                         status=400,
                     )
-                profiler.start(log_dir)
+                profiler.start(
+                    log_dir, python_tracer=body.get("python_tracer") is True
+                )
             elif action == "stop":
-                profiler.stop()
+                await self._stop_profile_off_loop(profiler)
             else:
                 return web.json_response(
                     {"error": "action must be 'start' or 'stop'"},
@@ -265,6 +271,33 @@ class HttpTransport:
             logger.exception("jax profiler hook failed")
             return web.json_response({"error": str(exc)}, status=500)
         return web.json_response(profiler.status())
+
+    async def _stop_profile_off_loop(self, profiler) -> None:
+        """``stop_trace`` collects and writes the capture (~0.5 s on a
+        v5e host even without the python tracer): on a worker thread,
+        so the loop it observed keeps serving. A 5 ms ticker beside it
+        observes the longest the loop was held meanwhile into
+        ``profile.stop_loop_stall_ms`` — the number that says whether
+        stopping a capture still wrecks what it observed."""
+        stall_ms = 0.0
+
+        async def watch() -> None:
+            nonlocal stall_ms
+            last = time.perf_counter()
+            while True:
+                await asyncio.sleep(0.005)
+                now = time.perf_counter()
+                stall_ms = max(stall_ms, (now - last) * 1e3 - 5.0)
+                last = now
+
+        watcher = asyncio.ensure_future(watch())  # wql: allow(unsupervised-task)
+        try:
+            await asyncio.to_thread(profiler.stop)
+        finally:
+            watcher.cancel()
+            self.server.metrics.observe_ms(
+                "profile.stop_loop_stall_ms", stall_ms
+            )
 
     async def _get_failpoints(self, request: web.Request) -> web.Response:
         if not self._authorized(request):
