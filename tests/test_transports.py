@@ -7,15 +7,19 @@ SURVEY §4 requires we exceed it).
 """
 
 import asyncio
+import contextlib
 import uuid
 
 import aiohttp
 import pytest
+import zmq
 
 pytest.importorskip("websockets")  # WS transport is half this module
 
 from tests.client_util import WsClient, ZmqClient, free_port
+from tests.test_robustness_zmq import wait_for
 from worldql_server_tpu.engine.config import Config
+from worldql_server_tpu.engine.peers import FramedPayload
 from worldql_server_tpu.engine.server import WorldQLServer
 from worldql_server_tpu.protocol import (
     Instruction,
@@ -466,3 +470,328 @@ def test_oversized_ws_frame_closes_only_that_connection():
         return True
 
     assert run(scenario())
+
+
+# region: the ZeroMQ peer's synchronous write path (ISSUE 25)
+
+
+@contextlib.asynccontextmanager
+async def zmq_served(**overrides):
+    """A ZeroMQ-only server and a ``connect()`` whose clients close with
+    it, pass or fail (a client context left open hangs the exit)."""
+    server = make_server(http_enabled=False, ws_enabled=False, **overrides)
+    clients = []
+
+    async def connect() -> ZmqClient:
+        clients.append(
+            await ZmqClient.connect(server.config.zmq_server_port))
+        return clients[-1]
+
+    await server.start()
+    try:
+        yield server, connect
+    finally:
+        for client in clients:
+            await client.close()
+        await server.stop()
+
+
+def frames(n: int, start: int = 0) -> list[Message]:
+    return [
+        Message(instruction=Instruction.LOCAL_MESSAGE, world_name="w",
+                position=Vector3(5, 5, 5), parameter=f"m{i}")
+        for i in range(start, start + n)
+    ]
+
+
+async def recv_parameters(client: ZmqClient, n: int) -> list[str]:
+    return [
+        (await client.recv_until(Instruction.LOCAL_MESSAGE)).parameter
+        for _ in range(n)
+    ]
+
+
+async def nothing_more(client: ZmqClient) -> bool:
+    try:
+        await client.recv_until(Instruction.LOCAL_MESSAGE, timeout=0.3)
+    except asyncio.TimeoutError:
+        return True
+    return False
+
+
+def delivery_counters(server) -> tuple[int, int]:
+    counters = server.metrics.counters
+    return (counters["delivery.sync_frames"],
+            counters["delivery.awaited_frames"])
+
+
+class PlainSocketSpy:
+    """Stands where a ZeroMQ peer's plain (shadow) socket does: counts
+    the sends that reach it and refuses the attempts it is told to
+    with ``zmq.Again``, as a socket at its high-water mark would."""
+
+    def __init__(self, real):
+        self.real = real
+        self.attempts = 0
+        self.refuse: set[int] = set()
+
+    def send(self, data, flags=0):
+        attempt = self.attempts
+        self.attempts += 1
+        if attempt in self.refuse:
+            raise zmq.Again()
+        return self.real.send(data, flags)
+
+
+@pytest.fixture
+def plain_sockets(monkeypatch):
+    """Every plain socket the transport makes over a peer's PUSH
+    socket, as spies, in the order of the handshakes. (pyzmq's own
+    asyncio sockets shadow an ADDRESS, and are left alone.)"""
+    made: list[PlainSocketSpy] = []
+    shadow = zmq.Socket.shadow
+
+    def spied(target):
+        real = shadow(target)
+        if not isinstance(target, zmq.Socket):
+            return real
+        made.append(PlainSocketSpy(real))
+        return made[-1]
+
+    monkeypatch.setattr(zmq.Socket, "shadow", staticmethod(spied))
+    return made
+
+
+def test_zmq_listener_queues_a_whole_deployment_dialling_at_once():
+    """One PULL listener for every peer: its accept queue holds as many
+    as one context can serve (libzmq's default of 100 made the rest of
+    a reconnect storm wait out TCP's SYN retries, up to 63 s)."""
+    async def scenario():
+        async with zmq_served() as (server, connect):
+            [transport] = server._transports
+            assert transport._pull.getsockopt(zmq.BACKLOG) >= 1023
+            await connect()
+            assert server.peer_map.size() == 1
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("n", [1, 50])
+def test_zmq_flush_writes_straight_into_the_socket(n):
+    """Over the real wire: the LocalMessages of ONE tick reach a ZeroMQ
+    peer once each and in order through the synchronous path: no
+    awaited frame, no ``deliver.drain`` in the tick's trace."""
+    async def scenario():
+        async with zmq_served(trace=True, tick_interval=30.0) as (
+                server, connect):
+            traces = []
+            record = server.tracer.on_trace
+
+            def keep(trace):
+                traces.append(trace)
+                record(trace)
+
+            server.tracer.on_trace = keep
+            sender, hearer = await connect(), await connect()
+            for z in (sender, hearer):
+                await z.send(Message(
+                    instruction=Instruction.AREA_SUBSCRIBE,
+                    world_name="w", position=Vector3(5, 5, 5)))
+            for message in frames(n):
+                await sender.send(message)
+            # the pump sleeps 30 s: this flush is the one tick
+            assert await wait_for(lambda: len(server.ticker._queue) == n)
+            sync0, awaited0 = delivery_counters(server)
+            await server.ticker.flush()
+            assert await recv_parameters(hearer, n) == [
+                f"m{i}" for i in range(n)]
+            assert await nothing_more(hearer)
+            sync1, awaited1 = delivery_counters(server)
+            assert (sync1 - sync0, awaited1 - awaited0) == (n, 0)
+            [tick] = [t for t in traces if t.name == "tick"
+                      and any(s.name == "deliver.write" for s in t.spans)]
+            spans = {s.name: s for s in tick.spans}
+            assert spans["deliver.write"].tags["sync_frames"] == n
+            assert spans["deliver.write"].tags["slow_peers"] == 0
+            assert "deliver.drain" not in spans
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("k", [0, 1, 13, 19])
+def test_zmq_high_water_mark_hands_the_rest_to_the_awaited_path(
+        k, plain_sockets):
+    """``zmq.Again`` at frame k of 20: frames 0..k-1 went to the socket
+    and are not sent again, k.. go through the awaited drain, and while
+    that drain is open a second flush neither overtakes it nor takes
+    the synchronous path."""
+    async def scenario():
+        async with zmq_served() as (server, connect):
+            z = await connect()
+            [spy] = plain_sockets
+            peer = server.peer_map.get(z.uuid)
+            # hold the awaited path shut, as a peer that does not read
+            gate = asyncio.Event()
+            send_raw = peer._send_raw
+
+            async def gated(data):
+                await gate.wait()
+                await send_raw(data)
+
+            peer._send_raw = gated
+            spy.refuse = {spy.attempts + k}
+            sync0, awaited0 = delivery_counters(server)
+            first = asyncio.ensure_future(server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in frames(20)]))
+            assert await wait_for(lambda: peer._drain is not None)
+            attempts = spy.attempts
+            second = asyncio.ensure_future(server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in frames(5, start=20)]))
+            await asyncio.sleep(0.05)
+            assert not first.done() and not second.done()
+            # a broadcast's single-frame path refuses too
+            assert not peer.try_write(FramedPayload(b"x"))
+            assert spy.attempts == attempts     # nothing tried the socket
+            gate.set()
+            assert await first == 20 and await second == 5
+            assert peer._drain is None
+            assert await recv_parameters(z, 25) == [
+                f"m{i}" for i in range(25)]
+            assert await nothing_more(z)
+            sync1, awaited1 = delivery_counters(server)
+            assert (sync1 - sync0, awaited1 - awaited0) == (k, 25 - k)
+            # with nothing owed the synchronous path takes over again
+            await server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in frames(3, start=25)])
+            assert delivery_counters(server) == (sync1 + 3, awaited1)
+            assert await recv_parameters(z, 3) == ["m25", "m26", "m27"]
+
+    run(scenario())
+
+
+def test_zmq_full_socket_waits_in_the_awaited_path_and_loses_nothing():
+    """A real socket at its real high-water mark: a peer that does not
+    read takes what libzmq and the kernel buffer, the flush waits for
+    the rest as it always did, a heartbeat is still answered behind
+    it, and once the peer reads every frame arrives once, in order."""
+    async def scenario():
+        async with zmq_served() as (server, connect):
+            z = await connect()
+            n, blob = 6000, "x" * 8192          # ~48 MB owed
+            messages = [
+                Message(instruction=Instruction.LOCAL_MESSAGE,
+                        world_name="w", position=Vector3(5, 5, 5),
+                        parameter=f"{i}:{blob}")
+                for i in range(n)
+            ]
+            sync0, awaited0 = delivery_counters(server)
+            flush = asyncio.ensure_future(server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in messages]))
+            peer = server.peer_map.get(z.uuid)
+            assert await wait_for(lambda: peer._drain is not None)
+            await asyncio.sleep(0.2)
+            assert not flush.done()             # it waits, it drops nothing
+            # a reply owed meanwhile queues behind the drain's frames
+            await z.send(Message(instruction=Instruction.HEARTBEAT))
+            got = []
+            while len(got) < n + 1:
+                message = await z.recv(timeout=10)
+                got.append(message.instruction.name if message.instruction
+                           != Instruction.LOCAL_MESSAGE
+                           else int(message.parameter.split(":", 1)[0]))
+            assert await flush == n
+            assert [g for g in got if g != "HEARTBEAT"] == list(range(n))
+            assert got.count("HEARTBEAT") == 1
+            sync1, awaited1 = delivery_counters(server)
+            assert sync1 - sync0 > 0 and awaited1 - awaited0 > 0
+            assert (sync1 - sync0) + (awaited1 - awaited0) == n
+            # the socket drained: the synchronous path is back
+            await server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in frames(3)])
+            assert delivery_counters(server) == (sync1 + 3, awaited1)
+            assert await recv_parameters(z, 3) == ["m0", "m1", "m2"]
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("n", [1, 6])
+def test_zmq_failed_synchronous_send_evicts_that_peer_alone(n):
+    """``transport.send=error:1:x1`` fires on the synchronous path: the
+    peer it hits is evicted as a failed awaited send evicts (counter,
+    ``remove_if``, loss hook, bytes taken back), the other peer of the
+    same flush gets every frame."""
+    from worldql_server_tpu.robustness import failpoints
+
+    async def scenario():
+        async with zmq_served() as (server, connect):
+            victim, other = await connect(), await connect()
+            losses = []
+            server.peer_map.on_frame_loss = losses.append
+            messages = frames(n)
+            size = len(serialize_message(messages[0]))
+            bytes0 = server.peer_map.bytes_delivered
+            sync0, awaited0 = delivery_counters(server)
+            fired0 = failpoints.registry.fired("transport.send")
+            failpoints.registry.set("transport.send", "error:1:x1")
+            try:
+                sent = await server.peer_map.deliver_batch(
+                    [(m, [victim.uuid, other.uuid]) for m in messages])
+            finally:
+                failpoints.registry.clear()
+            assert failpoints.registry.fired("transport.send") == fired0 + 1
+            assert sent == 2 * n
+            counters = server.metrics.counters
+            assert counters["peers.evicted_send_failed"] == 1
+            assert counters["broadcast.send_errors"] == n
+            assert losses == [victim.uuid]
+            assert server.peer_map.bytes_delivered - bytes0 == n * size
+            # the victim's frames failed in the awaited path, counted
+            assert delivery_counters(server) == (sync0 + n, awaited0 + n)
+            assert await recv_parameters(other, n) == [
+                f"m{i}" for i in range(n)]
+            assert await wait_for(
+                lambda: server.peer_map.get(victim.uuid) is None)
+            assert server.peer_map.get(other.uuid) is not None
+            [transport] = server._transports
+            assert victim.uuid not in transport._push_sockets
+            assert await nothing_more(victim)
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("case", ["plane-adopted", "closed", "socket-gone"])
+def test_zmq_synchronous_path_is_not_for(case, plain_sockets):
+    """A peer the delivery plane adopted writes to its worker's ring,
+    a closed peer and one whose socket is gone fall back to the awaited
+    path: none of them sends on the parent's plain socket."""
+    async def scenario():
+        adopted = case == "plane-adopted"
+        async with zmq_served(delivery_workers=int(adopted)) as (
+                server, connect):
+            z = await connect()
+            [spy] = plain_sockets
+            peer = server.peer_map.get(z.uuid)
+            [transport] = server._transports
+            if adopted:
+                assert peer.shard is not None
+                assert z.uuid not in transport._push_sockets
+                await asyncio.sleep(0.25)   # the worker connects its PUSH
+            elif case == "closed":
+                peer.closed = True
+            else:
+                transport._drop_socket(z.uuid)
+            sent = await server.peer_map.deliver_batch(
+                [(m, [z.uuid]) for m in frames(4)])
+            assert sent == 4 and spy.attempts == 0
+            if adopted:
+                assert await recv_parameters(z, 4) == [
+                    "m0", "m1", "m2", "m3"]
+            else:
+                assert delivery_counters(server) == (0, 4)
+                assert server.metrics.counters["broadcast.send_errors"] == 4
+                assert await nothing_more(z)
+
+    run(scenario())
+
+
+# endregion

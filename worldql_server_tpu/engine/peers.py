@@ -61,8 +61,12 @@ class FramedPayload:
 TryWrite = Callable[[FramedPayload], bool]
 
 #: batch variant: hand a peer's whole per-tick frame list to the
-#: transport in one write (writev-style) — all or nothing
-TryWriteMany = Callable[[list[FramedPayload]], bool]
+#: transport without awaiting. A transport whose write is one piece
+#: (WebSocket's ``writelines``) answers True or False for the whole
+#: list; one that writes a frame at a time (ZeroMQ: a message each)
+#: answers HOW MANY frames it took, from the front — the rest, and
+#: only the rest, is then owed through ``send_raw``, in order
+TryWriteMany = Callable[[list[FramedPayload]], "bool | int"]
 
 
 class Peer:
@@ -70,7 +74,7 @@ class Peer:
 
     __slots__ = ("uuid", "addr", "kind", "_send_raw", "_try_write",
                  "_try_write_many", "tracks_heartbeat", "last_heartbeat",
-                 "closed", "shard", "slot")
+                 "closed", "shard", "slot", "_drain")
 
     def __init__(
         self,
@@ -98,6 +102,11 @@ class Peer:
         # transport's own.
         self.shard: int | None = None
         self.slot: int | None = None
+        # Tail of the awaited drains deliver_batch owes this peer (a
+        # future its last one resolves), None when nothing is owed.
+        # While it is set the sync paths refuse, and a new drain
+        # starts when this one ends: a later frame never overtakes.
+        self._drain: asyncio.Future | None = None
 
     def update_last_heartbeat(self) -> None:
         self.last_heartbeat = time.monotonic()
@@ -122,20 +131,23 @@ class Peer:
 
     def try_write(self, framed: FramedPayload) -> bool:
         """Synchronous fast-path delivery; False = use ``send_raw``."""
-        if self.closed or self._try_write is None:
+        if (self.closed or self._try_write is None
+                or self._drain is not None):
             return False
         return self._try_write(framed)
 
-    def try_write_many(self, framed_list: list[FramedPayload]) -> bool:
-        """One coalesced write of a whole per-tick frame list; False =
-        deliver each frame via ``send_raw`` instead."""
-        if self.closed:
-            return False
+    def try_write_many(self, framed_list: list[FramedPayload]) -> int:
+        """Hand a whole per-tick frame list to the transport without
+        awaiting. Returns how many frames it took, from the front:
+        ``framed_list[taken:]`` is owed through ``send_raw``."""
+        if self.closed or self._drain is not None:
+            return 0
         if self._try_write_many is not None:
-            return self._try_write_many(framed_list)
+            taken = self._try_write_many(framed_list)
+            return len(framed_list) if taken is True else int(taken)
         if self._try_write is not None and len(framed_list) == 1:
-            return self._try_write(framed_list[0])
-        return False
+            return int(self._try_write(framed_list[0]))
+        return 0
 
     def __repr__(self) -> str:
         return f"Peer({self.kind}, {self.uuid}, {self.addr})"
@@ -312,12 +324,18 @@ class PeerMap:
           fan-out re-broadcasts the sender's bytes verbatim), skip
           re-serialization entirely;
         * frame once per transport kind (FramedPayload cache);
-        * ONE ``try_write_many`` per peer per tick — each peer's frames
-          coalesce into a single transport write (writev-style) instead
-          of one write per delivery.
-        Peers whose transport can't take the sync write (saturated, or
-        no fast path) fall back to awaited sends in one gather at the
-        end. ``t_ingress_ns`` is the batch's frame-clock stamp
+        * ONE ``try_write_many`` per peer per tick — a WebSocket peer's
+          frames coalesce into a single transport write (writev-style),
+          a ZeroMQ peer's go to its socket one non-blocking message
+          each, in one plain loop: no task, no await.
+        What a sync path does not take (a saturated or closing
+        transport, a ZeroMQ socket at its high-water mark: the frames
+        from the first refused one on) falls back to awaited sends in
+        one gather at the end, in order, and until that drain ends the
+        peer's sync paths refuse, so nothing overtakes it. Counters
+        ``delivery.sync_frames`` / ``delivery.awaited_frames`` say
+        which way the frames went. ``t_ingress_ns`` is the batch's
+        frame-clock stamp
         (``time.monotonic_ns`` at ticker flush start, 0 = unclocked):
         both paths close it at delivery completion into the
         ``frame.e2e_ms`` histogram — the honest dispatch→socket-write
@@ -418,45 +436,71 @@ class PeerMap:
                     outbox.setdefault(p, []).append(framed)
             span.tag(frames=n, peers=len(outbox),
                      bytes=self.bytes_delivered - bytes_before)
-        slow: list[tuple[Peer, list[FramedPayload]]] = []
+        # peers owed an awaited drain: (peer, the frames its sync path
+        # left, the drain to wait for, the future this one resolves)
+        slow: list[tuple[Peer, list[FramedPayload],
+                         asyncio.Future | None, asyncio.Future]] = []
+        awaited = 0
         with tracer.span("deliver.write") as span:
             for p, framed_list in outbox.items():
-                if not p.try_write_many(framed_list):
-                    slow.append((p, framed_list))
-            span.tag(peers=len(outbox), slow_peers=len(slow))
+                taken = p.try_write_many(framed_list)
+                if taken < len(framed_list):
+                    awaited += len(framed_list) - taken
+                    # joins the peer's chain of drains HERE, not at
+                    # the drain task's first step: from this line on
+                    # the peer's sync paths refuse
+                    prev, p._drain = p._drain, asyncio.Future()
+                    slow.append((p, framed_list[taken:], prev, p._drain))
+            span.tag(peers=len(outbox), slow_peers=len(slow),
+                     sync_frames=n - awaited)
         errors = 0
         if slow:
             # SEQUENTIAL per peer: concurrent send() calls on one
             # websockets connection raise ConcurrencyError (and would
             # reorder frames anyway); distinct peers still overlap
-            async def drain_peer(p: Peer, fl: list[FramedPayload]) -> int:
+            async def drain_peer(p: Peer, fl: list[FramedPayload],
+                                 prev: asyncio.Future | None,
+                                 done: asyncio.Future) -> int:
                 failed = 0
-                for f in fl:
-                    try:
-                        await p.send_raw(f.payload)
-                    except Exception as exc:
-                        failed += 1
-                        self.bytes_delivered -= len(f.payload)
-                        logger.debug("batch delivery error: %s", exc)
+                try:
+                    if prev is not None:
+                        await prev
+                    for f in fl:
+                        try:
+                            await p.send_raw(f.payload)
+                        except Exception as exc:
+                            failed += 1
+                            self.bytes_delivered -= len(f.payload)
+                            logger.debug("batch delivery error: %s", exc)
+                finally:
+                    if not done.done():
+                        done.set_result(None)
+                    if p._drain is done:
+                        p._drain = None
                 if failed and self.on_frame_loss is not None:
                     # the peer missed >= 1 frame of this batch: the
                     # next interest frame must be a full resync
                     self.on_frame_loss(p.uuid)
                 return failed
-            # the slow-path gather: every peer without a sync fast
-            # path (each ZeroMQ peer: one awaited send a frame)
+            # the slow-path gather: what a sync path did not take (a
+            # peer with none, a saturated or closing transport, a
+            # ZeroMQ socket at its high-water mark, a peer whose
+            # earlier drain is still running)
             with tracer.span(
-                "deliver.drain", slow_peers=len(slow),
-                frames=sum(len(fl) for _, fl in slow),
+                "deliver.drain", slow_peers=len(slow), frames=awaited,
             ):
                 for failed in await asyncio.gather(
-                    *(drain_peer(p, fl) for p, fl in slow)
+                    *(drain_peer(*owed) for owed in slow)
                 ):
                     errors += failed
         if self.metrics is not None:
             self.metrics.inc("broadcast.sends", n - errors)
             if errors:
                 self.metrics.inc("broadcast.send_errors", errors)
+            # how often the sync paths engage: frames a transport took
+            # without an await against frames owed through send_raw
+            self.metrics.inc("delivery.sync_frames", n - awaited)
+            self.metrics.inc("delivery.awaited_frames", awaited)
             # e2e stamps, closed at batch completion (the slow-path
             # drain included — fast-path frames already sat in their
             # transport buffers by then, so this is the conservative

@@ -52,6 +52,11 @@ from ..robustness import failpoints
 
 logger = logging.getLogger(__name__)
 
+#: the PULL listener's accept queue: as many as one zmq context holds
+#: sockets (ZMQ_MAX_SOCKETS, 1,023), so a whole deployment can dial at
+#: once (the kernel caps it at net.core.somaxconn)
+_LISTEN_BACKLOG = 1024
+
 #: longest the recv loop works through a backlog before it lets the
 #: rest of the event loop (ticker, HTTP, senders) run
 _RECV_YIELD_SECS = 0.01
@@ -97,6 +102,12 @@ class ZmqTransport:
         # senders are disconnected by libzmq; the PULL socket and every
         # other peer keep working.
         self._pull.setsockopt(zmq.MAXMSGSIZE, config.max_message_size)
+        # Every peer dials this ONE listener, and after a restart they
+        # all dial at once. libzmq's default backlog of 100 drops the
+        # SYNs beyond it, and TCP retries those 1, 3, 7, 15, 31, 63 s
+        # later: 896 peers took 68 s to connect, the last of them past
+        # their 120 s patience (PERF.md, PR 25).
+        self._pull.setsockopt(zmq.BACKLOG, _LISTEN_BACKLOG)
         self._pull.bind(f"tcp://{config.zmq_server_host}:{config.zmq_server_port}")
         logger.info(
             "ZeroMQ PULL server listening on %s:%s",
@@ -415,25 +426,65 @@ class ZmqTransport:
             )
         )
 
+        def evict() -> None:
+            # Failed send ⇒ evict peer (outgoing.rs:66-76) — but
+            # only while THIS binding is still current: a stale
+            # binding's dying send must not evict a resumed one.
+            self.server.metrics.inc("peers.evicted_send_failed")
+            self._drop_socket(peer_uuid)
+            task = asyncio.get_running_loop().create_task(  # wql: allow(unsupervised-task)
+                self.server.peer_map.remove_if(peer_uuid, peer)
+            )
+            self._evictions.add(task)
+            task.add_done_callback(self._evictions.discard)
+
+        awaited = 0  # send_raw calls that have not returned yet
+
         async def send_raw(data: bytes) -> None:
+            nonlocal awaited
             sock = self._push_sockets.get(peer_uuid)
             if sock is None:
                 raise ConnectionError("push socket gone")
+            awaited += 1
             try:
                 failpoints.fire("transport.send")
                 await sock.send(data)
             except Exception:
-                # Failed send ⇒ evict peer (outgoing.rs:66-76) — but
-                # only while THIS binding is still current: a stale
-                # binding's dying send must not evict a resumed one.
-                self.server.metrics.inc("peers.evicted_send_failed")
-                self._drop_socket(peer_uuid)
-                task = asyncio.get_running_loop().create_task(  # wql: allow(unsupervised-task)
-                    self.server.peer_map.remove_if(peer_uuid, peer)
-                )
-                self._evictions.add(task)
-                task.add_done_callback(self._evictions.discard)
+                evict()
                 raise
+            finally:
+                awaited -= 1
+
+        # The flush's way out: a plain socket over the same libzmq
+        # socket, so a send is one call and no Future. Never closed
+        # (closing a shadow closes what it shadows), never used once
+        # ``push`` has left ``_push_sockets``, and never while an
+        # awaited send is in flight: it would overtake that send, and
+        # take the socket's one edge-triggered wake-up from it.
+        send_now = zmq.Socket.shadow(push).send
+
+        def try_write_many(framed_list) -> int:
+            """Sync path: each frame its own message, non-blocking,
+            in order. Returns how many the socket took; the caller
+            owes the rest to ``send_raw``: after ``zmq.Again`` (the
+            high-water mark) they wait there as they always did,
+            after an eviction they fail there and are counted."""
+            if awaited or self._push_sockets.get(peer_uuid) is not push:
+                return 0
+            taken = 0
+            try:
+                for framed in framed_list:
+                    failpoints.fire("transport.send")
+                    send_now(framed.payload, zmq.DONTWAIT)
+                    taken += 1
+            except zmq.Again:
+                pass
+            except Exception:
+                evict()
+            return taken
+
+        def try_write(framed) -> bool:
+            return try_write_many((framed,)) == 1
 
         old = None
         if session is not None:
@@ -449,6 +500,8 @@ class ZmqTransport:
             send_raw=send_raw,
             kind="zeromq",
             tracks_heartbeat=True,
+            try_write=try_write,
+            try_write_many=try_write_many,
         )
         plane = getattr(self.server, "delivery_plane", None)
         adopted = plane is not None and plane.adopt(peer, endpoint=endpoint)
