@@ -4119,14 +4119,15 @@ def bench_config13(args) -> dict:
                         break
                     settled = observed[0]
                 mgr = server.interest
-                st = mgr._peers.get(observer.uuid)
                 ledger = {}
-                if st is not None:
-                    for key, (_wid, pos_b) in st.state.items():
-                        x, y, z = np.frombuffer(pos_b, np.float32)
-                        ledger[_uuid.UUID(bytes=key)] = (
-                            float(x), float(y), float(z)
-                        )
+                for key, (_wid, pos_b) in mgr.ledger(
+                    observer.uuid,
+                    server.entity_plane._peer_ids.get(observer.uuid, -1),
+                ).items():
+                    x, y, z = np.frombuffer(pos_b, np.float32)
+                    ledger[_uuid.UUID(bytes=key)] = (
+                        float(x), float(y), float(z)
+                    )
                 got = oracle.snapshot().get("bench", {})
                 s = oracle.stats()
                 parity = {

@@ -33,6 +33,7 @@ from worldql_server_tpu.interest import (
 from worldql_server_tpu.interest.manager import (
     DEMOTE_KEYFRAME,
     FRAME_CHUNK,
+    pack_entries,
     PARAM_DELTA,
     PARAM_FULL,
     PARAM_FULL_CONT,
@@ -507,7 +508,7 @@ def test_large_keyframe_chunks_full_then_fullc():
     assert len(rc.worlds["arena"]) == n
 
 
-def test_oversized_delta_escalates_to_epoch_bump_keyframes():
+def test_oversized_delta_ships_as_consecutive_delta_frames():
     plane = FakePlane(cap=2048)
     mgr = InterestManager()
     viewer = uuid.uuid4()
@@ -520,13 +521,15 @@ def test_oversized_delta_escalates_to_epoch_bump_keyframes():
     rc = ReplayClient()
     for m, _ in run_tick(mgr, plane, vis):
         rc.apply(m)
-    # every entity moves: a >FRAME_CHUNK delta beats no full frame —
-    # the manager DECLARES a resync instead of shipping a monster
+    # every entity moves: a >FRAME_CHUNK delta is chunked like a
+    # keyframe is, in the SAME epoch — escalating it to a keyframe made
+    # a peer that fell behind fall further (20K-entry keyframes a tick
+    # at 100K entities once a tick outgrew FRAME_CHUNK updates a peer)
     plane._pos[:n, 1] = 7.0
     pairs = run_tick(mgr, plane, vis)
     stamps = [parse_stamp(m.parameter) for m, _ in pairs]
-    assert stamps[0] == (PARAM_FULL, 2, 0)
-    assert all(s[1] == 2 for s in stamps)
+    assert stamps == [(PARAM_DELTA, 1, 2), (PARAM_DELTA, 1, 3)]
+    assert [len(m.entities) for m, _ in pairs] == [FRAME_CHUNK, 40]
     for m, _ in pairs:
         assert rc.apply(m)
     assert all(
@@ -536,6 +539,179 @@ def test_oversized_delta_escalates_to_epoch_bump_keyframes():
         )]) for i in range(n))
     )
     assert rc.stats()["deltas_refused"] == 0
+    assert mgr.stats()["resyncs"] == 0
+
+
+# endregion
+
+# region: the snapshot diff (synced peers keep no ledger of their own)
+
+
+def ledger_of(mgr, plane, peer):
+    """uuid -> position, read off the manager's side of the contract."""
+    return {
+        uuid.UUID(bytes=key): tuple(
+            float(v) for v in np.frombuffer(pos_b, np.float32))
+        for key, (_wid, pos_b) in mgr.ledger(
+            peer, plane._peer_ids[peer]).items()
+    }
+
+
+def test_a_synced_peer_holds_its_view_of_the_snapshot_and_no_dict():
+    plane = FakePlane()
+    mgr = InterestManager()
+    a, b = uuid.uuid4(), uuid.uuid4()
+    pa, pb = plane.pid(a), plane.pid(b)
+    ents = [uuid.uuid4() for _ in range(3)]
+    for i, e in enumerate(ents):
+        plane.put(i, e, (float(i), 0, 0))
+    rca, rcb = ReplayClient(), ReplayClient()
+    vis = {0: [pa, pb], 1: [pa], 2: [pb]}
+    for m, targets in run_tick(mgr, plane, vis):
+        (rca if a in targets else rcb).apply(m)
+    # first contact walked each ledger once; from here the snapshot IS it
+    for peer in (a, b):
+        assert mgr._peers[peer].synced and mgr._peers[peer].state is None
+    assert ledger_of(mgr, plane, a) == rca.snapshot()["arena"] == {
+        ents[0]: (0.0, 0, 0), ents[1]: (1.0, 0, 0)}
+    assert ledger_of(mgr, plane, b) == rcb.snapshot()["arena"]
+    assert mgr.stats()["near"] == 4            # rows x recipients
+
+    # one row moves: an entry at each recipient of THAT row, nobody else
+    plane._pos[1] = (1.0, 5.0, 0.0)
+    pairs = run_tick(mgr, plane, vis)
+    assert [(parse_stamp(m.parameter)[0], t) for m, t in pairs] == [
+        (PARAM_DELTA, [a])]
+    assert [e.uuid for e in pairs[0][0].entities] == [ents[1]]
+
+    # a loss: the peer's view is taken off the snapshot for the keyframe
+    # walk, then dropped again once the keyframe is committed
+    mgr.mark_resync(b)
+    plane._pos[2] = (2.0, 5.0, 0.0)
+    pairs = run_tick(mgr, plane, vis)
+    assert [parse_stamp(m.parameter) for m, _ in pairs] == [(PARAM_FULL, 2, 0)]
+    rcb.apply(pairs[0][0])
+    assert mgr._peers[b].synced and mgr._peers[b].state is None
+    assert ledger_of(mgr, plane, b) == rcb.snapshot()["arena"] == {
+        ents[0]: (0.0, 0, 0), ents[2]: (2.0, 5.0, 0)}
+    assert mgr.ledger(uuid.uuid4(), 7) == {}
+
+
+def test_reordered_targets_and_a_second_pid_cost_no_one_an_entry():
+    """The kNN lists a row's recipients nearest first, and a recipient
+    once per neighbour it owns: the order and the repeats change with
+    every step of a cube-mate; the SET is what a peer's view is."""
+    plane = FakePlane()
+    mgr = InterestManager()
+    a, b, c = (uuid.uuid4() for _ in range(3))
+    pa, pb, pc = plane.pid(a), plane.pid(b), plane.pid(c)
+    plane.put(0, uuid.uuid4(), (1, 1, 1))
+    plane.put(1, uuid.uuid4(), (2, 2, 2))
+    assert len(run_tick(mgr, plane, {0: [pa, pb, pb], 1: [pa]})) == 2
+    assert run_tick(mgr, plane, {0: [pb, pa, pa], 1: [pa, pa]}) == []
+    # a third recipient of row 0: a keyframe for it, nothing for a and b
+    pairs = run_tick(mgr, plane, {0: [pb, pc, pa], 1: [pa]})
+    assert [(parse_stamp(m.parameter)[0], t) for m, t in pairs] == [
+        (PARAM_FULL, [c])]
+    # ... and it leaves again: its tombstone alone
+    pairs = run_tick(mgr, plane, {0: [pa, pb], 1: [pa]})
+    assert [(parse_stamp(m.parameter)[0], t) for m, t in pairs] == [
+        (PARAM_DELTA, [c])]
+    assert pairs[0][0].entities[0].flex == b"\x00"
+    assert mgr.stats()["near"] == 3
+
+
+def test_slot_reuse_and_a_row_change_through_the_snapshot():
+    plane = FakePlane()
+    mgr = InterestManager()
+    viewer = uuid.uuid4()
+    vp = plane.pid(viewer)
+    e1, e2, e3 = uuid.uuid4(), uuid.uuid4(), uuid.uuid4()
+    plane.put(0, e1, (1, 0, 0))
+    plane.put(1, e2, (2, 0, 0))
+    rc = ReplayClient()
+    for m, _ in run_tick(mgr, plane, {0: [vp], 1: [vp]}):
+        rc.apply(m)
+    # e1 is removed and e3 takes its slot inside one tick; e2 re-registers
+    # into another row at the same position
+    plane.put(0, e3, (3, 0, 0))
+    plane.drop(1)
+    plane.put(5, e2, (2, 0, 0))
+    pairs = run_tick(mgr, plane, {0: [vp], 5: [vp]})
+    assert params(pairs) == [stamp(PARAM_DELTA, 1, 1)]
+    sent = {e.uuid: e.flex for e in pairs[0][0].entities}
+    assert sent == {e1: b"\x00", e3: None, e2: None}   # no tombstone for e2
+    rc.apply(pairs[0][0])
+    assert rc.snapshot() == {"arena": {e3: (3.0, 0, 0), e2: (2.0, 0, 0)}}
+    assert ledger_of(mgr, plane, viewer) == rc.snapshot()["arena"]
+    # the plane grows, and the targets get wider and narrower again
+    plane2 = FakePlane(cap=4096)
+    for name in ("_live", "_pos", "_uuid_bytes", "_wid"):
+        getattr(plane2, name)[:2048] = getattr(plane, name)
+    plane2._peer_ids, plane2._peer_uuids = plane._peer_ids, plane._peer_uuids
+    plane2.put(3000, e1, (9, 9, 9))
+    other = plane2.pid(uuid.uuid4())
+    pairs = run_tick(mgr, plane2, {0: [vp, other, other], 5: [vp], 3000: [vp]})
+    for m, targets in pairs:
+        if viewer in targets:
+            assert parse_stamp(m.parameter)[0] == PARAM_DELTA
+            assert [e.uuid for e in m.entities] == [e1]
+            rc.apply(m)
+    assert run_tick(mgr, plane2, {0: [vp, other], 5: [vp], 3000: [vp]}) == []
+    assert len(rc.snapshot()["arena"]) == 3
+    assert rc.stats()["deltas_refused"] == rc.stats()["gaps_seen"] == 0
+
+
+def test_degraded_cadence_takes_every_peer_off_the_snapshot_and_back():
+    plane = FakePlane()
+    mgr = InterestManager()
+    viewer = uuid.uuid4()
+    vp = plane.pid(viewer)
+    e = uuid.uuid4()
+    plane.put(0, e, (1, 0, 0))
+    rc = ReplayClient()
+    for m, _ in run_tick(mgr, plane, {0: [vp]}):
+        rc.apply(m)
+    assert mgr._peers[viewer].synced
+    mgr.note_governor(0, True)          # near rows every second tick
+    sent = 0
+    for step in range(4):
+        plane._pos[0] = (1, float(step + 1), 0)
+        pairs = run_tick(mgr, plane, {0: [vp]})
+        assert not mgr._peers[viewer].synced
+        assert mgr._peers[viewer].state is not None
+        for m, _ in pairs:
+            sent += 1
+            rc.apply(m)
+    assert sent == 2
+    mgr.note_governor(0, False)
+    plane._pos[0] = (1, 9.0, 0)
+    for m, _ in run_tick(mgr, plane, {0: [vp]}):
+        rc.apply(m)
+    assert mgr._peers[viewer].synced and mgr._peers[viewer].state is None
+    assert rc.snapshot() == {"arena": {e: (1.0, 9.0, 0.0)}}
+    assert ledger_of(mgr, plane, viewer) == rc.snapshot()["arena"]
+    assert rc.stats()["deltas_refused"] == 0
+
+
+@pytest.mark.parametrize("kw", [{"near_radius": 10.0},
+                                {"bandwidth_bytes": 1 << 20}])
+def test_cadences_and_budgets_keep_every_ledger_per_peer(kw):
+    """They defer single rows, so no two peers need hold what the
+    snapshot says: such a manager never syncs a peer to it."""
+    plane = FakePlane()
+    mgr = InterestManager(**kw)
+    viewer = uuid.uuid4()
+    vp = plane.pid(viewer)
+    e = uuid.uuid4()
+    plane.put(0, e, (1, 0, 0), owner=viewer)
+    run_tick(mgr, plane, {0: [vp]})
+    plane._pos[0] = (2, 0, 0)
+    run_tick(mgr, plane, {0: [vp]})
+    st = mgr._peers[viewer]
+    assert not st.synced and set(st.state) == {e.bytes}
+    assert mgr._snap.targets.size == 0
+    assert ledger_of(mgr, plane, viewer) == {e: (2.0, 0.0, 0.0)}
 
 
 # endregion
@@ -649,9 +825,11 @@ def test_template_native_matches_object_path_byte_for_byte():
     mgr = InterestManager()
     for entries in (_entries(3, tomb_every=2), _entries(1), []):
         plane._wire = wire
-        native = mgr._encode_template(plane, PARAM_DELTA, 0, entries)
+        native = mgr._encode_template(
+            plane, PARAM_DELTA, 0, *pack_entries(entries))
         plane._wire = None
-        obj = mgr._encode_template(plane, PARAM_DELTA, 0, entries)
+        obj = mgr._encode_template(
+            plane, PARAM_DELTA, 0, *pack_entries(entries))
         assert native == obj
         # and the patched result still deserializes with the stamp
         buf = bytearray(native[0])
@@ -779,14 +957,12 @@ def test_churn_property_replay_matches_ground_truth(delta_ticks):
             pid = plane._peer_ids.get(peer)
             if pid is None:
                 continue
-            st = mgr._peers.get(peer)
             expect = {}
-            if st is not None:
-                for key, (wid, pos_b) in st.state.items():
-                    x, y, z = np.frombuffer(pos_b, np.float32)
-                    expect[uuid.UUID(bytes=key)] = (
-                        float(x), float(y), float(z)
-                    )
+            for key, (wid, pos_b) in mgr.ledger(peer, pid).items():
+                x, y, z = np.frombuffer(pos_b, np.float32)
+                expect[uuid.UUID(bytes=key)] = (
+                    float(x), float(y), float(z)
+                )
             got = clients[peer].snapshot().get("w", {})
             assert got == expect, f"tick {t} peer divergence"
 
@@ -833,14 +1009,11 @@ def test_churn_ledger_equals_visible_set_without_lod():
             int(r) for r in np.flatnonzero(live)
             if pid in targets[r][targets[r] >= 0]
         }
-        st = mgr._peers.get(peer)
-        ledger_rows = set()
-        if st is not None:
-            key_to_row = {
-                plane._uuid_bytes[r].tobytes(): int(r)
-                for r in np.flatnonzero(live)
-            }
-            ledger_rows = {key_to_row[k] for k in st.state}
+        key_to_row = {
+            plane._uuid_bytes[r].tobytes(): int(r)
+            for r in np.flatnonzero(live)
+        }
+        ledger_rows = {key_to_row[k] for k in mgr.ledger(peer, pid)}
         assert ledger_rows == visible_rows
 
 

@@ -1329,7 +1329,7 @@ class EntityPlane:
             if self.metrics is not None:
                 self.metrics.inc("sim.frames_skipped")
         elif self.interest is not None:
-            pairs = self.interest.build_pairs(self, pos, targets, cap)
+            pairs = self.interest.build_pairs(self, pos, targets, cap, trace)
         else:
             pairs = self._build_frames(pos, targets, counts, cap)
 

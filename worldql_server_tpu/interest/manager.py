@@ -47,6 +47,17 @@ with a DIFF against the last state the peer provably received:
   ``delta.frames_reused`` from clean-cohort replay to dirty cohorts
   with identical diffs.
 
+* **one snapshot, not a ledger a peer** — with neither LOD cadence nor
+  budget configured every tick commits every visible row for every
+  peer, so each peer holds exactly its view of the columns the last
+  tick's diff read (:class:`_Snapshot`). The tick's diff is then the
+  rows that differ from the snapshot, for all peers at once
+  (:meth:`InterestManager._snapshot_specs`); a peer's ledger is walked
+  row by row (:meth:`InterestManager._walk_specs`) only at first
+  contact, on a resync and under a degraded near cadence. Cadences
+  and budgets defer single rows: such a manager walks every ledger
+  every tick, as it always did.
+
 This module is also the sequence-stamp authority: the ``tools/check``
 rule ``unsequenced-frame`` fails any stamped-frame parameter literal
 built outside it.
@@ -60,6 +71,7 @@ import uuid as uuid_mod
 
 import numpy as np
 
+from ..observability.spans import NULL_TRACE
 from ..protocol.types import NIL_UUID, Entity, Instruction, Message, Vector3
 
 logger = logging.getLogger(__name__)
@@ -138,6 +150,78 @@ class _WireFrame:
         return getattr(msg, name)
 
 
+def pack_entries(entries: list) -> tuple:
+    """``[(uuid16, wid, pos_f32_bytes, tombstone)]`` as the columns a
+    frame spec carries: ``(keys u8[n,16], pos f32[n,3], tomb u8[n])``."""
+    n = len(entries)
+    return (
+        np.frombuffer(b"".join(e[0] for e in entries), np.uint8).reshape(n, 16),
+        np.frombuffer(b"".join(e[2] for e in entries), np.float32).reshape(n, 3),
+        np.fromiter((e[3] for e in entries), np.uint8, n),
+    )
+
+
+def _fitted(a: np.ndarray, shape: tuple, fill) -> np.ndarray:
+    """``a`` grown to ``shape`` (never shrunk), new cells ``fill``."""
+    if a.shape == shape:
+        return a
+    out = np.full(shape, fill, a.dtype)
+    out[tuple(slice(0, n) for n in a.shape)] = a
+    return out
+
+
+def _entry_ids(pid, wid, keys) -> np.ndarray:
+    """(recipient, world, uuid) of each entry as one 24-byte value."""
+    return np.concatenate([
+        pid.astype(np.int32)[:, None].view(np.uint8),
+        wid.astype(np.int32)[:, None].view(np.uint8),
+        keys,
+    ], axis=1).view("V24").ravel()
+
+
+class _Snapshot:
+    """The columns the last tick's diff read, row by row. A ``synced``
+    peer holds exactly its view of them — the live rows whose targets
+    list it, each under that row's uuid, world and position — so the
+    next tick's diff is the rows that differ from here, and no ledger
+    is kept per peer."""
+
+    __slots__ = ("live", "keys", "wid", "pos", "targets")
+
+    def __init__(self):
+        self.live = np.zeros(0, bool)
+        self.keys = np.zeros((0, 16), np.uint8)
+        self.wid = np.zeros(0, np.int32)
+        self.pos = np.zeros((0, 3), np.float32)
+        self.targets = np.zeros((0, 0), np.int32)
+
+    def fit(self, cap: int, k: int) -> tuple[int, int]:
+        """Grow to at least ``cap`` rows of ``k`` targets."""
+        cap = max(cap, self.targets.shape[0])
+        k = max(k, self.targets.shape[1])
+        self.live = _fitted(self.live, (cap,), False)
+        self.keys = _fitted(self.keys, (cap, 16), 0)
+        self.wid = _fitted(self.wid, (cap,), -1)
+        self.pos = _fitted(self.pos, (cap, 3), 0.0)
+        self.targets = _fitted(self.targets, (cap, k), -1)
+        return cap, k
+
+    def ledgers_of(self, pids: list) -> dict:
+        """pid -> the ledger its view is: uuid16 bytes -> (wid,
+        pos_f32x3 bytes) of the live rows whose targets list it."""
+        mask = np.isin(self.targets, pids) & self.live[:, None]
+        rows = np.nonzero(mask)[0]
+        seen = self.targets[mask]
+        return {
+            pid: {
+                self.keys[r].tobytes(): (int(self.wid[r]),
+                                         self.pos[r].tobytes())
+                for r in np.unique(rows[seen == pid]).tolist()
+            }
+            for pid in pids
+        }
+
+
 class _PeerState:
     """One recipient's delivery ledger: the diff base (what the peer
     holds if it applied every frame), the epoch:seq cursor, the resync
@@ -151,13 +235,19 @@ class _PeerState:
     def __init__(self, now: float, burst: float):
         self.epoch = 0
         self.seq = 0
-        #: uuid16 bytes -> (wid, pos_f32x3 bytes) the peer holds
-        self.state: dict[bytes, tuple[int, bytes]] = {}
+        #: uuid16 bytes -> (wid, pos_f32x3 bytes) the peer holds; None
+        #: while ``synced``: the peer then holds exactly its view of the
+        #: manager's snapshot, and nothing is kept per peer
+        self.state: dict[bytes, tuple[int, bytes]] | None = {}
         self.resync = True          # first frame of a peer is a keyframe
         self.demote = DEMOTE_NONE
         self.tokens = burst
         self.refilled_at = now
         self.deferrals = 0
+
+    @property
+    def synced(self) -> bool:
+        return self.state is None
 
 
 class InterestManager:
@@ -186,6 +276,11 @@ class InterestManager:
         #: cohort template cache, swapped wholesale per tick like the
         #: plane's _frame_cache: content key -> (template, e_off, s_off)
         self._templates: dict = {}
+        #: the diff base of every synced peer, and how many rows list
+        #: each pid in it (neither LOD cadences nor budgets: those defer
+        #: single rows, so every peer's ledger is then its own)
+        self._snap = _Snapshot()
+        self._visible = np.zeros(0, np.int64)
         # counters / last-tick gauges
         self.resyncs = 0
         self.bytes_shed = 0
@@ -217,6 +312,18 @@ class InterestManager:
     def forget_peer(self, peer: uuid_mod.UUID) -> None:
         self._peers.pop(peer, None)
 
+    def ledger(self, peer: uuid_mod.UUID, pid: int) -> dict:
+        """What ``peer`` (the plane's ``pid``) holds if it applied every
+        frame: uuid16 bytes -> (wid, pos_f32x3 bytes). The parity
+        oracle's side of the contract (tests, chip_smoke); a synced
+        peer's is read off the snapshot."""
+        st = self._peers.get(peer)
+        if st is None or pid < 0:
+            return {}
+        if not st.synced:
+            return dict(st.state)
+        return self._snap.ledgers_of([pid])[pid]
+
     def note_governor(self, shed_level: int, tier_degraded: bool) -> None:
         """Overload coupling: SHED tiers widen the far cadence
         (k << level) and a degraded tick tier halves the near cadence —
@@ -228,21 +335,222 @@ class InterestManager:
 
     # region: frame building
 
-    def build_pairs(self, plane, pos, targets, cap: int) -> list:
+    def build_pairs(self, plane, pos, targets, cap: int,
+                    trace=None) -> list:
         """Replace ``EntityPlane._build_frames`` for one applied tick:
         per-recipient delta/full frames instead of per-entity
         broadcast. Returns the same ``(message, [target_uuid])`` pair
-        shape ``PeerMap.deliver_batch`` consumes."""
+        shape ``PeerMap.deliver_batch`` consumes. The two legs are
+        spans of the tick's ``trace`` (inside ``tick.sim.apply``):
+        ``tick.sim.interest.diff`` (who sees which rows, each peer's
+        ledger diff) and ``tick.sim.interest.encode``."""
         self._ticks += 1
+        if trace is None:
+            trace = NULL_TRACE
+        with trace.span("tick.sim.interest.diff"):
+            specs = self._diff_specs(plane, pos, targets, cap)
+        with trace.span("tick.sim.interest.encode"):
+            pairs = self._encode_specs(plane, specs)
+        self.last_bytes = sum(len(m.wire) for m, _ in pairs)
+        return pairs
+
+    def _diff_specs(self, plane, pos, targets, cap: int) -> list:
+        """Every recipient's frame decision for this tick:
+        ``[(uuid, state, frame_specs, new_state, is_resync, complete)]``
+        with a frame spec ``(kind, world, keys, pos, tomb)`` (columns of
+        :func:`pack_entries`). ``new_state`` None: the peer stays
+        synced to the snapshot; ``complete``: ``new_state`` is the
+        peer's whole view, so committing it syncs the peer."""
         live = plane._live[:cap]
-        valid = targets >= 0
+        targets = np.asarray(targets)[:cap]
+        self.last_near = self.last_far = self.last_demoted = 0
+        if self.near_radius > 0.0 or self.bandwidth_bytes:
+            specs, looked_at = self._walk_specs(
+                plane, pos, targets, live, None, False,
+            )
+        else:
+            specs, walk, looked_at = self._snapshot_specs(
+                plane, pos, targets, live,
+            )
+            if walk is None or walk:
+                walked, n = self._walk_specs(
+                    plane, pos, targets, live, walk,
+                    not self._tier_degraded,
+                )
+                specs += walked
+                looked_at += n
+            if not self._tier_degraded:
+                self.last_near = int(self._visible.sum())   # all near
+        if self.metrics is not None and looked_at:
+            self.metrics.inc("interest.rows_diffed", looked_at)
+        return specs
+
+    def _snapshot_specs(self, plane, pos, targets, live):
+        """Diff this tick's columns against the snapshot: the rows that
+        differ give every synced peer's delta at once (entered or moved
+        rows as positioned entries, rows that left or changed identity
+        as tombstones), then the snapshot takes the tick. Returns
+        ``(specs, walk, rows that differed)``; ``walk`` is the pids whose
+        ledger :meth:`_walk_specs` must walk instead (first contact,
+        resync), or None for every peer (degraded near cadence)."""
+        snap = self._snap
+        cap = len(live)
+        n, k = snap.fit(*targets.shape)     # rows past cap: dead now
+        uuids = plane._peer_uuids
+        live = _fitted(live, (n,), False)
+        targets = _fitted(targets, (n, k), -1)
+        keys = _fitted(plane._uuid_bytes[:cap], (n, 16), 0)
+        wid = _fitted(plane._wid[:cap], (n,), -1)
+        pos32 = _fitted(
+            np.ascontiguousarray(pos[:cap], np.float32), (n, 3), 0.0,
+        )
+
+        # who rides the snapshot this tick, whose ledger is walked
+        fast = np.zeros(len(uuids), bool)
+        walk: set[int] = set()
+        for u, st in self._peers.items():
+            pid = plane._peer_ids.get(u)
+            if pid is None:
+                continue
+            if st.synced and not st.resync and not self._tier_degraded:
+                fast[pid] = True
+            elif st.synced or st.state or (
+                pid < len(self._visible) and self._visible[pid] > 0
+            ):
+                walk.add(pid)
+
+        # rows that differ (bit for bit: -0.0 and NaN are positions
+        # too); a row whose targets only changed ORDER drops out below
+        moved = (
+            (live != snap.live) | (wid != snap.wid)
+            | (pos32.view(np.uint32) != snap.pos.view(np.uint32)).any(axis=1)
+            | (keys.view(np.uint64) != snap.keys.view(np.uint64)).any(axis=1)
+        )
+        cand = np.flatnonzero(
+            (moved | (targets != snap.targets).any(axis=1))
+            & (live | snap.live)
+        )
+        new_t = np.where(live[cand, None], targets[cand], -1)
+        old_t = np.where(snap.live[cand, None], snap.targets[cand], -1)
+        new_t.sort(axis=1)
+        old_t.sort(axis=1)
+        keep = moved[cand] | (new_t != old_t).any(axis=1)
+        rows, new_t, old_t = cand[keep], new_t[keep], old_t[keep]
+        m = len(rows)
+
+        def pairs(t):
+            """(pid, index into ``rows``) of a sorted targets block."""
+            first = np.ones(t.shape, bool)
+            first[:, 1:] = t[:, 1:] != t[:, :-1]
+            mask = first & (t >= 0)
+            at = np.broadcast_to(np.arange(m)[:, None], t.shape)[mask]
+            return t[mask].astype(np.int64) * m + at
+
+        specs: list = []
+        if m:
+            new_c, old_c = pairs(new_t), pairs(old_t)
+            stays = np.isin(new_c, old_c, assume_unique=True)
+            stayed = np.isin(old_c, new_c, assume_unique=True)
+            new_pid, new_at = np.divmod(new_c, m)
+            old_pid, old_at = np.divmod(old_c, m)
+            gained, lost = new_pid[~stays], old_pid[~stayed]
+            top = int(max(new_pid.max(initial=-1), old_pid.max(initial=-1))) + 1
+            self._visible = _fitted(
+                self._visible, (max(top, len(self._visible)),), 0,
+            )
+            self._visible += (
+                np.bincount(gained, minlength=len(self._visible))
+                - np.bincount(lost, minlength=len(self._visible))
+            )
+            for pid in np.unique(gained).tolist():
+                if pid < len(uuids) and not fast[pid]:
+                    walk.add(pid)
+
+            # entries of the peers that ride the snapshot
+            content = moved[rows]
+            rekeyed = (
+                (keys[rows].view(np.uint64)
+                 != snap.keys[rows].view(np.uint64)).any(axis=1)
+                | (wid[rows] != snap.wid[rows])
+            )
+            fast = _fitted(fast, (max(top, len(fast)),), False)
+            put = (~stays | content[new_at]) & fast[new_pid]
+            drop = (~stayed | rekeyed[old_at]) & fast[old_pid]
+            e_pid, e_row = new_pid[put], rows[new_at[put]]
+            t_pid, t_row = old_pid[drop], rows[old_at[drop]]
+            e_keys, e_wid = keys[e_row], wid[e_row]
+            t_keys, t_wid = snap.keys[t_row], snap.wid[t_row]
+            if len(t_pid) and len(e_pid):
+                # an entity that only changed row is no departure
+                gone = ~np.isin(_entry_ids(t_pid, t_wid, t_keys),
+                                _entry_ids(e_pid, e_wid, e_keys))
+                t_pid, t_row = t_pid[gone], t_row[gone]
+                t_keys, t_wid = t_keys[gone], t_wid[gone]
+            specs = self._delta_specs(
+                uuids,
+                np.concatenate([e_pid, t_pid]),
+                np.concatenate([e_wid, t_wid]),
+                np.concatenate([e_keys, t_keys]),
+                np.concatenate([pos32[e_row], snap.pos[t_row]]),
+                np.concatenate([np.zeros(len(e_pid), np.uint8),
+                                np.ones(len(t_pid), np.uint8)]),
+            )
+
+        # a synced peer about to be walked holds its view of the
+        # snapshot as it is NOW, before it takes this tick
+        behind = [pid for pid in (range(len(uuids)) if self._tier_degraded
+                                  else walk)
+                  if uuids[pid] in self._peers
+                  and self._peers[uuids[pid]].synced]
+        if behind:
+            for pid, held in snap.ledgers_of(behind).items():
+                self._peers[uuids[pid]].state = held
+        snap.live[cand] = live[cand]
+        snap.keys[cand] = keys[cand]
+        snap.wid[cand] = wid[cand]
+        snap.pos[cand] = pos32[cand]
+        snap.targets[cand] = targets[cand]
+        return specs, (None if self._tier_degraded else walk), m
+
+    def _delta_specs(self, uuids, pid, wid, keys, pos, tomb) -> list:
+        """Entries of many peers as per-peer delta frame specs: grouped
+        by recipient and world, ordered by uuid, ``FRAME_CHUNK`` a
+        frame."""
+        if not len(pid):
+            return []
+        be = np.ascontiguousarray(keys).view(">u8")
+        order = np.lexsort((be[:, 1], be[:, 0], wid, pid))
+        pid, wid = pid[order], wid[order]
+        keys, pos, tomb = keys[order], pos[order], tomb[order]
+        cut = np.flatnonzero((pid[1:] != pid[:-1]) | (wid[1:] != wid[:-1])) + 1
+        starts = np.concatenate(([0], cut)).tolist()
+        frames_of: dict[int, list] = {}
+        for a, b in zip(starts, starts[1:] + [len(pid)]):
+            frames_of.setdefault(int(pid[a]), []).extend(
+                (PARAM_DELTA, int(wid[a]), keys[c:min(c + FRAME_CHUNK, b)],
+                 pos[c:min(c + FRAME_CHUNK, b)],
+                 tomb[c:min(c + FRAME_CHUNK, b)])
+                for c in range(a, b, FRAME_CHUNK)
+            )
+        return [
+            (uuids[p], self._peers[uuids[p]], frames, None, False, True)
+            for p, frames in frames_of.items()
+        ]
+
+    def _walk_specs(self, plane, pos, targets, live, only, complete):
+        """Walk the ledgers of the peers ``only`` (None: every peer
+        that sees a row or holds one) against their visible rows.
+        Returns ``(specs, rows looked at)``."""
+        valid = targets >= 0 if only is None else np.isin(
+            targets, list(only),
+        )
         rows = np.flatnonzero(live & valid.any(axis=1))
 
         # invert row->targets into per-recipient visible row lists
         by_pid: dict[int, np.ndarray] = {}
         if rows.size:
             tgt = targets[rows]
-            mask = tgt >= 0
+            mask = valid[rows]
             r_idx = np.repeat(rows, tgt.shape[1])[mask.ravel()]
             p_idx = tgt.ravel()[mask.ravel()]
             order = np.argsort(p_idx, kind="stable")
@@ -256,17 +564,18 @@ class InterestManager:
 
         # peers with retained state but nothing visible still need
         # their departures delivered
-        peers = set(by_pid)
-        for u, st in self._peers.items():
-            if st.state:
-                pid = plane._peer_ids.get(u)
-                if pid is not None:
-                    peers.add(pid)
+        peers = set(only) if only is not None else set(by_pid)
+        if only is None:
+            for u, st in self._peers.items():
+                if st.state:
+                    pid = plane._peer_ids.get(u)
+                    if pid is not None:
+                        peers.add(pid)
 
         near_every = 2 if self._tier_degraded else 1
         far_every = self.far_every_k << self._shed_level
-        specs = []      # (uuid, st, frames_spec, new_state, committed_ticks)
-        self.last_near = self.last_far = self.last_demoted = 0
+        specs = []
+        looked_at = 0
         for pid in sorted(peers):
             if pid >= len(plane._peer_uuids):
                 continue
@@ -276,16 +585,24 @@ class InterestManager:
                 st = self._peers[u] = _PeerState(
                     self._clock(), self.bandwidth_burst
                 )
+            vrows = by_pid.get(pid)
+            if vrows is not None:
+                looked_at += int(vrows.size)
             spec = self._peer_spec(
-                plane, pos, pid, st, by_pid.get(pid),
-                near_every, far_every,
+                plane, pos, pid, st, vrows, near_every, far_every,
             )
             if spec is not None:
-                specs.append((u, st) + spec)
-
-        pairs = self._encode_specs(plane, specs)
-        self.last_bytes = sum(len(m.wire) for m, _ in pairs)
-        return pairs
+                frames, new_state, is_resync = spec
+                specs.append((
+                    u, st,
+                    [(kind, wid) + pack_entries(entries)
+                     for kind, wid, entries in frames],
+                    new_state, is_resync, complete,
+                ))
+            elif complete and not st.resync:
+                # nothing to say: the ledger IS the view
+                st.state = None
+        return specs, looked_at
 
     def _center_of(self, plane, pid: int):
         """The recipient's subscription center: centroid of its own
@@ -392,16 +709,13 @@ class InterestManager:
                 by_world.setdefault(wid, []).append((key, wid, pos_b, True))
         if not by_world:
             return None
-        total = sum(len(v) for v in by_world.values())
-        if total > FRAME_CHUNK:
-            # a delta this large beats no full frame — declare a
-            # resync (epoch bump) and ship chunked keyframes instead
-            frames = self._full_specs(new_state, st.state)
-            return frames, new_state, True
-        frames = [
-            (PARAM_DELTA, wid, sorted(entries))
-            for wid, entries in sorted(by_world.items())
-        ]
+        frames = []
+        for wid, entries in sorted(by_world.items()):
+            entries.sort()
+            frames += [
+                (PARAM_DELTA, wid, entries[c0:c0 + FRAME_CHUNK])
+                for c0 in range(0, len(entries), FRAME_CHUNK)
+            ]
         return frames, new_state, False
 
     def _full_specs(self, new_state, old_state):
@@ -431,16 +745,15 @@ class InterestManager:
         delivery pairs."""
         next_templates: dict = {}
         pairs = []
+        entries_sent = 0
         now = self._clock()
         self.last_delta_frames = self.last_full_frames = 0
-        for u, st, frames, new_state, is_resync in specs:
+        for u, st, frames, new_state, is_resync, complete in specs:
             encoded = []
             nbytes = 0
-            for kind, wid, entries in frames:
-                ckey = (kind, wid, b"".join(
-                    e[0] + e[2] + (b"\x01" if e[3] else b"\x00")
-                    for e in entries
-                ))
+            for kind, wid, keys, pos, tomb in frames:
+                ckey = (kind, wid, keys.tobytes(), pos.tobytes(),
+                        tomb.tobytes())
                 tpl = next_templates.get(ckey)
                 if tpl is None:
                     tpl = self._templates.get(ckey)
@@ -453,7 +766,9 @@ class InterestManager:
                     if self.metrics is not None:
                         self.metrics.inc("delta.frames_reused")
                 if tpl is None:
-                    tpl = self._encode_template(plane, kind, wid, entries)
+                    tpl = self._encode_template(
+                        plane, kind, wid, keys, pos, tomb,
+                    )
                 next_templates[ckey] = tpl
                 encoded.append((kind, tpl))
                 nbytes += len(tpl[0])
@@ -490,8 +805,14 @@ class InterestManager:
                     self.last_delta_frames += 1
                 else:
                     self.last_full_frames += 1
-            st.state = new_state
+            entries_sent += sum(len(frame[2]) for frame in frames)
+            if new_state is not None:
+                # a walked ledger; when it is the peer's whole view
+                # the peer is synced and the snapshot stands for it
+                st.state = None if complete else new_state
         self._templates = next_templates
+        if self.metrics is not None and entries_sent:
+            self.metrics.inc("interest.entries", entries_sent)
         return pairs
 
     def _afford(self, st, nbytes: int, now: float) -> bool:
@@ -508,7 +829,8 @@ class InterestManager:
             return True
         return False
 
-    def _encode_template(self, plane, kind: str, wid: int, entries):
+    def _encode_template(self, plane, kind: str, wid: int, keys, pos,
+                         tomb):
         """One cohort's wire bytes with a zeroed stamp, plus the byte
         offsets of the epoch/seq hex fields for per-peer patching.
         Native single-pass encode when the library has the symbol; the
@@ -517,29 +839,23 @@ class InterestManager:
             plane._world_names
         ) else ""
         placeholder = stamp(kind, 0, 0)
-        n = len(entries)
         wire = getattr(plane, "_wire", None)
         if wire is not None and getattr(wire, "can_encode_interest", False):
-            keys = np.empty((n, 16), np.uint8)
-            pos = np.empty((n, 3), np.float64)
-            tomb = np.zeros(n, np.uint8)
-            for i, (key, _wid, pos_b, dead) in enumerate(entries):
-                keys[i] = np.frombuffer(key, np.uint8)
-                pos[i] = np.frombuffer(pos_b, np.float32).astype(np.float64)
-                tomb[i] = 1 if dead else 0
             buf = wire.encode_interest_frame(
-                placeholder.encode(), world.encode(), keys, pos, tomb
+                placeholder.encode(), world.encode(),
+                np.ascontiguousarray(keys), pos.astype(np.float64),
+                np.ascontiguousarray(tomb),
             )
         else:
-            ents = []
-            for key, _wid, pos_b, dead in entries:
-                p = np.frombuffer(pos_b, np.float32)
-                ents.append(Entity(
-                    uuid=uuid_mod.UUID(bytes=key),
+            ents = [
+                Entity(
+                    uuid=uuid_mod.UUID(bytes=key.tobytes()),
                     position=Vector3(float(p[0]), float(p[1]), float(p[2])),
                     world_name=world,
                     flex=TOMBSTONE_FLEX if dead else None,
-                ))
+                )
+                for key, p, dead in zip(keys, pos, tomb)
+            ]
             from ..protocol import serialize_message
 
             buf = serialize_message(Message(
