@@ -1,16 +1,21 @@
 """Tick-batched LocalMessage routing (engine/ticker.py)."""
 
 import asyncio
+import statistics
+import time
 import uuid
 
 import pytest
 
 from worldql_server_tpu.engine.config import Config
+from worldql_server_tpu.engine.metrics import Metrics
 from worldql_server_tpu.engine.peers import Peer, PeerMap
 from worldql_server_tpu.engine.router import Router
 from worldql_server_tpu.engine.ticker import TickBatcher
 from worldql_server_tpu.protocol import deserialize_message
 from worldql_server_tpu.protocol.types import Instruction, Message, Vector3
+from worldql_server_tpu.robustness import failpoints
+from worldql_server_tpu.robustness.overload import OverloadGovernor
 from worldql_server_tpu.spatial.cpu_backend import CpuSpatialBackend
 from worldql_server_tpu.spatial.tpu_backend import TpuSpatialBackend
 from worldql_server_tpu.storage.memory_store import MemoryRecordStore
@@ -257,3 +262,243 @@ def test_second_cancel_still_completes_inflight_delivery():
         assert [m.parameter for m in h.locals_for(b)] == ["m0"]
 
     run(scenario())
+
+
+# region: the pump's deadline (ISSUE 28): a flush is due one interval
+# after the last one STARTED. Times are walls on a shared CPU, so every
+# bound leaves room: what is asserted is which of two designs ran (50
+# against 70 ms, 80 against 130), not a timer's precision.
+
+
+class SlowBackend:
+    """Resolves nothing; ``collect`` holds its worker thread for the
+    next of ``collect_s`` (the last one repeats). ``starts`` is the
+    loop's clock at every dispatch: a flush with work starts there."""
+
+    def __init__(self, *collect_s):
+        self.collect_s = list(collect_s)
+        self.starts = []
+        self.log = []
+
+    def dispatch_local_batch(self, queries):
+        self.starts.append(asyncio.get_running_loop().time())
+        self.log.append(("dispatch", len(self.starts)))
+        return queries
+
+    def collect_local_batch(self, handle):
+        n = len(self.starts) - 1
+        time.sleep(self.collect_s[min(n, len(self.collect_s) - 1)])
+        return [[] for _ in handle]
+
+
+class NoPeers:
+    bytes_delivered = 0
+
+    async def deliver_batch(self, pairs, t_ingress_ns=0):
+        pass
+
+
+class PumpHarness:
+    def __init__(self, *collect_s, interval=0.05, metrics=None, **kw):
+        self.backend = SlowBackend(*collect_s)
+        self.metrics = metrics if metrics is not None else Metrics()
+        self.ticker = TickBatcher(
+            self.backend, NoPeers(), interval, metrics=self.metrics, **kw
+        )
+        self._feeder = None
+
+    async def put(self, n=1):
+        for _ in range(n):
+            await self.ticker.enqueue(
+                Message(instruction=Instruction.LOCAL_MESSAGE), object()
+            )
+
+    def feed(self, every_s=0.005):
+        """Traffic all the while, so that every flush has work."""
+        async def feeder():
+            while True:
+                await self.put()
+                await asyncio.sleep(every_s)
+        self._feeder = asyncio.ensure_future(feeder())
+
+    async def run_until(self, flushes, timeout=20.0):
+        self.ticker.start()
+        deadline = time.monotonic() + timeout
+        while len(self.backend.starts) < flushes:
+            assert time.monotonic() < deadline, self.backend.starts
+            await asyncio.sleep(0.005)
+        if self._feeder is not None:
+            self._feeder.cancel()
+        await self.ticker.stop()
+
+    def periods_ms(self):
+        s = self.backend.starts
+        return [(b - a) * 1e3 for a, b in zip(s, s[1:])]
+
+    def counted(self):
+        snap = self.metrics.snapshot()
+        hist = snap["latency"].get("tick.period_ms", {"count": 0})
+        return hist, snap["counters"].get("tick.late_flushes", 0)
+
+
+def test_pump_short_flushes_start_one_interval_apart():
+    """A 20 ms flush at a 50 ms interval: flushes start 50 ms apart,
+    not 70 (the flush + a whole interval of sleep after it)."""
+    async def scenario():
+        h = PumpHarness(0.02)
+        h.feed()
+        await h.run_until(10)
+        periods = h.periods_ms()[:9]
+        assert 49.0 <= statistics.median(periods) < 62.0, periods
+        hist, late = h.counted()
+        # the pump's first flush has no earlier start to count from;
+        # stop()'s drain flush is not the pump's
+        pump_flushes = h.metrics.counters["tick.flushes"] - 1
+        assert hist["count"] in (pump_flushes - 1, pump_flushes), hist
+        assert hist["mean_ms"] == pytest.approx(
+            statistics.mean(h.periods_ms()[:hist["count"]]), abs=5.0)
+        assert late <= 2  # nothing overran (a hiccup of the box may)
+
+    run(scenario())
+
+
+class TurnsAfterAccount(Metrics):
+    """Makes a callback ready in the flush's LAST step (the account,
+    after its last await), which in turn makes a second one ready."""
+
+    def __init__(self, log):
+        super().__init__()
+        self.log = log
+
+    def inc(self, name, by=1):
+        super().inc(name, by)
+        if name == "tick.flushes":
+            k = self.counters[name]
+            loop = asyncio.get_running_loop()
+
+            def turn():
+                self.log.append(("turn", k))
+                loop.call_soon(self.log.append, ("turn after", k))
+
+            loop.call_soon(turn)
+
+
+def test_pump_long_flush_is_followed_at_once_after_one_turn():
+    """An 80 ms flush at a 50 ms interval: the next starts at once
+    (80 ms apart, not 130), after exactly ONE turn of the loop: what
+    was ready when the flush ended runs first, what THAT made ready
+    runs after the next flush's first step."""
+    async def scenario():
+        log = []
+        h = PumpHarness(0.08, metrics=TurnsAfterAccount(log))
+        h.backend.log = log
+        h.feed()
+        await h.run_until(6)
+        periods = h.periods_ms()[:5]
+        assert 79.0 <= statistics.median(periods) < 115.0, periods
+        for k in range(1, 5):
+            at = [log.index(e) for e in (
+                ("turn", k), ("dispatch", k + 1), ("turn after", k))]
+            assert at == sorted(at), (k, log)
+        hist, late = h.counted()
+        # every pump flush but the first began past its due time
+        assert late == hist["count"] >= 5
+
+    run(scenario())
+
+
+def test_pump_does_not_catch_up_after_one_long_flush():
+    """One 160 ms flush (three intervals' worth), then quick ones: the
+    flush after it starts at once, and the ones after THAT a whole
+    interval apart. Lateness is never repaid with a burst."""
+    async def scenario():
+        h = PumpHarness(0.16, 0.001)
+        h.feed()
+        await h.run_until(6)
+        periods = h.periods_ms()[:5]
+        assert 159.0 <= periods[0] < 200.0, periods
+        assert all(49.0 <= p < 100.0 for p in periods[1:]), periods
+        _, late = h.counted()
+        assert late == 1
+
+    run(scenario())
+
+
+def test_pump_size_triggered_flush_restarts_the_clock():
+    """A full queue flushes at once (the governed enqueue signals the
+    pump), and the NEXT flush is due one interval after that start,
+    neither at the old deadline nor an interval after it."""
+    async def scenario():
+        h = PumpHarness(
+            0.001, interval=0.3, max_batch=4,
+            governor=OverloadGovernor(max_batch=4, min_batch=4),
+        )
+        loop = asyncio.get_running_loop()
+        h.ticker.start()
+        t0 = loop.time()
+        await asyncio.sleep(0.1)
+        await h.put(4)              # the cap: flush now
+        await asyncio.sleep(0.05)
+        assert len(h.backend.starts) == 1
+        assert (h.backend.starts[0] - t0) * 1e3 < 250.0  # not the timer's 300
+        await h.put()               # rides the timer
+        await h.run_until(2)
+        [period] = h.periods_ms()
+        # the old deadline was 200 ms after the first start
+        assert 299.0 <= period < 400.0, period
+        hist, late = h.counted()
+        assert (hist["count"], late) == (1, 0)
+        assert hist["mean_ms"] == pytest.approx(period, abs=1.0)
+
+    run(scenario())
+
+
+def test_pump_period_counts_idle_starts_and_only_flushes_with_work():
+    """Messages 100 ms apart at a 25 ms interval: most flushes are
+    idle and count nowhere; each flush WITH work observes the pump's
+    period (idle flushes are starts too), not the gap between
+    messages."""
+    async def scenario():
+        h = PumpHarness(0.001, interval=0.025)
+        h.feed(every_s=0.1)
+        await h.run_until(5)
+        assert all(p > 60.0 for p in h.periods_ms()[:4]), h.periods_ms()
+        hist, late = h.counted()
+        assert 3 <= hist["count"] <= h.metrics.counters["tick.flushes"]
+        assert 24.0 <= hist["mean_ms"] < 40.0, hist
+        assert late <= 1
+
+    run(scenario())
+
+
+def test_pump_failpoint_kills_the_pump_outside_the_containment():
+    """`ticker.pump` fires between the wait and the flush, where no
+    handler contains it: the pump task itself dies (the supervisor's
+    case, tests/test_chaos.py), and a pump started again counts a new
+    clock from its own start: its first flush observes no period."""
+    async def scenario():
+        h = PumpHarness(0.001, interval=0.02)
+        failpoints.registry.reset()
+        try:
+            h.ticker.start()
+            await asyncio.sleep(0.05)
+            failpoints.registry.set("ticker.pump", "error:1:x1")
+            pump = h.ticker._task
+            for _ in range(400):
+                if pump.done():
+                    break
+                await asyncio.sleep(0.005)
+            assert isinstance(pump.exception(), failpoints.FailpointError)
+        finally:
+            failpoints.registry.reset()
+        await asyncio.sleep(0.1)    # far past any deadline of the old pump
+        await h.put()
+        h.ticker._task = None
+        await h.run_until(1)
+        hist, late = h.counted()
+        assert (hist["count"], late) == (0, 0)
+
+    run(scenario())
+
+
+# endregion

@@ -62,7 +62,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--zmq-timeout-secs", type=int)
     p.add_argument("--no-zmq", action="store_true")
     p.add_argument("--spatial-backend", choices=["cpu", "tpu", "sharded"])
-    p.add_argument("--tick-interval", type=float)
+    p.add_argument("--tick-interval", type=float,
+                   help="seconds between the STARTS of two batched-tick "
+                        "flushes while a flush fits in it (a longer "
+                        "flush is followed by the next at once); 0 "
+                        "(default) resolves each LocalMessage as it "
+                        "arrives")
     p.add_argument("--tick-pipeline", type=int,
                    help="max dispatched-but-undelivered ticks: 1 "
                         "(default) = sequential flush; 2 overlaps tick "

@@ -105,7 +105,9 @@ class Config:
     spatial_backend: str = field(
         default_factory=lambda: _env("WQL_SPATIAL_BACKEND", "cpu")
     )
-    # Batched-tick window in seconds for the TPU backend; 0 = flush
+    # Batched-tick period in seconds for the TPU backend: a flush
+    # starts one interval after the last one STARTED while flushes fit
+    # in it (a longer flush is followed by the next at once); 0 = flush
     # per message (reference-equivalent immediate semantics).
     tick_interval: float = field(
         default_factory=lambda: float(_env("WQL_TICK_INTERVAL", "0"))

@@ -9,6 +9,15 @@ in arrival order. Trade: up to one tick of added latency buys
 per-batch instead of per-message device cost — the design the
 1M-entity target requires (BASELINE.json north star).
 
+The pump (``_run``) keeps a deadline, not a sleep: ``tick_interval`` is
+the PERIOD between the starts of two flushes while a flush fits in it.
+A flush is due one interval after the last one started, so the pump
+sleeps only what the flush left of the interval; a flush that outran
+the interval is followed by the next after one turn of the loop (the
+period is then the flush itself, and nothing is caught up afterwards: a
+late start is simply the new start). Histogram ``tick.period_ms`` and
+counter ``tick.late_flushes`` say which of the two a server is in.
+
 Overlap: the dispatch (which reads loop-owned state) runs on the event
 loop; the device wait + UUID decode run on a worker thread, so the loop
 keeps serving transports while the device crunches. A full queue
@@ -174,6 +183,9 @@ class TickBatcher:
         # PeerMap.bytes_delivered high-water at the last _account —
         # diffed into the delivery.bytes_per_tick gauge
         self._bytes_mark = 0
+        # (period_ms | None, late) of the flush the pump is in, left by
+        # _run and counted by _note_period once the flush has work
+        self._pump_note: tuple[float | None, bool] | None = None
 
     def start(self) -> None:
         if self._sup is not None:
@@ -240,21 +252,48 @@ class TickBatcher:
                 await self.flush()
 
     async def _run(self) -> None:
+        loop = asyncio.get_running_loop()
+        # the pump's clock (the loop's monotonic one): a flush is due
+        # one interval after the last one STARTED. A (re)started pump
+        # knows no earlier start and owes its first flush an interval
+        # from now.
+        last_start = None
+        due = loop.time() + self.interval
         while True:
-            # the timer OR a size-triggered flush request, whichever
-            # lands first — a full queue flushes immediately without
-            # the recv path ever blocking on it
-            try:
-                await asyncio.wait_for(
-                    self._flush_request.wait(), timeout=self.interval
-                )
-            except asyncio.TimeoutError:
-                pass
+            remaining = due - loop.time()
+            late = remaining <= 0
+            if late:
+                # the last flush outran the interval and this one is
+                # already due: give every ready task (the recv loop
+                # among them, which hands the loop back within
+                # _RECV_YIELD_SECS) exactly one turn, then flush
+                await asyncio.sleep(0)
+            else:
+                # what is left of the interval OR a size-triggered
+                # flush request, whichever lands first — a full queue
+                # flushes immediately without the recv path ever
+                # blocking on it
+                try:
+                    await asyncio.wait_for(
+                        self._flush_request.wait(), timeout=remaining
+                    )
+                except asyncio.TimeoutError:
+                    pass
             self._flush_request.clear()
             # deliberately OUTSIDE the containment below: an armed
             # `ticker.pump` failpoint kills the pump itself, which is
             # how the chaos suite drives supervisor restart/escalation
             failpoints.fire("ticker.pump")
+            # every start, early (size-triggered) or late, restarts the
+            # clock: lateness is never repaid with a burst of short
+            # ticks
+            start = loop.time()
+            self._pump_note = (
+                None if last_start is None else (start - last_start) * 1e3,
+                late,
+            )
+            last_start = start
+            due = start + self.interval
             try:
                 if self.pipeline > 1:
                     await self.flush_pipelined()
@@ -262,6 +301,10 @@ class TickBatcher:
                     await self.flush()
             except Exception:
                 logger.exception("tick flush failed — batch dropped")
+            finally:
+                # an idle flush opens no trace and leaves the note: a
+                # flush that is not the pump's must not count it
+                self._pump_note = None
 
     # region: entity-sim stages (--entity-sim)
 
@@ -751,6 +794,7 @@ class TickBatcher:
         """Open this flush's "tick" trace (the shared null trace when
         tracing is off — one branch inside Tracer.begin, per flush)."""
         self._tick_seq += 1
+        self._note_period()
         trace = self._tracer.begin(
             "tick", tick=self._tick_seq, batch=batch_size,
             inflight=len(self._inflight), pipeline=self.pipeline,
@@ -760,6 +804,25 @@ class TickBatcher:
             # answers "was the governor shedding?" without a scrape
             trace.tag(overload=self._governor.state)
         return trace
+
+    def _note_period(self) -> None:
+        """Count the pump's note for this flush: ``tick.period_ms``,
+        the start of the pump's last flush to the start of this one
+        (idle flushes are starts too), and ``tick.late_flushes``, a
+        start past its due time. Reached from ``_begin_trace``, which
+        both flush variants pass once, inside the pump's own call, exactly
+        when the flush has work: the flushes ``tick.flushes`` counts. A
+        flush that is not the pump's (``stop``'s drain, the ungoverned
+        size cap) finds no note."""
+        note, self._pump_note = self._pump_note, None
+        if note is None or self.metrics is None:
+            return
+        period_ms, late = note
+        if period_ms is not None:
+            # the time BETWEEN two ticks' roots: no span can hold it
+            self.metrics.observe_ms("tick.period_ms", period_ms)  # wql: allow(unspanned-stage)
+        # by 0 too: the series is there from the pump's first flush
+        self.metrics.inc("tick.late_flushes", int(late))
 
     def _note_queue_wait(self, batch, t_flush_ns: int, trace) -> None:
         """Close the batch's queue-wait clocks (enqueue → this flush's
