@@ -3179,10 +3179,7 @@ def bench_config9(args) -> dict:
 
             async def drain():
                 for _ in range(1000):
-                    if (
-                        not server.ticker._queue
-                        and not server.ticker.inflight()
-                    ):
+                    if not server.ticker._queue:
                         return
                     await asyncio.sleep(0.01)
 

@@ -73,7 +73,7 @@ def chaos_config(tmp_path, **overrides) -> Config:
         http_enabled=True, http_host="127.0.0.1", http_port=free_port(),
         ws_enabled=False,
         zmq_server_host="127.0.0.1", zmq_server_port=free_port(),
-        tick_interval=0.02, tick_pipeline=2,
+        tick_interval=0.02,
         spatial_backend="cpu",
         resilience="on", failover_after=100,
         supervisor_budget=20, supervisor_backoff=0.005,

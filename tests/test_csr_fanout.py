@@ -1,4 +1,4 @@
-"""CSR / sparse result-compaction paths (spatial/tpu_backend.py).
+"""CSR result-compaction path (spatial/tpu_backend.py).
 
 The CSR layout is what the bench and distributed delivery consume; the
 run-window assembly (counts = RAW run lengths, per-(query, segment)
@@ -381,28 +381,11 @@ def test_sharded_tiny_multiseg_tick_with_decayed_cap():
     assert len(got) == 5 and all(len(g) >= 1 for g in got)
 
 
-def test_sparse_path_matches_dense():
-    b, sub_pos, peers = build_hot_cold(hot_cubes=2, hot_occupancy=20)
-    rng = np.random.default_rng(17)
-    qidx = rng.integers(0, len(sub_pos), 100)
-    batch = query_batch(b, sub_pos[qidx], [peers[i] for i in qidx])
-    dense = b.match_arrays(*batch)
-    m, res = b.match_arrays_async(*batch, max_hits=256)
-    rows, targets, n_hits = res
-    rows = np.asarray(rows)[:int(n_hits)]
-    targets = np.asarray(targets)[:int(n_hits)]
-    want = dense_lists(dense)
-    got = {int(r): sorted(int(t) for t in row if t >= 0)
-           for r, row in zip(rows, targets)}
-    for i, w in enumerate(want):
-        assert got.get(i, []) == w
-
-
 def test_key1_collision_rejected_by_second_key():
     """The exactness contract: a query whose FIRST key matches a
     stored run but whose second key differs (the absent-cube collision
-    case, ~2^-64) must resolve empty — on the dense, CSR, and sparse
-    paths alike."""
+    case, ~2^-64) must resolve empty — on the dense and CSR paths
+    alike."""
     from worldql_server_tpu.spatial.hashing import (
         PAD_KEY, QUERY_PAD_KEY2, next_pow2, pad_to,
     )
@@ -428,8 +411,6 @@ def test_key1_collision_rejected_by_second_key():
     assert (dense == -1).all()
     counts, flat, total = b._dispatch_csr(queries, segs, ks, kinds, 1024)
     assert int(total) == 0 and int(np.asarray(counts)[:m].sum()) == 0
-    rows, targets, n_hits = b._dispatch_sparse(queries, segs, ks, kinds, 64)
-    assert int(n_hits) == 0
     # and the same queries with the TRUE key2 resolve non-empty
     queries_ok = (queries[0], pad_to(stored_k2, cap, QUERY_PAD_KEY2),
                   queries[2], queries[3])

@@ -202,7 +202,7 @@ def test_kill_worker_evicts_shard_and_keeps_delivering():
             )
             for uuid in victims:
                 assert server.peer_map.get(uuid) is None
-            # no tick-pipeline stall: every recorded tick.deliver span
+            # the tick path did not stall: every recorded tick.deliver span
             # stayed far below the eviction window
             ticks = server.recorder.snapshot()
             assert ticks, "flight recorder captured no ticks"

@@ -330,31 +330,6 @@ def test_forced_slow_tick_dump_attributes_90pct_to_stages(tmp_path):
     assert delivered == 1
 
 
-def test_pipelined_ticks_record_traces_too(tmp_path):
-    tracer = Tracer(enabled=True)
-    rec = FlightRecorder(depth=8, slow_tick_ms=None, dump_dir=str(tmp_path))
-    tracer.on_trace = rec.record
-
-    async def scenario():
-        h = _TickHarness(tracer)
-        h.ticker.pipeline = 2
-        pos = Vector3(5, 5, 5)
-        a = await h.add_subscribed_peer(pos)
-        await h.add_subscribed_peer(pos)
-        for _ in range(3):
-            await h.queue_local(a, pos)
-            await h.ticker.flush_pipelined()
-        await h.ticker.stop()
-
-    run(scenario())
-    snap = rec.snapshot()
-    assert len(snap) == 3
-    for t in snap:
-        names = {s["name"] for s in t["spans"]}
-        assert {"tick.dispatch", "tick.collect", "tick.deliver"} <= names
-        assert t["tags"]["pipeline"] == 2
-
-
 def test_tracing_disabled_records_nothing():
     async def scenario():
         h = _TickHarness(tracer=None)

@@ -166,7 +166,6 @@ def test_overload_storm_survival_accounting_recovery():
                     server.metrics.counters["messages.local_message"]
                     == offered
                     and not server.ticker._queue
-                    and not server.ticker.inflight()
                     and not server.ticker._flushing.locked()
                 ):
                     break

@@ -53,7 +53,6 @@ def test_hot_kernels_are_registered():
     for family in (
         "tpu_backend.match_dense",
         "tpu_backend.match_run_csr",
-        "tpu_backend.match_sparse",
         "tpu_backend.device_compact",
     ):
         assert family in families

@@ -2,6 +2,7 @@
 
 import asyncio
 import statistics
+import threading
 import time
 import uuid
 
@@ -12,6 +13,7 @@ from worldql_server_tpu.engine.metrics import Metrics
 from worldql_server_tpu.engine.peers import Peer, PeerMap
 from worldql_server_tpu.engine.router import Router
 from worldql_server_tpu.engine.ticker import TickBatcher
+from worldql_server_tpu.observability.spans import Tracer
 from worldql_server_tpu.protocol import deserialize_message
 from worldql_server_tpu.protocol.types import Instruction, Message, Vector3
 from worldql_server_tpu.robustness import failpoints
@@ -26,13 +28,13 @@ def run(coro):
 
 
 class Harness:
-    def __init__(self, backend_cls, interval=0.03, max_batch=16_384):
+    def __init__(self, backend_cls, interval=0.03, max_batch=16_384, **kw):
         config = Config()
         self.backend = backend_cls(config.sub_region_size)
         self.store = MemoryRecordStore(config)
         self.peer_map = PeerMap(on_remove=self.backend.remove_peer)
         self.ticker = TickBatcher(
-            self.backend, self.peer_map, interval, max_batch=max_batch
+            self.backend, self.peer_map, interval, max_batch=max_batch, **kw
         )
         self.router = Router(
             self.peer_map, self.backend, self.store, ticker=self.ticker
@@ -67,6 +69,43 @@ class Harness:
             instruction=Instruction.LOCAL_MESSAGE, sender_uuid=sender,
             world_name="world", position=pos, parameter=parameter,
         ))
+
+    async def pair(self, pos):
+        """Two peers subscribed at ``pos``: (sender, receiver)."""
+        a = await self.add_peer()
+        b = await self.add_peer()
+        await self.subscribe(a, pos)
+        await self.subscribe(b, pos)
+        return a, b
+
+    async def wait_for(self, cond, timeout=20.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline
+            await asyncio.sleep(0.005)
+
+
+class GatedCollect:
+    """Wrap a backend's collect so the test controls when a tick's
+    device wait 'completes' (it runs on a worker thread)."""
+
+    def __init__(self, backend):
+        self.real = backend.collect_local_batch
+        self.gates: list = []          # threading.Events, FIFO per collect
+        self.started: list = []
+        backend.collect_local_batch = self._collect
+
+    def gate(self):
+        ev = threading.Event()
+        self.gates.append(ev)
+        return ev
+
+    def _collect(self, handle):
+        gate = self.gates.pop(0) if self.gates else None
+        self.started.append(handle)
+        if gate is not None:
+            gate.wait(30)
+        return self.real(handle)
 
 
 @pytest.mark.parametrize("backend_cls", [CpuSpatialBackend, TpuSpatialBackend])
@@ -262,6 +301,181 @@ def test_second_cancel_still_completes_inflight_delivery():
         assert [m.parameter for m in h.locals_for(b)] == ["m0"]
 
     run(scenario())
+
+
+# region: one flush (ISSUE 29): every queued message goes dispatch →
+# collect → deliver inside ``flush()`` under the ``_flushing`` lock,
+# whoever asked for the flush
+
+
+POS = Vector3(5, 5, 5)
+
+
+@pytest.mark.parametrize("first", ["pump", "size"])
+def test_flush_arriving_during_a_collect_is_delivered_after_it(first):
+    """The ``_flushing`` lock's promise: a flush that arrives while
+    another is in its (gated) collect takes no step until that one has
+    delivered, so every peer sees tick N whole before tick N+1 — with
+    the timer's flush in the collect and the ``max_batch`` one arriving,
+    and the other way round."""
+
+    async def scenario():
+        h = Harness(CpuSpatialBackend, interval=0.02, max_batch=2)
+        a, b = await h.pair(POS)
+        gated = GatedCollect(h.backend)
+        g0 = gated.gate()
+
+        async def send(*params):
+            for p in params:
+                await h.local(a, POS, p)
+
+        if first == "pump":
+            h.ticker.start()
+            await send("t0-m0")            # the timer's flush takes it
+            await h.wait_for(lambda: gated.started)
+            # the second fills the queue: enqueue awaits a flush inline
+            late = asyncio.create_task(send("t1-m0", "t1-m1"))
+            want = ["t0-m0", "t1-m0", "t1-m1"]
+        else:
+            late = asyncio.create_task(send("t0-m0", "t0-m1"))
+            await h.wait_for(lambda: gated.started)
+            h.ticker.start()
+            await send("t1-m0")            # the timer's flush is due
+            want = ["t0-m0", "t0-m1", "t1-m0"]
+
+        # several intervals: an unserialized second flush would have
+        # collected (ungated) and delivered by now
+        await asyncio.sleep(0.15)
+        assert len(gated.started) == 1
+        assert h.locals_for(b) == []
+
+        g0.set()
+        await late
+        # ticks: a flush is counted after its delivery has landed
+        await h.wait_for(lambda: h.ticker.ticks == 2)
+        assert [m.parameter for m in h.locals_for(b)] == want
+        await h.ticker.stop()
+        assert len(h.locals_for(b)) == 3
+
+    run(scenario())
+
+
+def test_stop_during_collect_requeues_and_delivers_exactly_once():
+    """stop() lands while the pump's flush waits on the device: the
+    batch it took is re-queued AHEAD of what arrived since, and the
+    drain flush delivers both once, in arrival order."""
+
+    async def scenario():
+        h = Harness(CpuSpatialBackend, interval=0.02)
+        a, b = await h.pair(POS)
+        gated = GatedCollect(h.backend)
+        g0 = gated.gate()
+        h.ticker.start()
+        await h.local(a, POS, "taken")
+        await h.wait_for(lambda: gated.started)
+        await h.local(a, POS, "queued")
+        await h.ticker.stop()
+        g0.set()  # the abandoned collect's worker thread
+        assert [m.parameter for m in h.locals_for(b)] == ["taken", "queued"]
+        assert len(gated.started) == 2  # the cancelled collect + the drain's
+
+    run(scenario())
+
+
+class IdlePlane:
+    """An active entity plane that owes no frames: every flush ticks
+    it, and it counts what the flush asked of it."""
+
+    interest = None
+
+    def __init__(self):
+        self.applied = self.aborted = 0
+
+    def active(self):
+        return True
+
+    def dispatch_tick(self):
+        return object()
+
+    def collect_tick(self, handle):
+        return handle
+
+    def apply(self, result, trace, skip_frames=False):
+        self.applied += 1
+        return []
+
+    def abort_tick(self):
+        self.aborted += 1
+
+
+@pytest.mark.parametrize("with_plane", [False, True],
+                         ids=["no-plane", "entity-plane"])
+@pytest.mark.parametrize("stage", ["dispatch_local_batch",
+                                   "collect_local_batch"])
+def test_failed_stage_drops_its_batch_only(stage, with_plane):
+    """A dispatch or collect that raises inside the pump's flush drops
+    THAT batch: the pump lives, the next tick delivers, and the sim
+    tick launched beside the failed batch is released exactly once."""
+
+    async def scenario():
+        plane = IdlePlane() if with_plane else None
+        h = Harness(CpuSpatialBackend, interval=0.02, entity_plane=plane)
+        a, b = await h.pair(POS)
+        real = getattr(h.backend, stage)
+        calls = []
+
+        def flaky(arg):
+            calls.append(arg)
+            if len(calls) == 1:
+                raise RuntimeError("device fell over")
+            return real(arg)
+
+        setattr(h.backend, stage, flaky)
+        h.ticker.start()
+        await h.local(a, POS, "dropped")
+        await h.wait_for(lambda: calls)
+        await h.local(a, POS, "survives")
+        # messages: only a delivered batch counts, once it has landed
+        await h.wait_for(lambda: h.ticker.messages)
+        assert [m.parameter for m in h.locals_for(b)] == ["survives"]
+        assert h.ticker.messages == 1
+        if plane is not None:
+            # read before stop(): a cancel landing inside a later sim
+            # tick aborts that one too
+            assert plane.aborted == 1
+            assert plane.applied >= 1
+        await h.ticker.stop()
+        assert len(h.locals_for(b)) == 1
+
+    run(scenario())
+
+
+def test_flush_observes_every_series_and_span_the_benchmark_reads():
+    traces = []
+    tracer = Tracer(enabled=True)
+    tracer.on_trace = traces.append
+
+    async def scenario():
+        h = Harness(CpuSpatialBackend, interval=60.0, metrics=Metrics(),
+                    tracer=tracer)
+        a, _ = await h.pair(POS)
+        await h.local(a, POS, "m")
+        await h.ticker.flush()
+        return h.ticker.metrics.snapshot()
+
+    snap = run(scenario())
+    for series in ("tick.queue_wait_ms", "tick.dispatch_ms",
+                   "tick.collect_ms", "tick.deliver_ms", "tick.flush_ms"):
+        assert snap["latency"][series]["count"] == 1, series
+    assert snap["counters"]["tick.flushes"] == 1
+    assert snap["counters"]["tick.messages"] == 1
+    [trace] = traces
+    assert [s.name for s in trace.spans if s.parent is None] == [
+        "tick.dispatch", "tick.collect", "tick.build_pairs", "tick.deliver",
+    ]
+
+
+# endregion
 
 
 # region: the pump's deadline (ISSUE 28): a flush is due one interval

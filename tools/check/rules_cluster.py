@@ -38,8 +38,7 @@ _TICK_SCOPED = ("engine/ticker.py", "cluster/shard.py")
 
 #: function names forming the tick path in the scoped modules
 _TICK_PATH = frozenset((
-    "flush", "flush_pipelined", "_collect_deliver",
-    "_collect_deliver_inner", "drain", "enqueue", "_dispatch_batch",
+    "flush", "drain", "enqueue", "_dispatch_batch",
     "deliver_batch", "_deliver_batch_planed", "_deliver_batch_local",
     "send_frame", "try_write", "try_write_many",
 ))

@@ -33,7 +33,6 @@ _HOT_FUNCTIONS = {
     "match_arrays_async",
     "_launch",
     "_dispatch",
-    "_dispatch_sparse",
     "_dispatch_csr",
     "_csr_effective_cap",
     "_prepare_queries",

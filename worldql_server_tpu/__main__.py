@@ -68,11 +68,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "flush is followed by the next at once); 0 "
                         "(default) resolves each LocalMessage as it "
                         "arrives")
-    p.add_argument("--tick-pipeline", type=int,
-                   help="max dispatched-but-undelivered ticks: 1 "
-                        "(default) = sequential flush; 2 overlaps tick "
-                        "N's collect+delivery with tick N+1's "
-                        "accumulation and dispatch")
     p.add_argument("--query-staging", choices=["auto", "on", "off"],
                    dest="query_staging",
                    help="columnar query staging: enqueue-time encode "
@@ -377,7 +372,7 @@ _OVERRIDES = [
     "db_region_z_size", "db_table_size", "db_cache_size", "http_host",
     "http_port", "http_auth_token", "ws_host", "ws_port", "zmq_server_host",
     "zmq_server_port", "zmq_timeout_secs", "spatial_backend", "tick_interval",
-    "tick_pipeline", "query_staging", "query_kinds", "query_stencil_max",
+    "query_staging", "query_kinds", "query_stencil_max",
     "query_ray_steps", "query_density_top_n", "mesh_batch", "mesh_space",
     "index_snapshot", "max_message_size",
     "durability", "wal_dir", "wal_fsync_ms", "wal_segment_bytes",

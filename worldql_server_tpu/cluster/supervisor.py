@@ -53,7 +53,6 @@ _PASSTHROUGH_FLAGS = (
     ("sub_region_size", "--sub-region-size"),
     ("spatial_backend", "--spatial-backend"),
     ("tick_interval", "--tick-interval"),
-    ("tick_pipeline", "--tick-pipeline"),
     ("query_staging", "--query-staging"),
     ("mesh_batch", "--mesh-batch"),
     ("mesh_space", "--mesh-space"),

@@ -112,15 +112,6 @@ class Config:
     tick_interval: float = field(
         default_factory=lambda: float(_env("WQL_TICK_INTERVAL", "0"))
     )
-    # Tick pipeline depth: maximum dispatched-but-undelivered ticks.
-    # 1 (default) keeps the sequential flush — dispatch, collect and
-    # deliver before the next tick starts. 2 overlaps tick N's device
-    # collect + delivery drain with tick N+1's accumulation and
-    # dispatch (engine/ticker.py; arrival order is preserved — the
-    # collect/deliver stages chain).
-    tick_pipeline: int = field(
-        default_factory=lambda: int(_env("WQL_TICK_PIPELINE", "1"))
-    )
     # Device-mesh shape for spatial_backend='sharded': data-parallel
     # query batch axis × space-sharded index axis. mesh_space=0 means
     # "all remaining devices" (parallel/mesh.py).
@@ -605,8 +596,6 @@ class Config:
                 "per query — use 'auto' to enable staging only when "
                 "supported"
             )
-        if self.tick_pipeline < 1:
-            errors.append("tick_pipeline must be >= 1 (1 = no overlap)")
         if self.delivery_workers < 0:
             errors.append("delivery_workers must be >= 0 (0 = in-process)")
         if self.delivery_workers:

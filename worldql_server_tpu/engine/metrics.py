@@ -206,8 +206,8 @@ class Metrics:
     def set_gauge(self, name: str, value) -> None:
         """Push-style gauge: record the latest value directly. For
         writers with no stable object to pull from — the tick
-        batcher's per-flush pipeline depth and compaction bucket are
-        snapshots of a moment, not a live view."""
+        batcher's per-flush delivered bytes are a snapshot of a
+        moment, not a live view."""
         self._gauges[name] = lambda v=value: v
 
     def gauge_value(self, name: str):

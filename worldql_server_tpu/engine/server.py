@@ -364,7 +364,7 @@ class WorldQLServer:
             self.ticker = TickBatcher(
                 self.backend, self.peer_map, config.tick_interval,
                 max_batch=config.max_batch,
-                metrics=self.metrics, pipeline=config.tick_pipeline,
+                metrics=self.metrics,
                 supervisor=self.supervisor, tracer=self.tracer,
                 device_telemetry=self.device_telemetry,
                 staging=self.staging,
@@ -463,8 +463,6 @@ class WorldQLServer:
                 "tick",
                 lambda: {
                     "interval_s": self.ticker.interval,
-                    "pipeline": self.ticker.pipeline,
-                    "inflight": self.ticker.inflight(),
                     "last_batch": self.ticker.last_batch,
                     "last_tick_ms": round(self.ticker.last_tick_ms, 3),
                     "last_dispatch_ms":

@@ -24,19 +24,6 @@ keeps serving transports while the device crunches. A full queue
 (``max_batch``) flushes early. ``tick_interval == 0`` keeps the
 reference-equivalent immediate path and never constructs this class.
 
-Pipelining (``pipeline`` > 1, ISSUE 3): ``flush`` splits into a
-dispatch stage (on the loop, launches the device batch) and a
-collect+deliver stage (a background task: device wait on a worker
-thread, then the batched delivery). With the default depth 2 at most
-ONE tick is in flight while the next accumulates and dispatches — tick
-N+1's device work overlaps tick N's D2H fetch and delivery drain. The
-stage tasks CHAIN (each awaits its predecessor before delivering), so
-per-peer arrival order is exactly the sequential path's, and ``stop``
-awaits the chain instead of cancelling it — the shield/re-queue
-guarantees of the sequential flush carry over unchanged.
-``pipeline == 1`` (the default) keeps the sequential flush byte for
-byte.
-
 Overload governance (``--overload on``, ISSUE 10): with a governor
 attached, ``enqueue`` never awaits — a full queue signals the pump
 (``_flush_request``) instead of flushing inline, so a slow device
@@ -76,7 +63,6 @@ class TickBatcher:
         interval: float,
         max_batch: int = 16_384,
         metrics=None,
-        pipeline: int = 1,
         supervisor=None,
         tracer: Tracer | None = None,
         device_telemetry=None,
@@ -152,11 +138,9 @@ class TickBatcher:
         # Optional robustness.Supervisor: the pump runs as a CRITICAL
         # supervised task (restart with backoff; escalate to clean
         # shutdown on budget exhaustion — a server that stopped ticking
-        # is deaf to its whole LocalMessage workload), and pipeline
-        # stages spawn crash-contained.
+        # is deaf to its whole LocalMessage workload).
         self._sup = supervisor
         self._handle = None
-        self.pipeline = max(1, int(pipeline))
         self._queue: deque[tuple[Message, LocalQuery]] = deque()
         self._task: asyncio.Task | None = None
         self._flushing = asyncio.Lock()
@@ -165,11 +149,6 @@ class TickBatcher:
         # must never await a full device flush from inside the recv
         # path (head-of-line blocking, ISSUE 10)
         self._flush_request = asyncio.Event()
-        # pipelined collect+deliver stages: _inflight caps the depth,
-        # _tail is the chain head the NEXT stage must wait out before
-        # delivering (arrival-order guarantee across ticks)
-        self._inflight: deque[asyncio.Task] = deque()
-        self._tail: asyncio.Task | None = None
         # stats (exposed via metrics)
         self.ticks = 0
         self.messages = 0
@@ -206,16 +185,12 @@ class TickBatcher:
             except asyncio.CancelledError:
                 pass
             self._task = None
-        await self.flush()  # drain in-flight stages + whatever is left
+        await self.flush()  # drain whatever is left
         while self._queue:
             # governed flushes take at most the admitted tier — keep
             # draining until the queue is empty (progress guaranteed:
             # every flush takes >= min_batch >= 1)
             await self.flush()
-
-    def inflight(self) -> int:
-        """Dispatched-but-undelivered ticks right now (gauge)."""
-        return len(self._inflight)
 
     async def enqueue(self, message: Message, query: LocalQuery) -> None:
         # queue-wait clock: closed by the flush that takes the message
@@ -246,10 +221,7 @@ class TickBatcher:
             # the queue purely as the fallback/requeue safety net
             self._staging.append(query)
         if len(self._queue) >= self.max_batch:
-            if self.pipeline > 1:
-                await self.flush_pipelined()
-            else:
-                await self.flush()
+            await self.flush()
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
@@ -295,10 +267,7 @@ class TickBatcher:
             last_start = start
             due = start + self.interval
             try:
-                if self.pipeline > 1:
-                    await self.flush_pipelined()
-                else:
-                    await self.flush()
+                await self.flush()
             except Exception:
                 logger.exception("tick flush failed — batch dropped")
             finally:
@@ -311,8 +280,8 @@ class TickBatcher:
     def _sim_dispatch(self, trace):
         """Launch the simulation tick (event-loop thread). Returns the
         collect handle, or None when the plane is idle, a previous sim
-        tick is still in flight (pipelined flushes never stack sim
-        ticks), or the dispatch failed (logged; the flush proceeds)."""
+        tick is still in flight, or the dispatch failed (logged; the
+        flush proceeds)."""
         plane = self._entity_plane
         if plane is None or not plane.active():
             return None
@@ -397,181 +366,6 @@ class TickBatcher:
 
     # endregion
 
-    # region: pipelined flush (pipeline > 1)
-
-    async def flush_pipelined(self) -> None:
-        """Dispatch everything queued and hand collect+delivery to a
-        chained background stage, keeping at most ``pipeline`` ticks
-        dispatched-but-undelivered: tick N+1 accumulates and launches
-        while tick N's collect runs on the worker thread and its
-        delivery drains. A dispatch failure drops the batch (same
-        contract as the sequential path's _run handler)."""
-        self._reap()
-        async with self._flushing:
-            batch = self._take_batch()
-            plane = self._entity_plane
-            sim_on = plane is not None and plane.active()
-            if not batch and not sim_on:
-                if self._cluster is not None:
-                    await self._cluster.drain()
-                if self._governor is not None:
-                    # idle windows are healthy samples — the governor's
-                    # road back to OK once load drops
-                    self._governor.note_idle(len(self._queue))
-            if batch or sim_on:
-                trace = self._begin_trace(len(batch))
-                t0 = time.perf_counter()
-                # frame clock: opened at flush start (the accumulation
-                # window is a config choice, not pipeline latency),
-                # closed at delivery completion on whichever path
-                t_ingress_ns = time.monotonic_ns()
-                self._note_queue_wait(batch, t_ingress_ns, trace)
-                sim_handle = self._sim_dispatch(trace)
-                skip_frames = self._frame_skip(sim_handle)
-                handle = None
-                if batch:
-                    try:
-                        with trace.span("tick.dispatch"):
-                            handle = self._dispatch_batch(batch)
-                            self.last_dispatch_ms = (
-                                time.perf_counter() - t0
-                            ) * 1e3
-                            if self.metrics is not None:
-                                self.metrics.observe_ms(
-                                    "tick.dispatch_ms",
-                                    self.last_dispatch_ms,
-                                )
-                    except BaseException:
-                        if sim_handle is not None:
-                            # the stage task never spawns — release
-                            # the un-applied sim tick
-                            plane.abort_tick()
-                        raise
-                if self._cluster is not None:
-                    # between dispatch and the stage's collect — the
-                    # device window — serialized under the flushing
-                    # lock so pipelined stages never interleave drains
-                    with trace.span("cluster.drain") as dspan:
-                        dspan.tag(frames=await self._cluster.drain())
-                stage = self._collect_deliver(
-                    batch, handle, self._tail, t0, trace, t_ingress_ns,
-                    sim_handle, skip_frames,
-                )
-                if self._sup is not None:
-                    task = self._sup.spawn_transient("tick-collect", stage)
-                else:
-                    task = asyncio.create_task(stage, name="tick-collect")  # wql: allow(unsupervised-task)
-                self._tail = task
-                self._inflight.append(task)
-        if self.metrics is not None:
-            self.metrics.set_gauge(
-                "tick.pipeline_inflight", len(self._inflight)
-            )
-        # backpressure: wait out the oldest stage once the pipeline is
-        # full — after this, at most pipeline-1 ticks remain in flight
-        # (pipeline=2: one tick overlaps the next accumulation window)
-        while len(self._inflight) >= 1 + self.pipeline:
-            await self._await_quiet(self._inflight[0])
-            self._reap()
-
-    async def _collect_deliver(self, batch, handle, prev, t0, trace,
-                               t_ingress_ns: int = 0,
-                               sim_handle=None,
-                               skip_frames: bool = False) -> None:
-        """Stage 2 of a pipelined tick: device collect (worker thread),
-        then — strictly after tick N-1's stage finished — the batched
-        delivery. Handles its own errors (a failed collect drops only
-        ITS batch; the next tick's stage runs untouched) and is never
-        cancelled by stop(), which awaits the chain instead."""
-        try:
-            await self._collect_deliver_inner(
-                batch, handle, prev, t0, trace, t_ingress_ns, sim_handle,
-                skip_frames,
-            )
-        finally:
-            trace.finish()  # idempotent; seals drop/error paths too
-
-    async def _collect_deliver_inner(
-        self, batch, handle, prev, t0, trace, t_ingress_ns: int = 0,
-        sim_handle=None, skip_frames: bool = False,
-    ) -> None:
-        targets = None
-        if handle is not None:
-            try:
-                tc = time.perf_counter()
-                with trace.span("tick.collect"):
-                    targets = await asyncio.to_thread(
-                        self.backend.collect_local_batch, handle
-                    )
-                    self.last_collect_ms = (time.perf_counter() - tc) * 1e3
-                    if self.metrics is not None:
-                        self.metrics.observe_ms(
-                            "tick.collect_ms", self.last_collect_ms
-                        )
-                self._note_collect_stats(trace)
-            except Exception:
-                logger.exception("tick collect failed — batch dropped")
-        # entity-sim stage: wait out the sim tick and fold it back into
-        # the host authority; its neighbor frames join this tick's
-        # batched delivery below. Runs before wait_prev so sim work
-        # overlaps the predecessor's delivery drain.
-        sim_pairs = []
-        if sim_handle is not None:
-            sim_pairs = await self._sim_collect_apply(
-                sim_handle, trace, skip_frames
-            )
-        # Arrival order across ticks: tick N-1's deliveries must all
-        # complete before ours start — even when our collect finished
-        # first (worker threads overlap). Ride out cancellation: the
-        # predecessor's delivery is owed regardless.
-        if prev is not None:
-            with trace.span("tick.wait_prev"):
-                while not prev.done():
-                    try:
-                        await asyncio.shield(prev)
-                    except (asyncio.CancelledError, Exception):
-                        continue
-        if targets is None and not sim_pairs:
-            return
-        try:
-            with trace.span("tick.build_pairs"):
-                pairs = self._build_pairs(batch, targets or [])
-                pairs.extend(sim_pairs)
-            td = time.perf_counter()
-            # same shield-and-re-await discipline as the sequential
-            # flush: a cancellation must not abort the delivery tail
-            # half-sent (fast-path frames are already in transport
-            # buffers; re-sending would duplicate)
-            with trace.span("tick.deliver"):
-                # made INSIDE the span: the delivery task's context
-                # then has tick.deliver open, so the delivery's own
-                # spans nest under it and its loop time is charged to
-                # them, while this waiting task is charged none.
-                # Awaited in place below (shield loop) — not a dangling
-                # loop, so it rides outside the supervisor
-                deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
-                    self.peer_map.deliver_batch(pairs, t_ingress_ns)
-                )
-                while not deliver_task.done():
-                    try:
-                        await asyncio.shield(deliver_task)
-                    except asyncio.CancelledError:
-                        continue
-                    except Exception:
-                        logger.exception("tick delivery failed")
-                        break
-            if self._cluster is not None and pairs:
-                # close the router-ingress clock (cluster.e2e_ms) for
-                # every delivered frame carrying a trace context —
-                # socket-write-complete, the conservative PR 7 close
-                self._cluster.close_frames(m for m, _ in pairs)
-            self._account(
-                batch, t0, deliver_ms=(time.perf_counter() - td) * 1e3,
-                trace=trace,
-            )
-        except Exception:
-            logger.exception("tick delivery failed — batch dropped")
-
     def _build_pairs(self, batch, targets) -> list:
         """One tick's delivery pairs. Radius rows pair the original
         message with its fan-out list, exactly as before. Kind rows
@@ -632,41 +426,9 @@ class TickBatcher:
             [query for _, query in batch]
         )
 
-    def _reap(self) -> None:
-        while self._inflight and self._inflight[0].done():
-            self._inflight.popleft()
-
-    @staticmethod
-    async def _await_quiet(task: asyncio.Task) -> None:
-        """Wait for a stage task without cancelling it and without
-        letting its (already-logged) errors escape. Our own
-        cancellation propagates once the task is done — the in-flight
-        batch is owed its delivery first."""
-        cancelled = False
-        while not task.done():
-            try:
-                await asyncio.shield(task)
-            except asyncio.CancelledError:
-                cancelled = True
-            except Exception:
-                break
-        if cancelled:
-            raise asyncio.CancelledError
-
-    async def _drain_inflight(self) -> None:
-        while self._inflight:
-            await self._await_quiet(self._inflight[0])
-            self._reap()
-
-    # endregion
-
     async def flush(self) -> None:
         """Resolve and deliver everything queued so far. Serialized so a
-        size-triggered flush can't interleave with the timer's. In
-        pipelined mode any in-flight stage is waited out FIRST, so the
-        drained queue delivers after it (stop()'s exactly-once drain
-        keeps cross-tick arrival order)."""
-        await self._drain_inflight()
+        size-triggered flush can't interleave with the timer's."""
         async with self._flushing:
             batch = self._take_batch()
             plane = self._entity_plane
@@ -681,7 +443,10 @@ class TickBatcher:
                 return
             trace = self._begin_trace(len(batch))
             t0 = time.perf_counter()
-            t_ingress_ns = time.monotonic_ns()  # frame clock (see above)
+            # frame clock: opened at flush start (the accumulation
+            # window is a config choice, not flush latency), closed at
+            # delivery completion
+            t_ingress_ns = time.monotonic_ns()
             self._note_queue_wait(batch, t_ingress_ns, trace)
 
             dispatched = not batch
@@ -742,14 +507,20 @@ class TickBatcher:
                 # half-sent — fast-path frames are already in
                 # transport buffers and re-sending would duplicate.
                 with trace.span("tick.deliver"):
-                    # made inside the span (see _collect_deliver_inner)
+                    # made INSIDE the span: the delivery task's context
+                    # then has tick.deliver open, so the delivery's own
+                    # spans nest under it and its loop time is charged to
+                    # them, while this waiting task is charged none.
+                    # Awaited in place — not a dangling loop, so it rides
+                    # outside the supervisor
                     deliver_task = asyncio.ensure_future(  # wql: allow(unsupervised-task)
                         self.peer_map.deliver_batch(pairs, t_ingress_ns)
                     )
                     await asyncio.shield(deliver_task)
                 if self._cluster is not None and pairs:
-                    # cluster.e2e_ms close at socket-write-complete
-                    # (see _collect_deliver_inner)
+                    # close the router-ingress clock (cluster.e2e_ms) for
+                    # every delivered frame carrying a trace context —
+                    # socket-write-complete, the conservative PR 7 close
                     self._cluster.close_frames(m for m, _ in pairs)
             except asyncio.CancelledError:
                 if sim_handle is not None:
@@ -797,7 +568,6 @@ class TickBatcher:
         self._note_period()
         trace = self._tracer.begin(
             "tick", tick=self._tick_seq, batch=batch_size,
-            inflight=len(self._inflight), pipeline=self.pipeline,
         )
         if self._governor is not None:
             # overload state rides every tick trace: a slow-tick dump
@@ -810,7 +580,7 @@ class TickBatcher:
         the start of the pump's last flush to the start of this one
         (idle flushes are starts too), and ``tick.late_flushes``, a
         start past its due time. Reached from ``_begin_trace``, which
-        both flush variants pass once, inside the pump's own call, exactly
+        ``flush`` passes once, inside the pump's own call, exactly
         when the flush has work: the flushes ``tick.flushes`` counts. A
         flush that is not the pump's (``stop``'s drain, the ungoverned
         size cap) finds no note."""
@@ -844,17 +614,12 @@ class TickBatcher:
             ),
         )
 
-    def _account(
-        self, batch, t0, deliver_ms: float | None = None, trace=NULL_TRACE,
-    ) -> None:
+    def _account(self, batch, t0, trace=NULL_TRACE) -> None:
         self.ticks += 1
         self.messages += len(batch)
         self.last_batch = len(batch)
         self.last_tick_ms = (time.perf_counter() - t0) * 1e3
-        self.last_deliver_ms = (
-            deliver_ms if deliver_ms is not None
-            else self.last_tick_ms - self.last_resolve_ms
-        )
+        self.last_deliver_ms = self.last_tick_ms - self.last_resolve_ms
         if self.metrics is not None:
             # whole-tick accounting: the enclosing "tick" root trace IS
             # the span for these two series
@@ -863,8 +628,8 @@ class TickBatcher:
             self.metrics.inc("tick.flushes")
             self.metrics.inc("tick.messages", len(batch))
             # delivered wire bytes attributable to THIS flush: the
-            # PeerMap counter diffed across consecutive accounts (both
-            # flush variants route here after their delivery settles)
+            # PeerMap counter diffed across consecutive accounts (the
+            # flush routes here after its delivery settles)
             bd = getattr(self.peer_map, "bytes_delivered", 0)
             self.metrics.set_gauge(
                 "delivery.bytes_per_tick", bd - self._bytes_mark

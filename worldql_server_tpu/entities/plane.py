@@ -974,9 +974,8 @@ class EntityPlane:
         columns, pick the delta or full path, launch the kernel (when
         any device work is owed), and enqueue the D2H prefetch.
         Returns an opaque handle for ``collect_tick`` or None when idle
-        / a previous tick is still in flight (pipelined flushes never
-        stack sim ticks — the writeback of tick N is input to tick
-        N+1)."""
+        / a previous tick is still in flight (sim ticks never stack:
+        the writeback of tick N is input to tick N+1)."""
         self._drain_pending()  # staged updates fold tick-edge
         if not self._slot_of or self._tick_inflight:
             return None

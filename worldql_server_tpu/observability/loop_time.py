@@ -62,9 +62,7 @@ LAYER_PREFIXES = (
     ("cluster.drain", "dispatch"),
     ("task:sup:tick-batcher", "dispatch"),
     ("tick.collect", "collect"),
-    ("tick.wait_prev", "collect"),
     ("device.", "collect"),
-    ("task:sup:tick-collect", "collect"),
     ("tick.build_pairs", "deliver"),
     ("tick.deliver", "deliver"),
     ("deliver.", "deliver"),

@@ -14,14 +14,15 @@ return shared null singletons that swallow everything.
 Thread-safety: the ticker's collect stage runs on a worker thread and
 the WAL writer thread emits fsync spans, so ``Trace.add`` takes a small
 lock and parent links ride a :mod:`contextvars` var (copied into
-``asyncio.to_thread`` and ``create_task``, so spans opened inside a
-pipelined stage task still attach to their tick's trace).
+``asyncio.to_thread`` and ``create_task``, so spans opened on the
+collect's worker thread or inside the delivery task still attach to
+their tick's trace).
 
 Two entry points:
 
 * ``tracer.begin(name, **tags)`` — an explicit trace object for flows
-  that cross task boundaries (the pipelined tick: dispatch on the
-  loop, collect+deliver in a chained stage task). The caller threads
+  that cross task boundaries (the tick: dispatch on the loop, collect
+  on a worker thread, delivery in a task of its own). The caller threads
   the ``Trace`` through and calls ``trace.span(...)`` / ``finish()``.
 * ``tracer.span(name, **tags)`` — a context manager that attaches to
   the current trace if one is active, else records a single-span

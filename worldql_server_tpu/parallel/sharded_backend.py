@@ -17,7 +17,7 @@ SPMD via ``jax.shard_map``; XLA lays out the gathers per shard and the
 final combine as an ICI all-reduce(max). Worlds need no special
 handling: world id is part of the spatial key, so a world's cubes
 scatter across shards (load-balancing Zipf-hotspot worlds) while each
-cube stays device-local. Sparse / CSR result compaction runs in the
+cube stays device-local. CSR result compaction runs in the
 same jit after the shard_map — XLA partitions the cumsum/scatter with
 the collectives it needs, so compacted results work identically on the
 mesh (the distributed delivery path consumes CSR).
@@ -48,7 +48,6 @@ from ..spatial.tpu_backend import (
     _scatter_dead,
     _sort_segment_dev,
     _write_chunk,
-    compact_sparse,
     match_core,
     pack_csr,
     probe_buckets_for,
@@ -418,8 +417,8 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
         return -(-cap // self.n_batch) * self.n_batch
 
     def _make_kernel(self, variant: str, kinds: tuple, ks: tuple, extra):
-        """Compile a mesh kernel: shard_map match (+ pmax merge), then
-        optional result compaction, one jit, explicit in_shardings.
+        """Compile a mesh kernel ('dense' or 'csr'): shard_map match
+        (+ pmax merge), one jit, explicit in_shardings.
         ``kinds`` says which segments are space-sharded stacks ('base',
         local view [1, cap]) vs replicated flat arrays ('delta')."""
         mesh = self.mesh
@@ -496,15 +495,10 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                 )
                 return counts, flat, total
         else:
-            matched = _shard_map(
+            fn = _shard_map(
                 local, mesh=mesh, in_specs=in_specs,
                 out_specs=P("batch", None),
             )
-            if variant == "dense":
-                fn = matched
-            else:
-                def fn(*args):
-                    return compact_sparse(matched(*args), c=extra)
 
         in_shardings = tuple(
             NamedSharding(mesh, spec) for spec in in_specs
@@ -524,10 +518,6 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
     def _dispatch(self, queries: tuple, segs, ks, kinds):
         flat = [a for seg in segs for a in seg]
         return self._kernel("dense", kinds, ks)(*flat, *queries)
-
-    def _dispatch_sparse(self, queries: tuple, segs, ks, kinds, c: int):
-        flat = [a for seg in segs for a in seg]
-        return self._kernel("sparse", kinds, ks, c)(*flat, *queries)
 
     def _dispatch_csr(self, queries: tuple, segs, ks, kinds, t_cap: int):
         flat = [a for seg in segs for a in seg]

@@ -340,8 +340,8 @@ class ReconnectStorm(Scenario):
             ))
             await asyncio.sleep(0.25)
         # entity_count advances at STAGING time — before the compile
-        # tick even starts — so drain the ticker (the compile runs
-        # inside a tick; inflight() covers it) before sampling the
+        # tick even starts — so drain the ticker's queue (the compile
+        # runs inside the tick that takes it) before sampling the
         # governor, or the bust lands right after this wait and the
         # swarm's handshakes walk into the shed window.
         await ctx.drain_ticker(30.0)

@@ -113,7 +113,7 @@ class ScenarioContext:
             return True
         deadline = time.perf_counter() + timeout_s
         while time.perf_counter() < deadline:
-            if not ticker._queue and not ticker.inflight():
+            if not ticker._queue:
                 return True
             await asyncio.sleep(0.01)
         return False

@@ -365,21 +365,6 @@ def _multi_match(flat_args, ks):
     return parts[0] if nseg == 1 else jnp.concatenate(parts, axis=1)
 
 
-def compact_sparse(tgt, *, c: int):
-    """Sparse compaction of a dense [M, K] target table: most queries
-    resolve to an empty fan-out (an entity alone in its cube
-    broadcasting except-self), so compact the non-empty rows on device
-    and ship only those. Returns ``(rows[c], targets[c, k], n_hits)``:
-    query indices with >= 1 target, their target rows, and the true hit
-    count (host re-fetches dense on the rare ``n_hits > c`` overflow).
-    Cuts device→host result bytes by the hit rate — the dominant cost
-    of a result fetch."""
-    nz = jnp.any(tgt >= 0, axis=1)
-    order = jnp.argsort(~nz, stable=True)  # hit rows first, in order
-    rows = order[:c]
-    return rows.astype(jnp.int32), tgt[rows], nz.sum(dtype=jnp.int32)
-
-
 #: CSR zone-A row width: one identity row of this many lanes per query
 CSR_ROW = 8
 #: CSR zone-B row width: hot-remainder regions pad to multiples of
@@ -702,11 +687,6 @@ def _match_dense_kernel(*flat_args, ks):
     return _multi_match(flat_args, ks)
 
 
-@partial(jax.jit, static_argnames=("ks", "c"))
-def _match_sparse_kernel(*flat_args, ks, c):
-    return compact_sparse(_multi_match(flat_args, ks), c=c)
-
-
 @jax.jit
 def _scatter_dead(peer_arr, rows):
     """Tombstone ``rows`` (padded with out-of-range indices) in a device
@@ -800,7 +780,6 @@ def _probe_only_dev(sk, sk2, n_buckets):
 # capacity tier (utils/retrace.py; tests/test_retrace_budget.py).
 for _family, _kernel_fn in {
     "match_dense": _match_dense_kernel,
-    "match_sparse": _match_sparse_kernel,
     "match_run_csr": _match_run_csr_kernel,
     "pack_csr": _pack_csr_kernel,
     "scatter_dead": _scatter_dead,
@@ -2343,14 +2322,12 @@ class TpuSpatialBackend(SpatialBackend):
         positions: np.ndarray,
         sender_ids: np.ndarray,
         repls: np.ndarray,
-        max_hits: int | None = None,
         csr_cap: int | None = None,
     ):
         """Asynchronous hot path: dispatch without forcing the result.
 
         Returns ``(m, result)`` where ``result`` is the device value —
-        dense ``targets``; with ``max_hits`` the sparse
-        ``(rows, targets, n_hits)`` triple; with ``csr_cap`` the
+        dense ``targets``; with ``csr_cap`` the
         compacted ``(counts, flat_targets, total)`` triple. Callers
         overlap ticks by dispatching tick t+1 before reading tick t
         (double buffering: transfer and compute of adjacent ticks
@@ -2364,13 +2341,10 @@ class TpuSpatialBackend(SpatialBackend):
         queries = self._prepare_queries(
             world_ids, positions, sender_ids, repls
         )
-        result = self._launch(
-            queries, segs, ks, kinds, csr_cap=csr_cap, max_hits=max_hits
-        )
-        return m, result[0] if max_hits is None and csr_cap is None else result
+        result = self._launch(queries, segs, ks, kinds, csr_cap=csr_cap)
+        return m, result[0] if csr_cap is None else result
 
-    def _launch(self, queries, segs, ks, kinds, *, csr_cap=None,
-                max_hits=None):
+    def _launch(self, queries, segs, ks, kinds, *, csr_cap=None):
         """Pick the result layout, dispatch, and enqueue the D2H
         prefetch (by the time a pipelined caller reads, the copy has
         landed — the read costs no round-trip). Returns a tuple of
@@ -2384,10 +2358,6 @@ class TpuSpatialBackend(SpatialBackend):
             result = self._dispatch_csr(
                 queries, segs, ks, kinds,
                 self._csr_effective_cap(next_pow2(csr_cap), queries, segs),
-            )
-        elif max_hits is not None:
-            result = self._dispatch_sparse(
-                queries, segs, ks, kinds, next_pow2(max_hits)
             )
         else:
             result = (self._dispatch(queries, segs, ks, kinds),)
@@ -2436,10 +2406,6 @@ class TpuSpatialBackend(SpatialBackend):
         round-trip per array."""
         flat = [a for seg in segs for a in seg]
         return _match_dense_kernel(*flat, *queries, ks=ks)
-
-    def _dispatch_sparse(self, queries: tuple, segs, ks, kinds, c: int):
-        flat = [a for seg in segs for a in seg]
-        return _match_sparse_kernel(*flat, *queries, ks=ks, c=c)
 
     def _dispatch_csr(self, queries: tuple, segs, ks, kinds, t_cap: int):
         flat = [a for seg in segs for a in seg]
