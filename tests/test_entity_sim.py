@@ -6,9 +6,11 @@ least one compaction mid-stream; the WS variant importorskips
 ``websockets`` (minimal containers run the ZMQ legs only)."""
 
 import asyncio
+import random
 import struct
 import uuid
 
+import numpy as np
 import pytest
 
 from tests.client_util import ZmqClient, free_port
@@ -545,6 +547,187 @@ def test_entity_sim_config_validation():
     config.entity_k = 0
     with pytest.raises(ValueError, match="entity_k"):
         config.validate()
+
+
+# endregion
+
+
+# region: the rows the plane owes the interest manager (ISSUE 30)
+
+
+class _ColumnRecorder:
+    """Stands where the interest manager stands and keeps what each
+    ``build_pairs`` was handed: the five columns it reads, and the
+    plane's word on which of their rows may have changed."""
+
+    def __init__(self):
+        self.seen = None
+        self.hinted = self.scanned = self.named = 0
+
+    def forget_peer(self, peer):
+        pass
+
+    def columns(self, plane, pos, targets, cap):
+        live = plane._live[:cap].copy()
+        return {
+            "live": live,
+            "keys": plane._uuid_bytes[:cap].copy(),
+            "wid": plane._wid[:cap].copy(),
+            "pos": np.array(pos[:cap], np.float32).view(np.uint32),
+            # a dead row's recipients are nobody's: the manager masks them
+            "targets": np.where(live[:, None], np.asarray(targets)[:cap], -1),
+        }
+
+    def build_pairs(self, plane, pos, targets, cap, trace=None, changed=None):
+        now = self.columns(plane, pos, targets, cap)
+        if changed is None:
+            self.scanned += 1
+        else:
+            rows, roster = changed
+            assert (np.diff(rows) > 0).all() and (np.diff(roster) > 0).all()
+            assert np.isin(roster, rows).all()
+            self.hinted += 1
+            self.named += len(rows)
+            was = self.seen
+            assert was is not None and len(was["live"]) == cap
+            spoken_for = (now["live"] | was["live"])
+            identity = (
+                (now["live"] != was["live"]) | (now["wid"] != was["wid"])
+                | (now["keys"] != was["keys"]).any(axis=1)
+            )
+            differs = spoken_for & (
+                identity | (now["pos"] != was["pos"]).any(axis=1)
+                | (now["targets"] != was["targets"]).any(axis=1)
+            )
+            unnamed = np.setdiff1d(np.flatnonzero(differs), rows)
+            assert unnamed.size == 0, f"rows changed and not named: {unnamed}"
+            unnamed = np.setdiff1d(np.flatnonzero(identity), roster)
+            assert unnamed.size == 0, f"roster changed and not named: {unnamed}"
+        self.seen = now
+        return []
+
+
+@pytest.mark.parametrize("mid_flight", [False, True])
+def test_rows_the_plane_names_cover_every_row_that_changed(mid_flight):
+    """A real plane on delta ticks, 80 ticks of wire updates with
+    entities removed and added, a tick whose frames were shed and an
+    aborted one: after every apply, each row whose column content
+    differs from what ``build_pairs`` last read is among the rows the
+    plane named, each slot whose identity differs among its roster —
+    or the plane named none and asked for the whole scan.
+    ``mid_flight``: the wire changes land between dispatch and apply."""
+    backend, plane = make_plane(k=4)
+    assert backend.configure_delta_ticks("on")
+    plane._delta_ticks = True
+    rec = plane.interest = _ColumnRecorder()
+    rng = random.Random(30 + mid_flight)
+    peers = [uuid.uuid4() for _ in range(5)]
+    owned: dict = {p: [] for p in peers}
+
+    def at_random():
+        return Vector3(rng.uniform(0, 96), rng.uniform(0, 96), 0.0)
+
+    def spawn(peer):
+        ent = uuid.uuid4()
+        owned[peer].append(ent)
+        plane.ingest(ent_msg(peer, [
+            Entity(uuid=ent, position=at_random(), world_name="w")]))
+
+    def wire(t):
+        for _ in range(rng.randrange(3)):
+            peer = rng.choice(peers)
+            drift = rng.random()        # a few drift for a while, and stop
+            plane.ingest(ent_msg(peer, [Entity(
+                uuid=rng.choice(owned[peer]), position=at_random(),
+                world_name="w", flex=vel_flex(rng.uniform(-2, 2))
+                if drift < 0.1 else vel_flex(0.0) if drift < 0.5 else None)]))
+        if t % 5 == 3:
+            peer = rng.choice(peers)
+            ent = owned[peer].pop(rng.randrange(len(owned[peer])))
+            plane.ingest(ent_msg(peer, [Entity(uuid=ent)],
+                                 parameter=PARAM_REMOVE))
+        if t % 5 == 0 or t % 10 == 3:
+            spawn(rng.choice(peers))     # a freed slot is taken again
+
+    for peer in peers:
+        for _ in range(40):
+            spawn(peer)
+    shed = 0
+    for t in range(80):
+        if not mid_flight:
+            wire(t)
+        handle = plane.dispatch_tick()
+        assert handle is not None
+        if mid_flight:
+            wire(t)
+        if t == 40:
+            plane.abort_tick()
+            continue
+        result = plane.collect_tick(handle)
+        for col in {"pos", "targets"} & result.keys():
+            result[col] = np.asfortranarray(result[col])   # as a TPU does
+        skip = t % 9 == 7
+        shed += skip
+        plane.apply(result, skip_frames=skip)
+    assert shed >= 8 and plane.dropped_ticks == 1
+    # the replay source is kept row-major, whatever the device handed
+    assert plane._last_targets.flags.c_contiguous
+    assert plane._last_pos.flags.c_contiguous
+    assert plane.delta_sim_ticks >= 40 and plane.full_sim_ticks >= 2
+    # the hint is there most ticks, and is a part of the swarm
+    assert rec.hinted >= 50 and rec.scanned >= 2
+    assert rec.hinted + rec.scanned + shed == plane.applied_ticks
+    assert rec.named / rec.hinted < plane.entity_count / 2
+
+
+def test_a_shed_streak_owes_at_most_a_tier_then_asks_for_the_scan():
+    """Ticks shed by ``skip_frames`` owe their rows to the next
+    ``build_pairs``; once they add up to more than a scan would read,
+    the plane stops listing them and names none."""
+    backend, plane = make_plane(k=4)
+    assert backend.configure_delta_ticks("on")
+    plane._delta_ticks = True
+    rec = plane.interest = _ColumnRecorder()
+    peer = uuid.uuid4()
+    ents = [uuid.uuid4() for _ in range(40)]
+    plane.ingest(ent_msg(peer, [
+        Entity(uuid=e, position=Vector3(20 * i, 0, 0), world_name="w")
+        for i, e in enumerate(ents)]))
+    tick(plane)
+    assert rec.scanned == 1 and plane._owed == ([], [])
+    shed = 0
+    while plane._owed is not None:
+        plane.ingest(ent_msg(peer, [Entity(
+            uuid=e, position=Vector3(20 * i + 1 + shed % 2, 0, 0),
+            world_name="w") for i, e in enumerate(ents[:8])]))
+        plane.apply(plane.collect_tick(plane.dispatch_tick()),
+                    skip_frames=True)
+        shed += 1
+        assert shed < 400
+    assert shed == plane.delta_sim_ticks >= 2
+    tick(plane)
+    assert rec.scanned == 2 and rec.hinted == 0
+    tick(plane)
+    assert rec.hinted == 1
+
+
+def test_plane_without_a_manager_keeps_nothing_for_one():
+    backend, plane = make_plane(k=4)
+    assert backend.configure_delta_ticks("on")
+    plane._delta_ticks = True
+    peer = uuid.uuid4()
+    ents = [uuid.uuid4() for _ in range(8)]
+    plane.ingest(ent_msg(peer, [
+        Entity(uuid=e, position=Vector3(20 * i, 0, 0), world_name="w")
+        for i, e in enumerate(ents)]))
+    for i in range(4):
+        plane.ingest(ent_msg(peer, [Entity(
+            uuid=ents[i], position=Vector3(20 * i + 1, 0, 0), world_name="w")]))
+        plane.ingest(ent_msg(peer, [Entity(uuid=ents[-1 - i])],
+                             parameter=PARAM_REMOVE))
+        tick(plane)
+        assert plane._owed is None
+    assert plane.delta_sim_ticks >= 2
 
 
 # endregion

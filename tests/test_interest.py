@@ -24,6 +24,8 @@ import numpy as np
 import pytest
 
 from worldql_server_tpu.engine.config import Config
+from worldql_server_tpu.engine.metrics import Metrics
+from worldql_server_tpu.entities import EntityPlane
 from worldql_server_tpu.interest import (
     InterestManager,
     ReplayClient,
@@ -868,6 +870,307 @@ def test_config_validates_interest_fields():
     assert "lod_near_radius" in errs(lod_near_radius=-1)
     assert "lod_far_every_k" in errs(lod_far_every_k=0)
     assert "peer_bandwidth_bytes" in errs(peer_bandwidth_bytes=-5)
+
+
+# endregion
+
+# region: the hinted diff against the whole scan (ISSUE 30)
+
+
+class Herd:
+    """``EntityPlane``'s apply leg in small, for two managers at once:
+    the columns ``build_pairs`` reads, the retained targets a delta
+    tick splices its closure into, and the rows owed to the manager's
+    next call, kept as ``plane.py`` keeps them (``_owed``) and settled
+    by its own ``_take_owed``. Entities sit ``PER_CUBE`` to a cube; a row's
+    recipients are the owners of the others in its cube, and come back
+    in a fresh ORDER whenever anything in the cube changed."""
+
+    PER_CUBE = 4
+
+    def __init__(self, seed: int, cap: int = 32, peers: int = 5, k: int = 6):
+        self.rng = np.random.default_rng(seed)
+        self.plane = FakePlane(cap=cap, worlds=("arena", "annex"))
+        self.peers = [uuid.UUID(int=seed * 1000 + i + 1) for i in range(peers)]
+        for peer in self.peers:
+            self.plane.pid(peer)
+        self.k = k
+        self.targets = np.full((cap, k), -1, np.int32)
+        self.cube = np.full(cap, -1, np.int64)
+        self.owner = np.full(cap, -1, np.int64)
+        self.dirty: set[int] = set()
+        self._owed = None
+        self.cold = True            # the next tick must be a full one
+
+    # -- the roster (plane._alloc_slot / _release_slot)
+
+    def alloc(self, slot, pid, cube, ent=None, wid=0):
+        ent = ent or uuid.UUID(bytes=self.rng.bytes(16))
+        self.plane.put(slot, ent, self.rng.integers(0, 64, 3) / 8.0, wid)
+        self.cube[slot], self.owner[slot] = cube, pid
+        self.dirty.add(cube)
+        if self._owed is not None:
+            self._owed[1].append(slot)
+        return ent
+
+    def release(self, slot):
+        self.dirty.add(int(self.cube[slot]))
+        self.plane._live[slot] = False
+        self.plane._uuid_bytes[slot] = 0
+        self.plane._wid[slot] = -1
+        self.targets[slot] = -1
+        self.cube[slot] = self.owner[slot] = -1
+        if self._owed is not None:
+            self._owed[1].append(slot)
+
+    def free_slot(self):
+        return int(np.flatnonzero(~self.plane._live)[0])
+
+    def some_live(self):
+        return int(self.rng.choice(np.flatnonzero(self.plane._live)))
+
+    # -- traffic
+
+    def move(self, slot):
+        self.plane._pos[slot] += np.float32(0.125)
+        self.dirty.add(int(self.cube[slot]))
+
+    def hop(self, slot, cube):
+        """To another cube: its old neighbours lose a recipient, its
+        new ones gain one, and it moved."""
+        self.dirty.update((int(self.cube[slot]), cube))
+        self.cube[slot] = cube
+        self.plane._pos[slot] += np.float32(0.125)
+
+    def stir(self, cube):
+        """The cube resolves again and nothing in it changed: the
+        same recipients in another order."""
+        self.dirty.add(cube)
+
+    def grow(self):
+        plane, cap = self.plane, self.plane._cap * 2
+
+        def grown(a, fill):
+            out = np.full((cap,) + a.shape[1:], fill, a.dtype)
+            out[: len(a)] = a
+            return out
+
+        plane._live = grown(plane._live, False)
+        plane._pos = grown(plane._pos, 0.0)
+        plane._uuid_bytes = grown(plane._uuid_bytes, 0)
+        plane._wid = grown(plane._wid, -1)
+        plane._cap = cap
+        self.targets = grown(self.targets, -1)
+        self.cube, self.owner = grown(self.cube, -1), grown(self.owner, -1)
+        self.cold = True
+
+    # -- one applied tick, and the settlement that precedes build_pairs
+
+    def _resolve(self, rows):
+        live = self.plane._live
+        for r in rows.tolist():
+            near = np.flatnonzero(live & (self.cube == self.cube[r]))
+            seen_by = self.owner[near[near != r]]
+            seen_by = self.rng.permutation(seen_by[seen_by != self.owner[r]])
+            self.targets[r] = -1
+            self.targets[r, : len(seen_by[: self.k])] = seen_by[: self.k]
+
+    def tick(self, full=False) -> str:
+        live = self.plane._live
+        if full or self.cold:
+            self._resolve(np.flatnonzero(live))
+            self._owed, self.cold, kind = None, False, "full"
+        elif not self.dirty:
+            kind = "replay"
+        else:
+            rows = np.flatnonzero(live & np.isin(self.cube, list(self.dirty)))
+            self._resolve(rows)
+            if self._owed is not None:
+                self._owed[0].append(rows)
+            kind = "delta"
+        self.dirty.clear()
+        return kind
+
+    def take_owed(self):
+        return EntityPlane._take_owed(self)      # the plane's own
+
+
+#: case -> (what happens at tick t on top of the walk, what must have
+#: been seen for the case to count). Every case is the same >= 200
+#: ticks of moves and stirred cubes; these are laid over them.
+def _reuse_slot(herd, t, managers):
+    if t % 7 == 3:                   # released, and another's at once
+        slot = herd.some_live()
+        pid, cube = int(herd.owner[slot]), int(herd.cube[slot])
+        herd.release(slot)
+        herd.alloc(slot, (pid + 1) % len(herd.peers), cube)
+    elif t % 7 == 5:                 # released now, re-allocated later
+        herd.release(herd.some_live())
+    elif t % 7 == 6:
+        herd.alloc(herd.free_slot(), t % len(herd.peers), t % 5)
+
+
+def _change_row(herd, t, managers):
+    if t % 5 == 2:                   # the same entity under another row
+        slot = herd.some_live()
+        ent = uuid.UUID(bytes=herd.plane._uuid_bytes[slot].tobytes())
+        pid, cube = int(herd.owner[slot]), int(herd.cube[slot])
+        at = herd.plane._pos[slot].copy()
+        herd.release(slot)
+        new = herd.free_slot() if t % 10 == 2 else slot
+        herd.alloc(new, pid, cube, ent, wid=(t // 5) % 2)
+        if t % 15 == 2:
+            herd.plane._pos[new] = at
+
+
+def _hop(herd, t, managers):
+    if t % 3 == 1:                   # a move, a gain and a loss at once
+        slot = herd.some_live()
+        herd.hop(slot, (int(herd.cube[slot]) + 1) % 5)
+        herd.move(herd.some_live())
+
+
+def _contact_and_resync(herd, t, managers):
+    if t == 60:                      # a peer nobody has framed yet
+        herd.peers.append(uuid.UUID(int=0xFEED))
+        herd.alloc(herd.free_slot(), herd.plane.pid(herd.peers[-1]), 1)
+    if t % 40 == 25:
+        for mgr in managers:
+            mgr.mark_resync(herd.peers[t % len(herd.peers)])
+
+
+def _degraded(herd, t, managers):
+    if t % 50 in (20, 31):
+        for mgr in managers:
+            mgr.note_governor(0, t % 50 == 20)
+
+
+def _walk_only(herd, t, managers):
+    """Nothing on top of the walk: the test's own loop makes the case."""
+
+
+def _grow(herd, t, managers):
+    if t in (71, 151):
+        herd.grow()
+
+
+HERD_CASES = {
+    "order_only": _walk_only,
+    "move_gain_loss": _hop,
+    "slot_reused": _reuse_slot,
+    "row_changed": _change_row,
+    "skip_frames": _walk_only,
+    "full_between": _walk_only,
+    "replay": _walk_only,
+    "contact_and_resync": _contact_and_resync,
+    "degraded_cadence": _degraded,
+    "tier_growth": _grow,
+    # a TPU hands [N, K] columns back column-major
+    "column_major": _walk_only,
+}
+
+
+@pytest.mark.parametrize("case", [*HERD_CASES, "all_at_once"])
+def test_hinted_diff_equals_the_whole_scan(case):
+    """Two managers over one seeded sequence of 220 ticks: one is told
+    which rows may differ (as ``EntityPlane`` tells it), one never.
+    Tick by tick the pairs are the same bytes to the same recipients
+    in the same order and the counters the benchmark reads agree; at
+    the end every peer's ledger is the same, and is what a client that
+    applied the frames holds."""
+    seed = sorted([*HERD_CASES, "all_at_once"]).index(case) + 1
+    herd = Herd(seed)
+    rng = random.Random(seed)
+    told, untold = (InterestManager(metrics=Metrics()) for _ in range(2))
+    managers = (told, untold)
+    clients: dict = {}
+    for slot in range(20):
+        herd.alloc(slot, slot % len(herd.peers), slot // Herd.PER_CUBE)
+    events = (list(HERD_CASES.values()) if case == "all_at_once"
+              else [HERD_CASES[case]])
+    every = case == "all_at_once"
+    seen = {"full": 0, "delta": 0, "replay": 0, "skipped": 0,
+            "silent_stirs": 0}
+
+    def counts(mgr):
+        return mgr.metrics.snapshot()["counters"]
+
+    for t in range(220):
+        still = (case == "replay" or every) and t % 6 == 4
+        if not still:
+            for event in events:
+                event(herd, t, managers)
+            if (case == "order_only" or every) and t % 4 == 2:
+                herd.stir(t % 5)             # and nothing else
+            else:
+                for _ in range(rng.randrange(3)):
+                    herd.move(herd.some_live())
+                herd.stir(rng.randrange(5))
+        full = (case == "full_between" or every) and t % 9 == 8
+        kind = herd.tick(full=full and not still)
+        seen[kind] += 1
+        if (case == "skip_frames" or every) and t % 8 in (5, 6) and t > 8:
+            seen["skipped"] += 1             # apply(skip_frames=True)
+            continue
+        cap = herd.plane._cap
+        before = dict(counts(told))
+        layout = (np.asfortranarray if case == "column_major" or every
+                  else np.asarray)
+        a = told.build_pairs(herd.plane, layout(herd.plane._pos),
+                             layout(herd.targets), cap, None,
+                             herd.take_owed())
+        b = untold.build_pairs(herd.plane, herd.plane._pos, herd.targets,
+                               cap)
+        assert [(m.wire, to) for m, to in a] == \
+            [(m.wire, to) for m, to in b], f"tick {t} ({kind})"
+        for name in ("interest.rows_diffed", "interest.entries"):
+            assert counts(told).get(name) == counts(untold).get(name), (t, name)
+        assert (told._visible == untold._visible).all()
+        assert told.stats() == untold.stats()
+        if (not a and kind == "delta" and counts(told)["interest.rows_scanned"]
+                > before["interest.rows_scanned"]):
+            seen["silent_stirs"] += 1
+        for m, to in a:
+            for peer in to:
+                assert clients.setdefault(peer, ReplayClient()).apply(m)
+
+    # the case happened, and the hint was what answered it
+    c = counts(told)
+    assert c["interest.hinted_ticks"] > 150 - 60 * every
+    assert c["interest.hinted_ticks"] + c["interest.scanned_ticks"] \
+        == told._ticks == untold._ticks
+    assert counts(untold).get("interest.hinted_ticks") is None
+    assert c["interest.rows_scanned"] < counts(untold)[
+        "interest.rows_scanned"] / 2
+    assert seen["delta"] > 100 and seen["full"] >= 1
+    if case in ("order_only", "all_at_once"):
+        assert seen["silent_stirs"] >= 10 + 30 * (not every)
+    if case in ("replay", "all_at_once"):
+        assert seen["replay"] >= 30
+    if case in ("skip_frames", "all_at_once"):
+        assert seen["skipped"] >= 50
+    if case in ("full_between", "all_at_once"):
+        assert seen["full"] >= 20
+    if case in ("tier_growth", "all_at_once"):
+        assert herd.plane._cap == 128 and seen["full"] >= 3
+    if case in ("contact_and_resync", "all_at_once"):
+        assert told.resyncs == untold.resyncs >= 4
+        assert uuid.UUID(int=0xFEED) in clients
+
+    for peer in herd.peers:
+        pid = herd.plane._peer_ids[peer]
+        held = told.ledger(peer, pid)
+        assert held == untold.ledger(peer, pid)
+        got = clients[peer].snapshot() if peer in clients else {}
+        for wid, world in enumerate(herd.plane._world_names):
+            assert got.get(world, {}) == {
+                uuid.UUID(bytes=key): tuple(
+                    float(v) for v in np.frombuffer(pos_b, np.float32))
+                for key, (w, pos_b) in held.items() if w == wid
+            }
+        if peer in clients:
+            assert clients[peer].stats()["deltas_refused"] == 0
+            assert clients[peer].stats()["gaps_seen"] == 0
 
 
 # endregion

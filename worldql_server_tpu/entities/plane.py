@@ -278,6 +278,15 @@ class EntityPlane:
         self._last_targets: np.ndarray | None = None
         self._last_counts: np.ndarray | None = None
         self._last_pos: np.ndarray | None = None
+        #: what this plane owes the interest manager's next
+        #: ``build_pairs``: the rows of the columns it reads that may
+        #: differ from what its LAST call read, as ``(closures, roster)``
+        #: — the ``rows`` of every delta tick applied since, and the
+        #: slots allocated or released since (``live``, uuid, world).
+        #: None: the plane cannot name them (no call yet, a full tick,
+        #: a shed streak that owes more than a tier) and the manager
+        #: scans every row. Without a manager it stays None.
+        self._owed: tuple[list, list] | None = None
         self.delta_sim_ticks = 0
         self.full_sim_ticks = 0
         self.delta_reused = 0
@@ -691,6 +700,8 @@ class EntityPlane:
         self._pid[slot] = pid
         self._vel[slot] = 0.0
         self._live[slot] = True
+        if self._owed is not None:
+            self._owed[1].append(slot)
         # slot identity changed: cached frames keyed on row indices
         # could alias the new occupant — drop them all
         self._frame_cache.clear()
@@ -764,6 +775,8 @@ class EntityPlane:
             if self._have_last:
                 self._last_targets[slot] = -1
                 self._last_counts[slot] = 0
+        if self._owed is not None:
+            self._owed[1].append(slot)
         uuid = self._uuid_of.pop(slot)
         del self._slot_of[uuid]
         self._slot_of_key.pop(uuid.bytes, None)
@@ -1275,7 +1288,15 @@ class EntityPlane:
         ``(message, targets)`` delivery pairs for the tick's batched
         deliver. ``skip_frames`` (tick-deadline degradation) applies
         the writeback + churn but sheds the frame leg — counted, never
-        silent."""
+        silent.
+
+        With an interest manager the plane tells it which rows to
+        read (``_owed``): a delta tick's closure, whatever earlier
+        delta ticks shed by ``skip_frames`` changed and still owe, and
+        the slots allocated or released since the manager's last call;
+        a replay tick adds none. After a full tick (the first one, a
+        tier change, ``abort_tick``, a mispredict, churn past the
+        threshold) it names none, and the manager scans every row."""
         self._tick_inflight = False
         t0 = time.perf_counter()
         cap = result["cap"]
@@ -1310,13 +1331,18 @@ class EntityPlane:
             # retain this tick as the delta replay source — as
             # WRITABLE copies: np.asarray of a device buffer is a
             # read-only zero-copy view, and delta ticks splice their
-            # sub-results into these in place
+            # sub-results into these in place. ROW-major copies: a TPU
+            # hands an [N, K] column back column-major, and everything
+            # from here on reads and writes rows (the delta splice,
+            # the frame leg's gather of the rows a tick changed — 13 ms
+            # a tick at 28K rows of a column-major 131,072 x 32)
             if self._delta_ticks:
-                self._last_pos = np.array(pos)
-                self._last_targets = np.array(targets)
-                self._last_counts = np.array(counts)
+                self._last_pos = pos = np.array(pos, order="C")
+                self._last_targets = targets = np.array(targets, order="C")
+                self._last_counts = counts = np.array(counts)
                 self._have_last = True
                 self._last_cap = cap
+            self._owed = None    # every row is new: nothing to vouch for
         self.last_churn = int(moved_slots.size)
 
         # 3. neighbor frames: one message per entity with >= 1 target,
@@ -1327,8 +1353,15 @@ class EntityPlane:
             self.frames_skipped += 1
             if self.metrics is not None:
                 self.metrics.inc("sim.frames_skipped")
+            # a long shed streak owes more rows than a scan reads
+            if self._owed is not None and (
+                sum(map(len, self._owed[0])) + len(self._owed[1]) > cap
+            ):
+                self._owed = None
         elif self.interest is not None:
-            pairs = self.interest.build_pairs(self, pos, targets, cap, trace)
+            pairs = self.interest.build_pairs(
+                self, pos, targets, cap, trace, self._take_owed(),
+            )
         else:
             pairs = self._build_frames(pos, targets, counts, cap)
 
@@ -1365,6 +1398,20 @@ class EntityPlane:
             trace.tag(sim=tags)
         return pairs
 
+    def _take_owed(self):
+        """Settle with the interest manager: the ``changed`` word of
+        its ``build_pairs`` — ``(rows, roster)`` sorted and unique, or
+        None where the plane cannot name them — and a clean slate for
+        what the columns do from here on."""
+        owed, self._owed = self._owed, ([], [])
+        if owed is None:
+            return None
+        closures, roster = owed
+        roster = np.unique(np.asarray(roster, np.intp))
+        if len(closures) == 1 and not roster.size:
+            return closures[0], roster      # a closure is sorted as made
+        return np.unique(np.concatenate([roster, *closures])), roster
+
     def _apply_delta(self, result: dict):
         """Splice a delta sub-tick over the retained last-tick arrays:
         closure rows take the freshly computed values, clean rows keep
@@ -1379,6 +1426,8 @@ class EntityPlane:
         self._last_targets[rows] = result["targets"][:n]
         self._last_counts[rows] = result["counts"][:n]
         self._last_pos[rows] = pos_sub
+        if self._owed is not None:
+            self._owed[0].append(rows)
 
         # writeback + churn for closure rows the wire didn't touch
         # mid-flight (same mask the full path applies tier-wide);

@@ -170,21 +170,21 @@ def _fitted(a: np.ndarray, shape: tuple, fill) -> np.ndarray:
     return out
 
 
-def _entry_ids(pid, wid, keys) -> np.ndarray:
-    """(recipient, world, uuid) of each entry as one 24-byte value."""
-    return np.concatenate([
-        pid.astype(np.int32)[:, None].view(np.uint8),
-        wid.astype(np.int32)[:, None].view(np.uint8),
-        keys,
-    ], axis=1).view("V24").ravel()
-
-
 class _Snapshot:
     """The columns the last tick's diff read, row by row. A ``synced``
     peer holds exactly its view of them — the live rows whose targets
     list it, each under that row's uuid, world and position — so the
     next tick's diff is the rows that differ from here, and no ledger
-    is kept per peer."""
+    is kept per peer.
+
+    ``targets`` holds a row's recipients in canonical form (sorted, a
+    dead row's all -1), so a row whose recipients came back in another
+    ORDER equals its snapshot, and only the rows a diff kept are ever
+    written. Who owes whom which rows: the caller of
+    :meth:`InterestManager.build_pairs` may vouch that every row it
+    does not name still holds what the last call read; the snapshot
+    then answers for those rows unread, and the named ones are
+    compared. A caller that names none is owed the whole scan."""
 
     __slots__ = ("live", "keys", "wid", "pos", "targets")
 
@@ -336,25 +336,37 @@ class InterestManager:
     # region: frame building
 
     def build_pairs(self, plane, pos, targets, cap: int,
-                    trace=None) -> list:
+                    trace=None, changed=None) -> list:
         """Replace ``EntityPlane._build_frames`` for one applied tick:
         per-recipient delta/full frames instead of per-entity
         broadcast. Returns the same ``(message, [target_uuid])`` pair
         shape ``PeerMap.deliver_batch`` consumes. The two legs are
         spans of the tick's ``trace`` (inside ``tick.sim.apply``):
         ``tick.sim.interest.diff`` (who sees which rows, each peer's
-        ledger diff) and ``tick.sim.interest.encode``."""
+        ledger diff) and ``tick.sim.interest.encode``.
+
+        ``changed`` is the caller's word on which rows of the columns
+        (``pos``, ``targets``, the plane's ``_live`` / ``_uuid_bytes`` /
+        ``_wid``) may differ from what the LAST call read:
+        ``(rows, roster)``, sorted unique row indices, ``roster`` the
+        part of ``rows`` whose ``live``, uuid or world may differ too.
+        The caller owes every such row since that call, those of ticks
+        it applied without calling here included; the diff then reads
+        those rows alone. None (the default): the caller cannot name
+        them, and every row is compared. The frames are the same
+        either way."""
         self._ticks += 1
         if trace is None:
             trace = NULL_TRACE
         with trace.span("tick.sim.interest.diff"):
-            specs = self._diff_specs(plane, pos, targets, cap)
+            specs = self._diff_specs(plane, pos, targets, cap, changed)
         with trace.span("tick.sim.interest.encode"):
             pairs = self._encode_specs(plane, specs)
         self.last_bytes = sum(len(m.wire) for m, _ in pairs)
         return pairs
 
-    def _diff_specs(self, plane, pos, targets, cap: int) -> list:
+    def _diff_specs(self, plane, pos, targets, cap: int,
+                    changed=None) -> list:
         """Every recipient's frame decision for this tick:
         ``[(uuid, state, frame_specs, new_state, is_resync, complete)]``
         with a frame spec ``(kind, world, keys, pos, tomb)`` (columns of
@@ -362,7 +374,9 @@ class InterestManager:
         synced to the snapshot; ``complete``: ``new_state`` is the
         peer's whole view, so committing it syncs the peer."""
         live = plane._live[:cap]
-        targets = np.asarray(targets)[:cap]
+        # row-major: every leg below gathers ROWS, and a device may
+        # hand the column back column-major (no copy when it is not)
+        targets = np.ascontiguousarray(np.asarray(targets)[:cap])
         self.last_near = self.last_far = self.last_demoted = 0
         if self.near_radius > 0.0 or self.bandwidth_bytes:
             specs, looked_at = self._walk_specs(
@@ -370,7 +384,7 @@ class InterestManager:
             )
         else:
             specs, walk, looked_at = self._snapshot_specs(
-                plane, pos, targets, live,
+                plane, pos, targets, live, changed,
             )
             if walk is None or walk:
                 walked, n = self._walk_specs(
@@ -385,16 +399,21 @@ class InterestManager:
             self.metrics.inc("interest.rows_diffed", looked_at)
         return specs
 
-    def _snapshot_specs(self, plane, pos, targets, live):
+    def _snapshot_specs(self, plane, pos, targets, live, changed):
         """Diff this tick's columns against the snapshot: the rows that
         differ give every synced peer's delta at once (entered or moved
         rows as positioned entries, rows that left or changed identity
-        as tombstones), then the snapshot takes the tick. Returns
+        as tombstones), then the snapshot takes those rows. Which rows
+        are read follows ``changed`` (:meth:`build_pairs`): the rows
+        the caller names, ``live`` / uuid / world for its roster slots
+        only; every row when it names none, or when the snapshot had
+        to grow (a new tier is nobody's to vouch for). Returns
         ``(specs, walk, rows that differed)``; ``walk`` is the pids whose
         ledger :meth:`_walk_specs` must walk instead (first contact,
         resync), or None for every peer (degraded near cadence)."""
         snap = self._snap
         cap = len(live)
+        shape = snap.targets.shape
         n, k = snap.fit(*targets.shape)     # rows past cap: dead now
         uuids = plane._peer_uuids
         live = _fitted(live, (n,), False)
@@ -419,23 +438,45 @@ class InterestManager:
             ):
                 walk.add(pid)
 
+        # the rows to read (dead on both sides: nothing to say), and
+        # the places among them whose identity is read too
+        whole = changed is None or shape != (n, k)
+        if whole:
+            at = np.flatnonzero(live | snap.live)
+            roster = slice(None)
+        else:
+            def named(a):
+                a = np.asarray(a, np.intp)
+                a = a[a < n]
+                return a[live[a] | snap.live[a]]
+
+            at, roster = named(changed[0]), named(changed[1])
+            roster = np.searchsorted(at, roster)
+        if self.metrics is not None:
+            self.metrics.inc("interest.rows_scanned", n if whole else len(at))
+            self.metrics.inc("interest.scanned_ticks" if whole
+                             else "interest.hinted_ticks")
+
         # rows that differ (bit for bit: -0.0 and NaN are positions
-        # too); a row whose targets only changed ORDER drops out below
-        moved = (
-            (live != snap.live) | (wid != snap.wid)
-            | (pos32.view(np.uint32) != snap.pos.view(np.uint32)).any(axis=1)
-            | (keys.view(np.uint64) != snap.keys.view(np.uint64)).any(axis=1)
+        # too). The snapshot's recipients are sorted, so one gather and
+        # one sort of the new side settle a row whose targets only
+        # changed ORDER: it equals its snapshot and drops out here.
+        differs = (
+            pos32.take(at, axis=0).view(np.uint32)
+            != snap.pos.take(at, axis=0).view(np.uint32)
+        ).any(axis=1)
+        ro = at[roster]
+        differs[roster] |= (
+            (live[ro] != snap.live[ro]) | (wid[ro] != snap.wid[ro])
+            | (keys[ro].view(np.uint64)
+               != snap.keys[ro].view(np.uint64)).any(axis=1)
         )
-        cand = np.flatnonzero(
-            (moved | (targets != snap.targets).any(axis=1))
-            & (live | snap.live)
-        )
-        new_t = np.where(live[cand, None], targets[cand], -1)
-        old_t = np.where(snap.live[cand, None], snap.targets[cand], -1)
+        new_t = targets.take(at, axis=0)
+        new_t[~live[at]] = -1
         new_t.sort(axis=1)
-        old_t.sort(axis=1)
-        keep = moved[cand] | (new_t != old_t).any(axis=1)
-        rows, new_t, old_t = cand[keep], new_t[keep], old_t[keep]
+        keep = differs | (new_t != snap.targets.take(at, axis=0)).any(axis=1)
+        rows, new_t, content = at[keep], new_t[keep], differs[keep]
+        old_t = snap.targets[rows]
         m = len(rows)
 
         def pairs(t):
@@ -443,8 +484,8 @@ class InterestManager:
             first = np.ones(t.shape, bool)
             first[:, 1:] = t[:, 1:] != t[:, :-1]
             mask = first & (t >= 0)
-            at = np.broadcast_to(np.arange(m)[:, None], t.shape)[mask]
-            return t[mask].astype(np.int64) * m + at
+            idx = np.broadcast_to(np.arange(m)[:, None], t.shape)[mask]
+            return t[mask].astype(np.int64) * m + idx
 
         specs: list = []
         if m:
@@ -466,38 +507,51 @@ class InterestManager:
                 if pid < len(uuids) and not fast[pid]:
                     walk.add(pid)
 
+            # every identity a kept row has (places 0..m-1) or had
+            # (m..2m-1), in (world, uuid) order: ``place`` ranks them
+            # all apart (ties as they stand here), ``ident`` ranks the
+            # same identity the same
+            ids_w = np.concatenate([wid[rows], snap.wid[rows]])
+            ids_k = np.concatenate([keys[rows], snap.keys[rows]]).view(">u8")
+            by_id = np.lexsort((ids_k[:, 1], ids_k[:, 0], ids_w))
+            place = np.empty(2 * m, np.int64)
+            place[by_id] = np.arange(2 * m)
+            s_w, s_k = ids_w[by_id], ids_k[by_id]
+            fresh = np.ones(2 * m, bool)
+            fresh[1:] = (s_w[1:] != s_w[:-1]) | (s_k[1:] != s_k[:-1]).any(axis=1)
+            ident = np.empty(2 * m, np.int64)
+            ident[by_id] = np.cumsum(fresh)
+
             # entries of the peers that ride the snapshot
-            content = moved[rows]
-            rekeyed = (
-                (keys[rows].view(np.uint64)
-                 != snap.keys[rows].view(np.uint64)).any(axis=1)
-                | (wid[rows] != snap.wid[rows])
-            )
+            rekeyed = ident[:m] != ident[m:]
             fast = _fitted(fast, (max(top, len(fast)),), False)
             put = (~stays | content[new_at]) & fast[new_pid]
             drop = (~stayed | rekeyed[old_at]) & fast[old_pid]
-            e_pid, e_row = new_pid[put], rows[new_at[put]]
-            t_pid, t_row = old_pid[drop], rows[old_at[drop]]
-            e_keys, e_wid = keys[e_row], wid[e_row]
-            t_keys, t_wid = snap.keys[t_row], snap.wid[t_row]
+            e_pid, e_at = new_pid[put], new_at[put]
+            t_pid, t_at = old_pid[drop], old_at[drop] + m
             if len(t_pid) and len(e_pid):
                 # an entity that only changed row is no departure
-                gone = ~np.isin(_entry_ids(t_pid, t_wid, t_keys),
-                                _entry_ids(e_pid, e_wid, e_keys))
-                t_pid, t_row = t_pid[gone], t_row[gone]
-                t_keys, t_wid = t_keys[gone], t_wid[gone]
+                gone = ~np.isin(t_pid * (2 * m) + ident[t_at],
+                                e_pid * (2 * m) + ident[e_at])
+                t_pid, t_at = t_pid[gone], t_at[gone]
+            # one integer an entry, ordered as the frames are:
+            # (recipient, world, uuid)
+            e_pid = np.concatenate([e_pid, t_pid])
+            e_at = np.concatenate([e_at, t_at])
+            order = np.argsort(e_pid * (2 * m) + place[e_at])
+            e_pid, e_at = e_pid[order], e_at[order]
+            src = rows[e_at % m]
+            e_keys, e_wid, e_pos = keys[src], wid[src], pos32[src]
+            tomb = e_at >= m         # these ride as what the row WAS
+            left = src[tomb]
+            e_keys[tomb], e_wid[tomb] = snap.keys[left], snap.wid[left]
+            e_pos[tomb] = snap.pos[left]
             specs = self._delta_specs(
-                uuids,
-                np.concatenate([e_pid, t_pid]),
-                np.concatenate([e_wid, t_wid]),
-                np.concatenate([e_keys, t_keys]),
-                np.concatenate([pos32[e_row], snap.pos[t_row]]),
-                np.concatenate([np.zeros(len(e_pid), np.uint8),
-                                np.ones(len(t_pid), np.uint8)]),
+                uuids, e_pid, e_wid, e_keys, e_pos, tomb.view(np.uint8),
             )
 
         # a synced peer about to be walked holds its view of the
-        # snapshot as it is NOW, before it takes this tick
+        # snapshot as it is NOW, before it takes this tick's rows
         behind = [pid for pid in (range(len(uuids)) if self._tier_degraded
                                   else walk)
                   if uuids[pid] in self._peers
@@ -505,23 +559,19 @@ class InterestManager:
         if behind:
             for pid, held in snap.ledgers_of(behind).items():
                 self._peers[uuids[pid]].state = held
-        snap.live[cand] = live[cand]
-        snap.keys[cand] = keys[cand]
-        snap.wid[cand] = wid[cand]
-        snap.pos[cand] = pos32[cand]
-        snap.targets[cand] = targets[cand]
+        snap.live[rows] = live[rows]
+        snap.keys[rows] = keys[rows]
+        snap.wid[rows] = wid[rows]
+        snap.pos[rows] = pos32[rows]
+        snap.targets[rows] = new_t
         return specs, (None if self._tier_degraded else walk), m
 
     def _delta_specs(self, uuids, pid, wid, keys, pos, tomb) -> list:
-        """Entries of many peers as per-peer delta frame specs: grouped
-        by recipient and world, ordered by uuid, ``FRAME_CHUNK`` a
-        frame."""
+        """Entries of many peers, ordered by (recipient, world, uuid),
+        as per-peer delta frame specs: one run of frames a recipient
+        and world, ``FRAME_CHUNK`` entries a frame."""
         if not len(pid):
             return []
-        be = np.ascontiguousarray(keys).view(">u8")
-        order = np.lexsort((be[:, 1], be[:, 0], wid, pid))
-        pid, wid = pid[order], wid[order]
-        keys, pos, tomb = keys[order], pos[order], tomb[order]
         cut = np.flatnonzero((pid[1:] != pid[:-1]) | (wid[1:] != wid[:-1])) + 1
         starts = np.concatenate(([0], cut)).tolist()
         frames_of: dict[int, list] = {}
