@@ -144,3 +144,60 @@ def test_segment_sort_compiles_at_a_delta_tier(spec):
         spec((cap,), jnp.int64), spec((cap,), jnp.int64),
         spec((cap,), jnp.int32), n_buckets=cap // 2,
     ).compile()
+
+
+# region: the mesh programs of `worlds-64x10k-sharded` (ISSUE 32)
+
+#: 640,000 rows over four key ranges: 160,000 a shard, the 2^18 tier;
+#: ~26,400 cubes a shard; the fullest cube holds 256
+MESH_SHARD_CAP = 1 << 18
+MESH_PROBE_BUCKETS = tb.probe_buckets_for(26_400)
+MESH_K = 256
+
+
+@pytest.fixture(scope="module")
+def mesh_backend(topo, one_chip):
+    """The sharded backend on a {batch 1, space 4} mesh of the four
+    DESCRIBED chips (``one_chip`` keeps the compile cache off)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from worldql_server_tpu.parallel import ShardedTpuSpatialBackend
+
+    devices = np.array(topo.devices[:4]).reshape(1, 4)
+    return ShardedTpuSpatialBackend(16, Mesh(devices, ("batch", "space")))
+
+
+def _mesh_base():
+    s = jax.ShapeDtypeStruct
+    return (
+        s((4, MESH_SHARD_CAP), jnp.int64), s((4, MESH_SHARD_CAP), jnp.int64),
+        s((4, MESH_SHARD_CAP), jnp.int32), s((4, MESH_SHARD_CAP), jnp.int32),
+        s((4, MESH_PROBE_BUCKETS, 24), jnp.int32), s((4, 1), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("m,t_cap", [(32, 1 << 10), (1024, 1 << 18)])
+def test_mesh_resolve_csr_compiles_for_four_chips(mesh_backend, m, t_cap):
+    """A served tick's tier and a warm-up burst's: the program is named
+    as the trace will print it and merges over the interconnect."""
+    kernel = mesh_backend._make_kernel("csr", ("base",), (MESH_K,), t_cap)
+    text = kernel.lower(*_mesh_base(), *_queries(jax.ShapeDtypeStruct, m)).compile().as_text()
+    assert "jit_mesh_resolve_csr" in text and "all-reduce" in text
+
+
+def test_mesh_resolve_dense_compiles_for_four_chips(mesh_backend):
+    kernel = mesh_backend._make_kernel("dense", ("base",), (MESH_K,), None)
+    text = kernel.lower(*_mesh_base(), *_queries(jax.ShapeDtypeStruct, 32)).compile().as_text()
+    assert "jit_mesh_resolve_dense" in text and "all-reduce" in text
+
+
+def test_mesh_repack_compiles_for_four_chips(mesh_backend):
+    kernel = mesh_backend._pack_kernel(1 << 12, 1024, 1, 1 << 18)
+    s = jax.ShapeDtypeStruct
+    text = kernel.lower(s((1024, 1), jnp.int32),
+                        s((1 << 18,), jnp.int32)).compile().as_text()
+    assert "jit_mesh_repack" in text
+
+
+# endregion

@@ -30,7 +30,9 @@ PR 5 observability substrate:
   compute_ms / d2h_ms / decode_ms, host-side brackets of the
   dispatch/collect instrumentation points — see
   spatial/tpu_backend.py) and feeds the
-  ``device.{encode,h2d,compute,d2h,decode}_ms`` histograms.
+  ``device.{encode,h2d,compute,d2h,decode}_ms`` histograms, and
+  ``device.mesh_fetch_ms`` on a tick whose timing carries that leg
+  (parallel/sharded_backend.py).
 * **Live buffer gauge** — :func:`live_device_bytes` sums live jax
   array footprints at scrape time (the ``device`` gauge), without ever
   importing jax on its own: a CPU-backend server that never loaded jax
@@ -213,8 +215,10 @@ class DeviceTelemetry:
                 for k, v in timing.items()
             })
             if self.metrics is not None:
+                # mesh_fetch_ms: only the sharded backend's timing has
+                # it, and only on a tick that fetched regions
                 for leg in ("encode_ms", "h2d_ms", "compute_ms", "d2h_ms",
-                            "decode_ms"):
+                            "decode_ms", "mesh_fetch_ms"):
                     value = timing.get(leg)
                     if isinstance(value, (int, float)):
                         self.metrics.observe_ms(

@@ -93,6 +93,18 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
         self.n_batch = mesh.shape["batch"]
         self.n_space = mesh.shape["space"]
         self._kernels: dict[tuple, object] = {}
+        # what the mesh adds to a served tick, beside compact_fetches /
+        # delta_reused (device_stats exports them): served batches
+        # handed to a mesh_resolve program, their real rows before
+        # padding, the bytes their pmax merges reduce, and the
+        # per-batch-shard result regions fetched and walked. The boot
+        # walk (spatial/precompile.py) and the rare overflow
+        # re-resolve call the programs below this seam and are not in
+        # them.
+        self.mesh_dispatches = 0
+        self.mesh_query_rows = 0
+        self.mesh_merge_bytes = 0
+        self.mesh_region_fetches = 0
 
     def supports_delta_ticks(self) -> bool:
         """Result reuse runs on the mesh via PER-SHARD FLAT-REGION
@@ -145,6 +157,16 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
     # region: device upload seams
 
     def _upload_base(self, keys, keys2, pids, k) -> dict:
+        # split the LIVE prefix: the host arrays arrive padded to a
+        # power of two with PAD_KEY rows (`_install_base`), and a split
+        # of the padded length hands the last shards the padding (at
+        # 640,000 rows: shards of 262,144 / 262,144 / 115,712 live rows
+        # and one of 408,576 pad rows, which also set the capacity a
+        # shard, 2^19 where 2^18 holds a quarter of the rows). Pad rows
+        # hold nothing, so no shard needs them; `_compact_device` keeps
+        # them out of its balance the same way.
+        live_n = int(np.searchsorted(keys, PAD_KEY, side="left"))
+        keys, keys2, pids = keys[:live_n], keys2[:live_n], pids[:live_n]
         splits = split_at_run_boundaries(keys, self.n_space)
         cap = next_pow2(max(b - a for a, b in zip(splits, splits[1:])))
 
@@ -409,11 +431,22 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
 
     # region: dispatch
 
+    #: smallest query tier of a mesh program. Every (query tier, t_cap)
+    #: pair is a program of its own, compiled at first use on the event
+    #: loop (0.57-0.85 s each on four v5e chips), and the adaptive
+    #: t_cap walks four to six tiers under each query tier. With the
+    #: base class's floor of 8, a served load of 5-35 dirty rows a tick
+    #: wandered over the tiers 8, 16 and 32: ~30 programs compiled in
+    #: the warm-up of `worlds-64x10k.hot-cube` and one in its window
+    #: (PERF.md section 6, PR 32). Padding 8 rows to 32 costs the
+    #: devices microseconds; the boot walk's ladder ends here too.
+    MIN_QUERY_TIER = 32
+
     def _query_cap(self, m: int) -> int:
         # Batch capacity must shard evenly over 'batch': power-of-two
         # tier, rounded up to a multiple of n_batch (which need not be
         # a power of two).
-        cap = max(next_pow2(m), self.n_batch)
+        cap = max(next_pow2(m), self.MIN_QUERY_TIER, self.n_batch)
         return -(-cap // self.n_batch) * self.n_batch
 
     def _make_kernel(self, variant: str, kinds: tuple, ks: tuple, extra):
@@ -443,7 +476,8 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
             # Exactly one 'space' shard holds any cube's base run, and
             # the delta part is identical on every shard — max is a
             # lossless merge either way.
-            return jax.lax.pmax(tgt, "space")
+            with jax.named_scope("mesh.merge"):
+                return jax.lax.pmax(tgt, "space")
 
         in_specs = tuple(
             spec
@@ -465,16 +499,18 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                 # a run lives on exactly one space shard — the global
                 # raw counts (and therefore the layout every shard
                 # agrees on) are the pmax union
-                cnts = [
-                    jax.lax.pmax(c, "space") for c in cnts_local
-                ]
+                with jax.named_scope("mesh.merge"):
+                    cnts = [
+                        jax.lax.pmax(c, "space") for c in cnts_local
+                    ]
                 counts, flat, total = run_csr_assemble(
                     segs, los, cnts, cnts_local, queries, t_cap_local
                 )
                 # owner shards wrote real lanes, the rest -1: max is a
                 # lossless merge (same argument as the dense path)
-                flat = jax.lax.pmax(flat, "space")
-                total = jax.lax.pmax(total, "space")
+                with jax.named_scope("mesh.merge"):
+                    flat = jax.lax.pmax(flat, "space")
+                    total = jax.lax.pmax(total, "space")
                 return counts, flat, total.reshape(1)
 
             matched_csr = _shard_map(
@@ -484,7 +520,10 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                 ),
             )
 
-            def fn(*args):
+            # the jitted program's name is what a device trace and a
+            # retrace report show (`jit_mesh_resolve_csr`); it must not
+            # read as one of the one-chip kernels
+            def mesh_resolve_csr(*args):
                 counts, flat, totals = matched_csr(*args)
                 # any shard overflowing its local budget triggers the
                 # global retry sentinel
@@ -494,11 +533,18 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                     totals.sum(dtype=jnp.int32),
                 )
                 return counts, flat, total
+
+            fn = mesh_resolve_csr
         else:
-            fn = _shard_map(
+            matched_dense = _shard_map(
                 local, mesh=mesh, in_specs=in_specs,
                 out_specs=P("batch", None),
             )
+
+            def mesh_resolve_dense(*args):
+                return matched_dense(*args)
+
+            fn = mesh_resolve_dense
 
         in_shardings = tuple(
             NamedSharding(mesh, spec) for spec in in_specs
@@ -512,8 +558,49 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
             kernel = self._kernels[key] = self._make_kernel(
                 variant, kinds, ks, extra
             )
-            retrace.GUARD.register(f"sharded.match_{variant}", kernel)
+            retrace.GUARD.register(f"sharded.mesh_resolve_{variant}", kernel)
         return kernel
+
+    def _dispatch_encoded(self, m, *args, **kwargs):
+        """The served launch: count what was handed to the mesh."""
+        handle = super()._dispatch_encoded(m, *args, **kwargs)
+        payload = handle[1]
+        if payload is not None:
+            self.mesh_dispatches += 1
+            self.mesh_query_rows += m
+            self.mesh_merge_bytes += self._merge_bytes(payload)
+        return handle
+
+    def _merge_bytes(self, payload) -> int:
+        """Bytes the pmax merges of one resolve call reduce over
+        ``space`` (padded rows x lanes x 4, summed over the call's
+        merges and its batch shards): the dense program merges its
+        [M, sum K] table; the CSR program each segment's [M] raw run
+        lengths, the [t_cap] flat result and one total a batch shard."""
+        if payload[0] == "dense":
+            return 4 * int(payload[1].size)
+        counts, flat, _ = payload[2]
+        return 4 * (int(counts.size) + int(flat.size) + self.n_batch)
+
+    def collect_local_batch(self, handle):
+        """A mesh CSR collect fetches the per-batch-shard counts and
+        regions (``_compact_fetch`` or the whole flat) and walks them
+        (``_decode_packed`` / ``_decode_csr``) on the collect worker
+        thread, where the loop's spans do not reach: their wall is the
+        leg ``mesh_fetch_ms`` of the tick's timing. The base class
+        brackets exactly those two stretches as ``d2h_ms`` and
+        ``decode_ms``, but publishes them for every tick, the reuse
+        cache's deviceless ones included, as zeros; this leg exists
+        only on a tick whose regions were fetched."""
+        out = super().collect_local_batch(handle)
+        payload = handle[1]
+        if (
+            payload is not None and payload[0] == "csr"
+            # not re-resolved dense after an overflow: no regions then
+            and (timing := self.last_device_timing).get("path") == "csr"
+        ):
+            timing["mesh_fetch_ms"] = timing["d2h_ms"] + timing["decode_ms"]
+        return out
 
     def _dispatch(self, queries: tuple, segs, ks, kinds):
         flat = [a for seg in segs for a in seg]
@@ -548,7 +635,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
         if kernel is None:
             nb = self.n_batch
 
-            def pack_all(counts, flat):
+            def mesh_repack(counts, flat):
                 c3 = counts.reshape(nb, mq // nb, nseg)
                 f2 = flat.reshape(nb, flat_len // nb)
                 packed, totals = jax.vmap(
@@ -557,7 +644,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                 return packed.reshape(-1), totals
 
             kernel = self._kernels[key] = jax.jit(
-                pack_all,
+                mesh_repack,
                 in_shardings=(
                     self._sharding("batch", None),
                     self._sharding("batch"),
@@ -566,7 +653,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
                     self._sharding("batch"), self._sharding("batch"),
                 ),
             )
-            retrace.GUARD.register("sharded.pack_csr", kernel)
+            retrace.GUARD.register("sharded.mesh_repack", kernel)
         return kernel
 
     def _compact_fetch(self, counts, flat, total: int, t_cap: int):
@@ -602,6 +689,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
         concatenated; walk each shard's queries against its own
         bucket (mirrors the zoned-layout region walk below)."""
         nb = self.n_batch
+        self.mesh_region_fetches += nb
         bucket_local = len(packed) // nb
         m_local = counts.shape[0] // nb
         out: list = []
@@ -623,6 +711,7 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
         if counts.ndim == 1:
             return super()._decode_csr(counts, flat, m)
         nb = self.n_batch
+        self.mesh_region_fetches += nb
         t_cap_local = len(flat) // nb
         m_local = counts.shape[0] // nb
         out: list = []
@@ -645,6 +734,10 @@ class ShardedTpuSpatialBackend(TpuSpatialBackend):
     def device_stats(self) -> dict:
         stats = super().device_stats()
         stats["mesh"] = {"batch": self.n_batch, "space": self.n_space}
+        stats["mesh_dispatches"] = self.mesh_dispatches
+        stats["mesh_query_rows"] = self.mesh_query_rows
+        stats["mesh_merge_bytes"] = self.mesh_merge_bytes
+        stats["mesh_region_fetches"] = self.mesh_region_fetches
         # bytes of the space-sharded base each device actually holds —
         # a mesh that put everything on its first device shows here
         per_device: dict[int, int] = {}
