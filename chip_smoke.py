@@ -84,6 +84,7 @@ def build_native() -> None:
     subprocess.run(["make", "-C", str(ROOT / "native")], check=True)
     from worldql_server_tpu.protocol import codec, entity_wire
     from worldql_server_tpu.spatial import native_keys
+    from worldql_server_tpu.transports import zmq_pass
 
     wire = entity_wire.shared()
     keys = native_keys._native
@@ -93,6 +94,7 @@ def build_native() -> None:
         "can_encode_frames": wire is not None and wire.can_encode_frames,
         "key_kernel": keys is not None,
         "wql_encode_queries": getattr(keys, "_encode", None) is not None,
+        "wql_send_pass": zmq_pass.shared() is not None,
     }
     missing = [name for name, live in legs.items() if not live]
     require(not missing, f"native legs missing after make: {missing}")

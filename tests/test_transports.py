@@ -547,7 +547,13 @@ class PlainSocketSpy:
 def plain_sockets(monkeypatch):
     """Every plain socket the transport makes over a peer's PUSH
     socket, as spies, in the order of the handshakes. (pyzmq's own
-    asyncio sockets shadow an ADDRESS, and are left alone.)"""
+    asyncio sockets shadow an ADDRESS, and are left alone.) A spy
+    stands in the per-peer closure's way, not in the native pass's
+    (which writes to the libzmq handle: tests/test_zmq_send_pass.py),
+    so a transport made under this fixture is served by the closure."""
+    from worldql_server_tpu.transports import zmq_pass
+
+    monkeypatch.setattr(zmq_pass, "shared", lambda: None)
     made: list[PlainSocketSpy] = []
     shadow = zmq.Socket.shadow
 

@@ -5,10 +5,11 @@
     WQL_NATIVE_CODEC=native/libwqlcodec-tsan.so \
       python -m tools.tsan_hammer [--threads 8] [--iters 150]
 
-All four exported entry points release the GIL for their whole body
+All five exported entry points release the GIL for their whole body
 (``wql_decode_entities``, ``wql_encode_queries``,
-``wql_encode_entity_frames``, ``wql_areamap_probe``), so any hidden
-shared state inside ``native/codec.cpp`` / ``spatial.cpp`` — a static
+``wql_encode_entity_frames``, ``wql_areamap_probe``,
+``wql_send_pass``), so any hidden shared state inside
+``native/codec.cpp`` / ``spatial.cpp`` / ``sendpass.cpp`` — a static
 scratch buffer, an unguarded counter, lazily-built tables — is a real
 data race the moment two event loops, a collect worker, and a bench
 run call in concurrently. This driver creates genuine overlap:
@@ -33,6 +34,7 @@ import threading
 import uuid
 
 import numpy as np
+import zmq
 
 from worldql_server_tpu.protocol import (
     Instruction,
@@ -42,6 +44,7 @@ from worldql_server_tpu.protocol import (
 )
 from worldql_server_tpu.protocol.types import Entity, Vector3
 from worldql_server_tpu.spatial import native_keys
+from worldql_server_tpu.transports import zmq_pass
 
 
 def _batch(tid: int, n: int = 24) -> list[bytes]:
@@ -89,6 +92,10 @@ def hammer(threads: int, iters: int) -> int:
         print("tsan-hammer: stale library without the entity entry "
               "points", file=sys.stderr)
         return 2
+    if zmq_pass.load() is None:
+        print("tsan-hammer: stale library without the send pass",
+              file=sys.stderr)
+        return 2
 
     barrier = threading.Barrier(threads)
     errors: list[str] = []
@@ -107,6 +114,22 @@ def hammer(threads: int, iters: int) -> int:
                 b"".join(uuid.UUID(int=(tid << 64) | i).bytes
                          for i in range(n)),
                 np.uint8).reshape(n, 16)
+            # the flush's send pass: this thread's own sockets (a
+            # libzmq socket belongs to one thread), the one library
+            send_pass = zmq_pass.load()
+            send_pass.threads = 1 + tid % 3     # its own helpers too
+            ctx = zmq.Context()
+            pulls, pushes = [], []
+            for k in range(24):                 # three whole shares
+                pulls.append(ctx.socket(zmq.PULL))
+                pulls[-1].bind(f"inproc://hammer-{tid}-{k}")
+                pushes.append(ctx.socket(zmq.PUSH))
+                pushes[-1].connect(f"inproc://hammer-{tid}-{k}")
+            handles = [push.underlying for push in pushes]
+            payloads = [bytes([tid, i]) * (i + 1) for i in range(4)]
+            table = [[0, 1, 2, 3], [], [1, 3]] * 8
+            passed = (sum(map(len, table)), list(map(len, table)),
+                      [0] * len(table))
             barrier.wait()
             for it in range(iters):
                 # 1. wql_decode_entities — per-thread scratch, shared .so
@@ -134,6 +157,19 @@ def hammer(threads: int, iters: int) -> int:
                     probe = native_keys.areamap_probe(64, 64, seed=tid)
                     if probe is not None and probe["matched_rows"] < 0:
                         raise AssertionError("areamap probe corrupt")
+                # 5. wql_send_pass
+                total, taken, errs = send_pass(payloads, handles, table)
+                got = [[pull.recv(zmq.DONTWAIT) for _ in owed]
+                       for pull, owed in zip(pulls, table)]
+                if (total, list(taken), list(errs)) != passed \
+                        or got != [[payloads[i] for i in owed]
+                                   for owed in table]:
+                    raise AssertionError(
+                        f"send pass corrupted under concurrency: "
+                        f"{total} {list(taken)} {list(errs)}")
+            for sock in (*pushes, *pulls):
+                sock.close(linger=0)
+            ctx.term()
         except Exception as exc:  # noqa: BLE001 — reported, not dropped
             errors.append(f"thread {tid}: {type(exc).__name__}: {exc}")
 
@@ -149,7 +185,7 @@ def hammer(threads: int, iters: int) -> int:
         return 1
     print(f"tsan-hammer: OK — {threads} threads x {iters} iters over "
           "wql_decode_entities / wql_encode_queries / "
-          "wql_encode_entity_frames / wql_areamap_probe")
+          "wql_encode_entity_frames / wql_areamap_probe / wql_send_pass")
     return 0
 
 

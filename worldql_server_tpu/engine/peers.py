@@ -14,10 +14,11 @@ heartbeat-tracked (ZeroMQ-style, staleness-swept) or not
 from __future__ import annotations
 
 import asyncio
+import errno
 import logging
 import time
 import uuid as uuid_mod
-from typing import Awaitable, Callable, Iterable
+from typing import Awaitable, Callable, Iterable, NamedTuple, Sequence
 
 from ..observability.spans import Tracer
 from ..protocol import Instruction, Message, serialize_message
@@ -69,12 +70,36 @@ TryWrite = Callable[[FramedPayload], bool]
 TryWriteMany = Callable[[list[FramedPayload]], "bool | int"]
 
 
+class PassEnd(NamedTuple):
+    """What a transport attaches to a peer whose per-tick frames can
+    leave in ONE native pass with every other such peer's
+    (``transports/zmq_pass.py``: no interpreter between two sends,
+    and where a wake of libzmq's I/O thread is dear the peers' wakes
+    are paid side by side on a few threads). A peer without one, and
+    every write that is not a tick's fan-out, takes
+    ``try_write_many``."""
+
+    #: the pass, shared by all peers of the transport:
+    #: ``write(payloads, handles, frames) -> (total, taken, err)``;
+    #: ``handles[p]`` takes ``payloads[i] for i in frames[p]``; for each
+    #: peer how many frames its socket took from the front and the
+    #: errno that stopped it (0 none, ``EAGAIN`` the high-water mark)
+    write: Callable[[list[bytes], list[int], list[list[int]]],
+                    tuple[int, Sequence[int], Sequence[int]]]
+    #: this peer's socket handle, or 0 when it must not be written in
+    #: the pass right now (the closure's own guard, asked here too)
+    handle: Callable[[], int]
+    #: a send failed with anything but ``EAGAIN``: what the closure's
+    #: ``except Exception`` does (ZeroMQ: evict)
+    failed: Callable[[], None]
+
+
 class Peer:
     """Uniform outbound handle over any transport (peer.rs:33-88)."""
 
     __slots__ = ("uuid", "addr", "kind", "_send_raw", "_try_write",
-                 "_try_write_many", "tracks_heartbeat", "last_heartbeat",
-                 "closed", "shard", "slot", "_drain")
+                 "_try_write_many", "_pass_end", "tracks_heartbeat",
+                 "last_heartbeat", "closed", "shard", "slot", "_drain")
 
     def __init__(
         self,
@@ -85,6 +110,7 @@ class Peer:
         tracks_heartbeat: bool = False,
         try_write: TryWrite | None = None,
         try_write_many: TryWriteMany | None = None,
+        pass_end: PassEnd | None = None,
     ):
         self.uuid = uuid
         self.addr = addr
@@ -92,6 +118,7 @@ class Peer:
         self._send_raw = send_raw
         self._try_write = try_write
         self._try_write_many = try_write_many
+        self._pass_end = pass_end
         self.tracks_heartbeat = tracks_heartbeat
         self.last_heartbeat = time.monotonic()
         self.closed = False
@@ -129,18 +156,31 @@ class Peer:
         except Exception as exc:
             raise PeerSendError(str(exc)) from exc
 
+    def _sync_refused(self) -> bool:
+        """Whether the peer may NOT be written synchronously: it is
+        closed, or an awaited drain still owes it frames that a sync
+        write would overtake. Every sync writer asks here."""
+        return self.closed or self._drain is not None
+
     def try_write(self, framed: FramedPayload) -> bool:
         """Synchronous fast-path delivery; False = use ``send_raw``."""
-        if (self.closed or self._try_write is None
-                or self._drain is not None):
+        if self._try_write is None or self._sync_refused():
             return False
         return self._try_write(framed)
+
+    def pass_handle(self) -> int:
+        """The socket handle a flush's native pass writes this peer's
+        frames to; 0 = not in the pass (no such end, refused as any
+        sync write, or the transport says not now)."""
+        if self._pass_end is None or self._sync_refused():
+            return 0
+        return self._pass_end.handle()
 
     def try_write_many(self, framed_list: list[FramedPayload]) -> int:
         """Hand a whole per-tick frame list to the transport without
         awaiting. Returns how many frames it took, from the front:
         ``framed_list[taken:]`` is owed through ``send_raw``."""
-        if self.closed or self._drain is not None:
+        if self._sync_refused():
             return 0
         if self._try_write_many is not None:
             taken = self._try_write_many(framed_list)
@@ -324,17 +364,20 @@ class PeerMap:
           fan-out re-broadcasts the sender's bytes verbatim), skip
           re-serialization entirely;
         * frame once per transport kind (FramedPayload cache);
-        * ONE ``try_write_many`` per peer per tick — a WebSocket peer's
-          frames coalesce into a single transport write (writev-style),
-          a ZeroMQ peer's go to its socket one non-blocking message
-          each, in one plain loop: no task, no await.
+        * ONE sync write per peer per tick, no task, no await — a
+          WebSocket peer's frames coalesce into a single transport
+          write (``try_write_many``, writev-style); the ZeroMQ peers'
+          go to their sockets one non-blocking message each, all of
+          them in ONE native pass (``PassEnd``), or peer by peer
+          through ``try_write_many`` where the pass does not serve.
         What a sync path does not take (a saturated or closing
         transport, a ZeroMQ socket at its high-water mark: the frames
         from the first refused one on) falls back to awaited sends in
         one gather at the end, in order, and until that drain ends the
         peer's sync paths refuse, so nothing overtakes it. Counters
         ``delivery.sync_frames`` / ``delivery.awaited_frames`` say
-        which way the frames went. ``t_ingress_ns`` is the batch's
+        which way the frames went, ``delivery.pass_frames`` how many
+        of the sync ones the native pass took. ``t_ingress_ns`` is the batch's
         frame-clock stamp
         (``time.monotonic_ns`` at ticker flush start, 0 = unclocked):
         both paths close it at delivery completion into the
@@ -410,12 +453,15 @@ class PeerMap:
     ) -> int:
         t_start_ns = time.monotonic_ns()
         tracer = self._tracer
-        outbox: dict[Peer, list[FramedPayload]] = {}
-        n = n_msgs = 0
+        # the batch's messages, numbered; a peer's list holds the
+        # numbers of its frames in batch order, so the native pass's
+        # table is built from plain ints, not from a frame object each
+        batch: list[FramedPayload] = []
+        outbox: dict[Peer, list[int]] = {}
+        n = 0
         with tracer.span("deliver.outbox") as span:
             bytes_before = self.bytes_delivered
             for message, uuids in pairs:
-                n_msgs += 1
                 data = message.wire
                 framed = FramedPayload(
                     serialize_message(message) if data is None else data
@@ -423,6 +469,9 @@ class PeerMap:
                 ctx = getattr(message, "trace_ctx", None)
                 if ctx is not None:
                     framed.ctx = ctx
+                i = len(batch)
+                batch.append(framed)
+                size = len(framed.payload)
                 for u in uuids:
                     p = self._map.get(u)
                     if p is None:
@@ -432,27 +481,68 @@ class PeerMap:
                             self.on_frame_loss(u)
                         continue
                     n += 1
-                    self.bytes_delivered += len(framed.payload)
-                    outbox.setdefault(p, []).append(framed)
+                    self.bytes_delivered += size
+                    frames = outbox.get(p)
+                    if frames is None:
+                        outbox[p] = [i]
+                    else:
+                        frames.append(i)
             span.tag(frames=n, peers=len(outbox),
                      bytes=self.bytes_delivered - bytes_before)
+        n_msgs = len(batch)
         # peers owed an awaited drain: (peer, the frames its sync path
         # left, the drain to wait for, the future this one resolves)
         slow: list[tuple[Peer, list[FramedPayload],
                          asyncio.Future | None, asyncio.Future]] = []
-        awaited = 0
+        awaited = passed = 0
+
+        def owe(p: Peer, frames: list[int], taken: int) -> None:
+            nonlocal awaited
+            awaited += len(frames) - taken
+            # joins the peer's chain of drains HERE, not at the drain
+            # task's first step: from this line on the peer's sync
+            # paths refuse
+            prev, p._drain = p._drain, asyncio.Future()
+            slow.append((p, [batch[i] for i in frames[taken:]],
+                         prev, p._drain))
+
         with tracer.span("deliver.write") as span:
-            for p, framed_list in outbox.items():
-                taken = p.try_write_many(framed_list)
-                if taken < len(framed_list):
-                    awaited += len(framed_list) - taken
-                    # joins the peer's chain of drains HERE, not at
-                    # the drain task's first step: from this line on
-                    # the peer's sync paths refuse
-                    prev, p._drain = p._drain, asyncio.Future()
-                    slow.append((p, framed_list[taken:], prev, p._drain))
+            # the peers whose transport offers a handle leave in one
+            # native pass AFTER this loop; everyone else (WebSocket,
+            # a peer with an awaited send in flight, a stale binding,
+            # an armed failpoint, no symbol) through the closure here
+            write = None
+            pass_peers: list[Peer] = []
+            handles: list[int] = []
+            tables: list[list[int]] = []
+            for p, frames in outbox.items():
+                handle = p.pass_handle()
+                if handle and (write is None or write is p._pass_end.write):
+                    write = p._pass_end.write
+                    pass_peers.append(p)
+                    handles.append(handle)
+                    tables.append(frames)
+                    continue
+                taken = p.try_write_many([batch[i] for i in frames])
+                if taken < len(frames):
+                    owe(p, frames, taken)
+            if write is not None:
+                passed, took, errs = write(
+                    [framed.payload for framed in batch], handles, tables
+                )
+                if passed < sum(map(len, tables)):
+                    # some socket stopped early: its high-water mark
+                    # (the rest waits in the drain, as after zmq.Again)
+                    # or an error (the rest fails there, counted)
+                    for p, frames, taken, err in zip(
+                        pass_peers, tables, took, errs
+                    ):
+                        if taken < len(frames):
+                            if err != errno.EAGAIN:
+                                p._pass_end.failed()
+                            owe(p, frames, taken)
             span.tag(peers=len(outbox), slow_peers=len(slow),
-                     sync_frames=n - awaited)
+                     sync_frames=n - awaited, pass_peers=len(pass_peers))
         errors = 0
         if slow:
             # SEQUENTIAL per peer: concurrent send() calls on one
@@ -501,6 +591,8 @@ class PeerMap:
             # without an await against frames owed through send_raw
             self.metrics.inc("delivery.sync_frames", n - awaited)
             self.metrics.inc("delivery.awaited_frames", awaited)
+            # of the sync frames, those the native pass took
+            self.metrics.inc("delivery.pass_frames", passed)
             # e2e stamps, closed at batch completion (the slow-path
             # drain included — fast-path frames already sat in their
             # transport buffers by then, so this is the conservative

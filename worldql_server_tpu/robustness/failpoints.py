@@ -319,6 +319,13 @@ def fire(name: str) -> None:
         registry.fire(name)
 
 
+def armed(name: str) -> bool:
+    """Whether ``fire(name)`` could do anything right now: a writer
+    that cannot fire a point frame by frame (the native send pass)
+    asks, and leaves the frames to the one that can."""
+    return name in registry._points
+
+
 async def afire(name: str) -> None:
     """Hot-path async injection site (loop-side boundaries)."""
     if registry._points:

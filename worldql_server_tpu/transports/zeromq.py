@@ -39,7 +39,7 @@ import uuid as uuid_mod
 import zmq
 import zmq.asyncio
 
-from ..engine.peers import Peer
+from ..engine.peers import PassEnd, Peer
 from ..protocol.entity_wire import RECV_DRAIN_MAX
 from ..protocol import (
     DeserializeError,
@@ -49,6 +49,7 @@ from ..protocol import (
     serialize_message,
 )
 from ..robustness import failpoints
+from . import zmq_pass
 
 logger = logging.getLogger(__name__)
 
@@ -92,6 +93,9 @@ class ZmqTransport:
         # eviction mid-flight and leak the dead peer from the map.
         self._evictions: set[asyncio.Task] = set()
         self._yielded = time.monotonic()  # see _give_way
+        # The flush's native writer (None: the library lacks the
+        # symbol, and every flush is written peer by peer).
+        self._send_pass = zmq_pass.shared()
 
     async def start(self) -> None:
         config = self.server.config
@@ -113,6 +117,14 @@ class ZmqTransport:
             "ZeroMQ PULL server listening on %s:%s",
             config.zmq_server_host,
             config.zmq_server_port,
+        )
+        logger.info(
+            "ZeroMQ flush writer: %s",
+            "one native pass a flush (wql_send_pass, %d thread%s)" % (
+                self._send_pass.threads,
+                "" if self._send_pass.threads == 1 else "s")
+            if self._send_pass is not None
+            else "peer by peer (no native send pass loaded)",
         )
         supervisor = getattr(self.server, "supervisor", None)
         if supervisor is not None:
@@ -462,6 +474,12 @@ class ZmqTransport:
         # awaited send is in flight: it would overtake that send, and
         # take the socket's one edge-triggered wake-up from it.
         send_now = zmq.Socket.shadow(push).send
+        handle = push.underlying
+
+        def writable() -> bool:
+            """The one guard of both sync writers: no awaited send in
+            flight, and this binding is still the current one."""
+            return not awaited and self._push_sockets.get(peer_uuid) is push
 
         def try_write_many(framed_list) -> int:
             """Sync path: each frame its own message, non-blocking,
@@ -469,7 +487,7 @@ class ZmqTransport:
             owes the rest to ``send_raw``: after ``zmq.Again`` (the
             high-water mark) they wait there as they always did,
             after an eviction they fail there and are counted."""
-            if awaited or self._push_sockets.get(peer_uuid) is not push:
+            if not writable():
                 return 0
             taken = 0
             try:
@@ -482,6 +500,16 @@ class ZmqTransport:
             except Exception:
                 evict()
             return taken
+
+        def pass_handle() -> int:
+            """The same libzmq socket for the flush's native pass,
+            which does per peer what the closure above does; 0 sends
+            the peer to the closure: it is not writable, or the
+            ``transport.send`` failpoint is armed and only the closure
+            can fire it frame by frame."""
+            if writable() and not failpoints.armed("transport.send"):
+                return handle
+            return 0
 
         def try_write(framed) -> bool:
             return try_write_many((framed,)) == 1
@@ -502,6 +530,8 @@ class ZmqTransport:
             tracks_heartbeat=True,
             try_write=try_write,
             try_write_many=try_write_many,
+            pass_end=None if self._send_pass is None else PassEnd(
+                self._send_pass, pass_handle, evict),
         )
         plane = getattr(self.server, "delivery_plane", None)
         adopted = plane is not None and plane.adopt(peer, endpoint=endpoint)
