@@ -51,6 +51,26 @@ def make_plane(**kw) -> EntityPlane:
     )
 
 
+def entity_server(tick_interval):
+    """A real ``--entity-sim`` server on a free ZeroMQ port (not yet
+    started) and its config."""
+    from tests.client_util import free_port
+    from worldql_server_tpu.engine.config import Config
+    from worldql_server_tpu.engine.server import WorldQLServer
+
+    config = Config()
+    config.store_url = "memory://"
+    config.http_enabled = False
+    config.ws_enabled = False
+    config.zmq_server_port = free_port()
+    config.zmq_server_host = "127.0.0.1"
+    config.spatial_backend = "tpu"
+    config.tick_interval = tick_interval
+    config.entity_sim = True
+    config.entity_k = 4
+    return WorldQLServer(config), config
+
+
 def ent_msg(sender, entities, parameter=None, world="w"):
     return Message(
         instruction=Instruction.LOCAL_MESSAGE, sender_uuid=sender,
@@ -470,22 +490,10 @@ def test_e2e_zmq_columnar_path_serves_frames(wire):
     the columnar fast path (provably fired), frames keep arriving with
     advancing positions, and the incremental H2D scatter carries the
     steady state."""
-    from tests.client_util import ZmqClient, free_port
-    from worldql_server_tpu.engine.config import Config
-    from worldql_server_tpu.engine.server import WorldQLServer
+    from tests.client_util import ZmqClient
 
     async def scenario():
-        config = Config()
-        config.store_url = "memory://"
-        config.http_enabled = False
-        config.ws_enabled = False
-        config.zmq_server_port = free_port()
-        config.zmq_server_host = "127.0.0.1"
-        config.spatial_backend = "tpu"
-        config.tick_interval = 0.03
-        config.entity_sim = True
-        config.entity_k = 4
-        server = WorldQLServer(config)
+        server, config = entity_server(tick_interval=0.03)
         await server.start()
         try:
             assert server.entity_ingest is not None
@@ -514,12 +522,139 @@ def test_e2e_zmq_columnar_path_serves_frames(wire):
             ingest = server.entity_ingest
             assert ingest.fast_messages > 0, ingest.stats()
             assert ingest.rows > 0
+            # ... staged by the pump's flush-start drains, the first of
+            # them on a plane with no entity yet, never a receive
+            assert ingest.edge_messages == ingest.fast_messages
             plane = server.entity_plane
             assert plane.wire_rows > 0       # updates rode the columns
             assert plane.h2d_scatter > 0     # touched slots, not tiers
             assert plane.frames_native > 0   # cohort-encoded frames
             await a.close()
             await b.close()
+        finally:
+            await server.stop()
+
+    run(scenario(), timeout=120)
+
+
+@pytest.mark.parametrize("stopper", ["server", "transport"])
+def test_e2e_zmq_only_entity_updates_wait_and_stop_stages_them(
+        wire, stopper):
+    """Real ZMQ, a tick interval so long that no edge comes: the
+    Handshake is answered and a removal routed ON RECEIPT (behind the
+    registration held before it), entity updates are held as bytes,
+    and ``stop`` (the ticker's drain, or the transport's own when
+    that stops first) stages what is held."""
+    from tests.client_util import ZmqClient
+
+    async def until(cond, what):
+        for _ in range(300):
+            if cond():
+                return
+            await asyncio.sleep(0.01)
+        raise AssertionError(what)
+
+    async def scenario():
+        server, config = entity_server(tick_interval=60.0)
+        await server.start()
+        stopped = False
+        try:
+            ingest, plane = server.entity_ingest, server.entity_plane
+            assert ingest.active and server.ticker.ingest_edge is not None
+            # the echo comes back long before any edge could
+            a = await asyncio.wait_for(
+                ZmqClient.connect(config.zmq_server_port), 10)
+            e = uuid.uuid4()
+            await a.send(ent_msg(a.uuid, [Entity(
+                uuid=e, position=Vector3(1, 2, 3), world_name="w")]))
+            await until(lambda: len(ingest._held) == 1, "not held")
+            assert plane.entity_count == 0 and ingest.fast_messages == 0
+            await a.send(ent_msg(a.uuid, [Entity(uuid=e)],
+                                 parameter="entity.remove"))
+            await a.send(ent_msg(a.uuid, [Entity(
+                uuid=e, position=Vector3(9, 2, 3), world_name="w")]))
+            # the removal cut the batch: registered, then removed
+            await until(lambda: ingest.fast_messages == 1
+                        and len(ingest._held) == 1, "removal waited")
+            assert plane.entity_count == 0
+            assert plane.entities_registered == 1
+            assert ingest.edge_messages == 0
+            await a.close()
+            if stopper == "transport":
+                [transport] = [t for t in server._transports
+                               if hasattr(t, "_stage_edge")]
+                server._transports.remove(transport)
+                await transport.stop()
+            else:
+                stopped = True
+                await server.stop()
+            assert not ingest._held
+            assert plane.entities_registered == 2
+            assert ingest.fast_messages == 2
+            assert ingest.edge_messages == (stopper == "server")
+        finally:
+            if not stopped:
+                await server.stop()
+
+    run(scenario(), timeout=120)
+
+
+def test_e2e_zmq_no_update_is_lost_or_reordered_between_loop_and_edge(wire):
+    """Stress, time-bounded: one sender streams 1,500 updates of one
+    entity (x = 1, 2, ...) with heartbeats between, against a 5 ms
+    tick, so the recv loop's holds and cuts and the pump's flush-start
+    drains (which read the same socket) interleave hundreds of times.
+    Every pass must see the buffers in the order they were sent."""
+    from tests.client_util import ZmqClient
+
+    n = 1500
+
+    async def scenario():
+        server, config = entity_server(tick_interval=0.005)
+        await server.start()
+        ingest = server.entity_ingest
+        seen = []
+        staged = ingest.process_batch
+
+        async def spy(datas, slow_route, ctxs=None):
+            for data in datas:
+                message = deserialize_message(data)
+                seen.append(
+                    message.entities[0].position.x if message.entities
+                    else -float(message.instruction == Instruction.HEARTBEAT)
+                )
+            await staged(datas, slow_route, ctxs=ctxs)
+
+        ingest.process_batch = spy
+        try:
+            a = await ZmqClient.connect(config.zmq_server_port)
+            e = uuid.uuid4()
+            beats = 0
+            for i in range(1, n + 1):
+                await a.send(ent_msg(a.uuid, [Entity(
+                    uuid=e, position=Vector3(float(i), 2, 3),
+                    world_name="w")]))
+                if i % 97 == 0:
+                    await a.send(Message(instruction=Instruction.HEARTBEAT))
+                    beats += 1
+                if i % 50 == 0:
+                    await asyncio.sleep(0.001)
+            for _ in range(3000):
+                if ingest.fast_messages == n and not ingest._held:
+                    break
+                await asyncio.sleep(0.01)
+            assert ingest.fast_messages == n, ingest.stats()
+            xs = [x for x in seen if x > 0]
+            assert xs == [float(i) for i in range(1, n + 1)]
+            # each heartbeat went in where it was sent: behind 97 more
+            at = [i for i, x in enumerate(seen) if x == -1.0]
+            assert len(at) == beats
+            assert [seen[i - 1] for i in at] == \
+                [97.0 * (k + 1) for k in range(beats)]
+            st = ingest.stats()
+            assert 0 < st["edge_messages"] <= st["fast_messages"]
+            assert st["batches"] < n // 4       # passes, not receives
+            await a.close()
         finally:
             await server.stop()
 
@@ -823,6 +958,428 @@ def test_frame_reuse_invalidates_on_movement_and_roster_change(wire):
     reused_before = plane.frames_reused
     _tick_pairs(plane)
     assert plane.frames_reused == reused_before
+
+
+# endregion
+
+
+# region: the held batch (ISSUE 37) — the transport hands every buffer
+# to ColumnarIngest.hold as it leaves the socket and the batch is
+# staged by ONE process_batch a tick edge. Semantics must not depend
+# on WHEN a buffer is staged: lanes, frames and counts equal the
+# per-receive staging this replaced (one process_batch a message) and
+# the object path.
+
+
+class HeldHarness(Harness):
+    """Harness plus a third plane fed the transport's way: ``offer``
+    hands each message, one at a time, to ``hold`` (staging when it
+    asks, as ``_absorb_inbound`` does) while the wire plane gets the
+    parent's per-receive staging and the object plane the Message;
+    ``edge`` is the flush-start drain's staging."""
+
+    def __init__(self, wire_codec):
+        super().__init__(wire_codec)
+        self.held_plane = make_plane()
+        self.held = ColumnarIngest(
+            self.held_plane, sender_known=lambda u: True, wire=wire_codec,
+        )
+        self.watch = None   # entity whose staged x every slow route notes
+        self.routed = []    # (instruction, parameter, staged x | None)
+
+    def staged_x(self):
+        slot = self.held_plane._slot_of.get(self.watch)
+        buf = self.held_plane._stage[self.held_plane._stage_active]
+        if slot is None or not buf.touched[slot]:
+            return None
+        return float(buf.pos[slot, 0])
+
+    async def _held_slow(self, data):
+        message = deserialize_message(data)
+        self.routed.append(
+            (message.instruction, message.parameter, self.staged_x())
+        )
+        self.held_plane.ingest(message)
+
+    def offer(self, *messages):
+        asks = []
+        for message in messages:
+            self.feed(message)  # a process_batch a message + the object
+            asks.append(self.held.hold(serialize_message(message)))
+            if asks[-1]:
+                run(self.held.stage(self._held_slow))
+        return asks
+
+    def edge(self):
+        run(self.held.stage(self._held_slow, edge=True))
+
+    def tick(self):
+        handle = self.held_plane.dispatch_tick()
+        held = (
+            self.held_plane.apply(self.held_plane.collect_tick(handle))
+            if handle is not None else []
+        )
+        return [*super().tick(), held]
+
+    def assert_lane_parity(self):
+        super().assert_lane_parity()
+        h, o = self.held_plane, self.obj_plane
+        assert h._cap == o._cap
+        for col in ("_live", "_pos", "_vel", "_wid", "_pid", "_cube"):
+            assert np.array_equal(getattr(h, col), getattr(o, col)), col
+        assert h._slot_of == o._slot_of
+
+
+def test_held_batch_staged_once_equals_per_receive_staging(wire):
+    h = HeldHarness(wire)
+    owner_a, owner_b = uuid.uuid4(), uuid.uuid4()
+    ents = [uuid.uuid4() for _ in range(8)]
+    asks = h.offer(*(
+        ent_msg(owner_a if i < 4 else owner_b, [Entity(
+            uuid=ents[i], position=Vector3((i % 4) * 30.0 + i // 4, 1, 1),
+            world_name="w", flex=vel_flex(1.0 + i),
+        )])
+        for i in range(8)
+    ))
+    assert asks == [False] * 8           # entity updates wait
+    assert h.held_plane.entity_count == 0 and h.held.batches == 0
+    assert h.wire_plane.entity_count == 8
+    h.edge()
+    assert h.held_plane.entity_count == 8 and h.held.batches == 1
+    h.tick()
+    h.assert_lane_parity()
+
+    # a tick's worth, one at a time: two senders interleaved, the same
+    # entity updated three times (last write wins = arrival order), a
+    # velocity on the first write only
+    asks = h.offer(
+        ent_msg(owner_a, [
+            Entity(uuid=ents[0], position=Vector3(5.0, 5.0, 5.0),
+                   world_name="w", flex=vel_flex(-3.0)),
+            Entity(uuid=ents[1], position=Vector3(6.0, 5.0, 5.0),
+                   world_name="w"),
+        ]),
+        ent_msg(owner_b, [Entity(uuid=ents[4],
+                                 position=Vector3(1.5, 2.0, 1.0),
+                                 world_name="w")]),
+        ent_msg(owner_a, [Entity(uuid=ents[0],
+                                 position=Vector3(7.0, 5.0, 5.0),
+                                 world_name="w")]),
+        ent_msg(owner_b, [Entity(uuid=ents[0],      # not the owner's
+                                 position=Vector3(66.0, 6.0, 6.0),
+                                 world_name="w")]),
+        ent_msg(owner_a, [Entity(uuid=ents[0],
+                                 position=Vector3(8.0, 5.0, 5.0),
+                                 world_name="w")]),
+    )
+    assert asks == [False] * 5
+    assert h.held_plane.staged_count() == 0     # nothing staged early
+    h.edge()
+    wp, op, hp = h.tick()
+    h.assert_lane_parity()
+    slot = h.held_plane._slot_of[ents[0]]
+    assert h.held_plane._vel[slot, 0] == pytest.approx(-3.0)
+
+    # frames byte for byte, recipients equal, all three ways
+    assert len(hp) == len(wp) == len(op) > 0
+    assert sorted(f.wire for f, _ in hp) == sorted(f.wire for f, _ in wp) \
+        == sorted(serialize_message(m) for m, _ in op)
+    assert sorted(map(sorted, (t for _, t in hp))) == \
+        sorted(map(sorted, (t for _, t in op)))
+
+    # the same messages and rows went in, in 2 passes against 13
+    per, held = h.ingest.stats(), h.held.stats()
+    assert (per["batches"], held["batches"]) == (13, 2)
+    for key in ("fast_messages", "slow_messages", "dropped", "rows"):
+        assert held[key] == per[key], key
+    assert held["edge_messages"] == held["fast_messages"] == 13
+    assert per["edge_messages"] == 0
+    assert h.held_plane.wire_rows == h.wire_plane.wire_rows
+    assert h.held_plane.updates == h.wire_plane.updates
+
+
+def _interloper(kind, owner, e):
+    if kind == "removal":
+        return ent_msg(owner, [Entity(uuid=e)], parameter="entity.remove")
+    if kind == "local-message":     # a LocalMessage that carries none
+        return Message(
+            instruction=Instruction.LOCAL_MESSAGE, sender_uuid=owner,
+            world_name="w", position=Vector3(1, 1, 1),
+        )
+    if kind == "global-entities":   # columnar too, but never waits
+        return Message(
+            instruction=Instruction.GLOBAL_MESSAGE, sender_uuid=owner,
+            world_name="w", entities=[Entity(
+                uuid=e, position=Vector3(4, 4, 4), world_name="w")],
+        )
+    return Message(
+        instruction={"handshake": Instruction.HANDSHAKE,
+                     "heartbeat": Instruction.HEARTBEAT}[kind],
+        sender_uuid=owner, parameter={"handshake": "127.0.0.1:9"}.get(kind),
+    )
+
+
+@pytest.mark.parametrize("kind", [
+    "removal", "local-message", "handshake", "heartbeat",
+    "global-entities",
+])
+def test_a_buffer_that_cannot_wait_keeps_its_senders_arrival_order(
+        wire, kind):
+    """Between two updates of one sender: the first is staged BEFORE
+    the interloper is routed (a removal must find it), the second
+    after; only entity-update LocalMessages wait for the edge."""
+    h = HeldHarness(wire)
+    owner = uuid.uuid4()
+    e = h.watch = uuid.uuid4()
+    h.offer(ent_msg(owner, [Entity(uuid=e, position=Vector3(1, 1, 1),
+                                   world_name="w")]))
+    h.edge()
+    h.tick()
+    asks = h.offer(
+        ent_msg(owner, [Entity(uuid=e, position=Vector3(2, 2, 2),
+                               world_name="w")]),
+        _interloper(kind, owner, e),
+        ent_msg(owner, [Entity(uuid=e, position=Vector3(9, 9, 9),
+                               world_name="w")]),
+    )
+    assert asks == [False, True, False]
+    if kind == "global-entities":
+        # staged in the same pass as the update before it, in order
+        assert h.routed == [] and h.held.fast_messages == 3
+        assert h.staged_x() == pytest.approx(4.0)
+    else:
+        [(instruction, parameter, staged_x)] = h.routed
+        assert staged_x == pytest.approx(2.0)   # the first, not the second
+        assert h.held.slow_messages == 1
+    assert h.held.edge_messages == 1            # the registration's edge
+    h.edge()
+    assert h.held.edge_messages == 2
+    h.tick()
+    h.assert_lane_parity()
+    slot = h.held_plane._slot_of[e]             # removal: registered anew
+    assert h.held_plane._pos[slot, 0] == pytest.approx(9.0)
+    assert h.held.stats()["slow_messages"] == h.ingest.stats()["slow_messages"]
+
+
+@pytest.mark.parametrize("bound, n_msgs, n_ents", [
+    ("messages", entity_wire.RECV_DRAIN_MAX, 1),
+    ("rows", 4, 1024),      # 4 x 1,024 rows = _RUN_ROWS_MAX
+])
+def test_the_bound_stages_without_an_edge(wire, bound, n_msgs, n_ents):
+    from worldql_server_tpu.entities.ingest import _RUN_ROWS_MAX
+
+    assert n_msgs * n_ents >= _RUN_ROWS_MAX or n_msgs == 256
+    plane = make_plane(max_entities=8192)
+    ingest = ColumnarIngest(plane, sender_known=lambda u: True, wire=wire)
+    owner = uuid.uuid4()
+
+    async def never(data):
+        raise AssertionError("unexpected slow route")
+
+    asks = []
+    for i in range(n_msgs):
+        asks.append(ingest.hold(serialize_message(ent_msg(owner, [
+            Entity(uuid=uuid.UUID(int=1 + i * n_ents + j),
+                   position=Vector3(float(j % 64), float(i), 1),
+                   world_name="w")
+            for j in range(n_ents)
+        ]))))
+        if asks[-1]:
+            run(ingest.stage(never))
+    assert asks == [False] * (n_msgs - 1) + [True]
+    assert plane.entity_count == n_msgs * n_ents
+    st = ingest.stats()
+    assert (st["batches"], st["fast_messages"], st["edge_messages"]) == \
+        (1, n_msgs, 0)
+    assert not ingest._held and ingest._held_rows == 0
+    run(ingest.stage(never, edge=True))         # nothing left: no pass
+    assert ingest.stats()["batches"] == 1
+
+
+@pytest.mark.parametrize("way", ["held", "per-receive"])
+def test_governor_counts_do_not_depend_on_when_a_batch_is_staged(wire, way):
+    """Admission still runs a message, and the audit invariant
+    offered == applied + coalesced + dropped holds with the same
+    numbers whether five updates are staged one a call or held and
+    staged once."""
+    from worldql_server_tpu.engine.metrics import Metrics
+    from worldql_server_tpu.robustness import failpoints
+    from worldql_server_tpu.robustness.overload import OverloadGovernor
+
+    gov = OverloadGovernor(max_batch=100, metrics=Metrics(), peer_rate=3,
+                           peer_burst=4, clock=lambda: 100.0)
+    plane = make_plane(governor=gov, metrics=gov.metrics)
+    ingest = ColumnarIngest(
+        plane, sender_known=lambda u: True, governor=gov, wire=wire,
+        metrics=gov.metrics,
+    )
+    owner = uuid.uuid4()
+    e = uuid.uuid4()
+    plane.ingest(ent_msg(owner, [Entity(uuid=e, position=Vector3(1, 1, 1),
+                                        world_name="w")]))
+    datas = [
+        serialize_message(ent_msg(owner, [Entity(
+            uuid=e, position=Vector3(10.0 + i, 2, 3), world_name="w",
+        )]))
+        for i in range(6)
+    ]
+
+    async def never(data):
+        raise AssertionError("unexpected slow route")
+
+    failpoints.registry.set("overload.force_state", "state:shed_low")
+    try:
+        gov.note_idle(0)
+        assert gov.coalesce_entities()
+        for data in datas:
+            if way == "held":
+                assert not ingest.hold(data)
+            else:
+                run(ingest.process_batch([data], never))
+        run(ingest.stage(never, edge=True))
+    finally:
+        failpoints.registry.clear()
+    # 6 offered: the bucket (burst 4, a frozen clock) sheds 2, the
+    # first staged update applies, 3 coalesce onto it
+    counters = gov.metrics.counters
+    assert gov.rate_limited == 2 and counters["peers.rate_limited"] == 2
+    assert ingest.dropped == 2 and ingest.fast_messages == 4
+    assert counters["messages.local_message"] == 6
+    assert counters["messages.entity_batches"] == 4
+    assert plane.staged_count() == 1
+    assert plane.coalesced == 3 and counters["overload.coalesced"] == 3
+    assert plane.updates == 2                   # the registration + 1
+    assert 6 == (plane.updates - 1) + plane.coalesced + ingest.dropped
+    plane._drain_pending()
+    assert plane._pos[plane._slot_of[e], 0] == pytest.approx(13.0)
+
+
+def _edge_ticker(plane, ingest, interval=0.02):
+    """A pump over ``plane`` whose tick edge stages ``ingest``'s held
+    batch: what ZmqTransport.start wires, minus the socket."""
+    from worldql_server_tpu.engine.ticker import TickBatcher
+
+    async def slow(data):
+        plane.ingest(deserialize_message(data))
+
+    async def edge():
+        await ingest.stage(slow, edge=True)
+
+    ticker = TickBatcher(plane.backend, PeerMap(), interval,
+                         entity_plane=plane)
+    ticker.ingest_edge = edge
+    return ticker
+
+
+@pytest.mark.parametrize("state", ["no-entities", "tick-in-flight"])
+def test_an_idle_plane_still_stages_within_one_flush(wire, state):
+    """The edge runs as every pump flush starts, work or none: a held
+    registration reaches a plane with no entity (its flushes are idle
+    and open no trace), and a held update is staged and folded while a
+    sim tick is in flight (dispatch_tick launches nothing then)."""
+    plane = make_plane()
+    ingest = ColumnarIngest(plane, sender_known=lambda u: True, wire=wire)
+    owner = uuid.uuid4()
+    e = uuid.uuid4()
+    if state == "tick-in-flight":
+        plane.ingest(ent_msg(owner, [Entity(
+            uuid=e, position=Vector3(1, 1, 1), world_name="w")]))
+        assert plane.dispatch_tick() is not None    # never collected
+    assert not ingest.hold(serialize_message(ent_msg(owner, [Entity(
+        uuid=e, position=Vector3(7, 7, 7), world_name="w")])))
+
+    async def scenario():
+        ticker = _edge_ticker(plane, ingest)
+        ticker.start()
+        try:
+            for _ in range(100):        # a few intervals, 2 s at most
+                if ingest.edge_messages:
+                    break
+                await asyncio.sleep(0.02)
+            if state == "tick-in-flight":
+                await asyncio.sleep(0.05)   # the flush after the edge
+        finally:
+            if state == "tick-in-flight":
+                plane.abort_tick()
+            await ticker.stop()
+
+    run(scenario())
+    assert ingest.stats()["edge_messages"] == 1 and not ingest._held
+    slot = plane._slot_of[e]
+    assert plane._pos[slot, 0] == pytest.approx(7.0)
+    if state == "tick-in-flight":
+        assert plane.staged_count() == 0 and plane.column_flips == 1
+
+
+def test_ticker_stop_stages_a_held_batch_before_its_drain_flush(wire):
+    """stop() cancels the pump, then runs the edge and the drain
+    flush: a batch held since the last flush is not lost, and a pump
+    cancelled while its cut waits for a staging in progress hands the
+    cut back to the head of the held batch."""
+    plane = make_plane()
+    ingest = ColumnarIngest(plane, sender_known=lambda u: True, wire=wire)
+    owner = uuid.uuid4()
+    ents = [uuid.uuid4() for _ in range(3)]
+
+    def update(i, x):
+        return serialize_message(ent_msg(owner, [Entity(
+            uuid=ents[i], position=Vector3(x, 1, 1), world_name="w")]))
+
+    async def scenario():
+        ticker = _edge_ticker(plane, ingest, interval=30.0)
+        ticker.start()
+        await asyncio.sleep(0)
+        assert not ingest.hold(update(0, 1.0))
+        await ticker.stop()
+        assert plane.entity_count == 1 and ingest.edge_messages == 1
+
+        # a cut cancelled in the queue goes back, in order
+        await ingest._staging.acquire()
+        ingest.hold(update(1, 2.0))
+        waiting = asyncio.ensure_future(ingest.stage(None, edge=True))
+        await asyncio.sleep(0)
+        assert not ingest._held             # cut, waiting its turn
+        ingest.hold(update(2, 3.0))         # arrived behind the cut
+        waiting.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await waiting
+        ingest._staging.release()
+        assert ingest._held == [update(1, 2.0), update(2, 3.0)]
+        assert ingest._held_rows == 2
+
+        async def never(data):
+            raise AssertionError("unexpected slow route")
+
+        await ingest.stage(never)
+        assert plane.entity_count == 3
+
+    run(scenario())
+
+
+def test_peek_update_rows_only_holds_entity_update_local_messages():
+    owner, e = uuid.uuid4(), uuid.uuid4()
+    peek = entity_wire.peek_update_rows
+
+    def entities(n):
+        return [Entity(uuid=uuid.UUID(int=i + 1), position=Vector3(i, 1, 1),
+                       world_name="w") for i in range(n)]
+
+    update = serialize_message(ent_msg(owner, entities(20)))
+    assert peek(update) == 20
+    assert peek(serialize_message(ent_msg(owner, entities(1)))) == 1
+    for kind in ("removal", "local-message", "handshake", "heartbeat",
+                 "global-entities"):
+        assert peek(serialize_message(_interloper(kind, owner, e))) == 0, kind
+    # bytes that do not parse never raise and never wait
+    for junk in (b"", b"\x00", b"\xff" * 64, update[:20], update[:40],
+                 b"\x10\x00\x00\x00" + b"\xff" * 12 + b"\x00" * 8):
+        assert peek(junk) == 0
+    # the pure-Python encoder lays the table out the same way
+    from worldql_server_tpu.protocol import codec
+
+    assert peek(codec.py_serialize_message(ent_msg(owner, entities(3)))) == 3
+    assert peek(codec.py_serialize_message(
+        _interloper("removal", owner, e))) == 0
 
 
 # endregion

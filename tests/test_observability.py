@@ -233,6 +233,48 @@ def test_loop_monitor_observes_lag_and_gc():
     assert "gc_counts" in snap
 
 
+def test_gc_hook_takes_no_lock_and_the_probe_observes_its_pauses():
+    """A collection pass starts at any bytecode of the loop's thread,
+    inside ``Metrics.observe_ms`` too (it allocates): a hook that
+    observed under Metrics' lock waited for its own caller, and the
+    loop never ran again (ISSUE 37: traced runs hung near a profiler
+    capture's stop). The hook only notes; the probe observes."""
+    from worldql_server_tpu.engine.metrics import Metrics
+
+    class Taken:
+        def __enter__(self):
+            raise AssertionError("the GC hook took Metrics' lock")
+
+        def __exit__(self, *exc):
+            return False
+
+    metrics = Metrics()
+    mon = LoopMonitor(metrics=metrics, interval=0.01)
+    lock, metrics._lock = metrics._lock, Taken()
+    mon._gc_callback("start", {})
+    mon._gc_callback("stop", {})
+    mon._gc_callback("stop", {})    # a stop without its start: ignored
+    metrics._lock = lock
+    assert mon.gc_passes == 1 and "gc.pause_ms" not in metrics.histograms
+
+    async def scenario():
+        task = asyncio.create_task(mon.run())
+        await asyncio.sleep(0.05)
+        task.cancel()
+        try:
+            await task
+        except asyncio.CancelledError:
+            pass
+
+    run(scenario())
+    assert metrics.histograms["gc.pause_ms"].total == 1
+    mon._gc_callback("start", {})
+    mon._gc_callback("stop", {})
+    mon.install()
+    mon.uninstall()                 # ... and so does uninstall
+    assert metrics.histograms["gc.pause_ms"].total >= 2
+
+
 # endregion
 
 # region: acceptance — forced slow tick attributes its wall time

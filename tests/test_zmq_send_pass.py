@@ -525,7 +525,8 @@ def pass_share(passed):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     [entry] = [m for m in bench["per_layer"]
                if m["name"] == "deliver_pass_share"]
-    assert entry["workloads"] == CELLS and entry == bench["per_layer"][-1]
+    # later PRs append their cells to the list and their metrics behind it
+    assert entry["workloads"][:len(CELLS)] == CELLS
     return [layers.read_all({"per_layer": [entry]}, cell, ctx)
             for cell in CELLS]
 

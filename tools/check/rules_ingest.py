@@ -12,8 +12,9 @@ token bucket) — or carry an auditable
 bounded some other way.
 
 Scope: the modules that receive wire traffic (``engine/ticker.py``,
-``engine/router.py``, ``entities/plane.py``, ``transports/zeromq.py``,
-``transports/websocket.py``), and within them only the ingest-path
+``engine/router.py``, ``entities/plane.py``, ``entities/ingest.py``,
+``transports/zeromq.py``, ``transports/websocket.py``), and within
+them only the ingest-path
 functions (message arrival → enqueue). A function is exempt when it
 visibly consults the admission plane — any reference whose dotted
 path mentions the governor or one of its admission calls — because
@@ -32,6 +33,7 @@ _SCOPED = (
     "engine/ticker.py",
     "engine/router.py",
     "entities/plane.py",
+    "entities/ingest.py",
     "transports/zeromq.py",
     "transports/websocket.py",
 )
@@ -40,6 +42,7 @@ _SCOPED = (
 _INGEST_FUNCS = {
     "enqueue",
     "ingest",
+    "hold",
     "handle_message",
     "_dispatch",
     "_entity_ingest",

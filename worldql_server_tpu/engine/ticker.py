@@ -162,6 +162,13 @@ class TickBatcher:
         # PeerMap.bytes_delivered high-water at the last _account —
         # diffed into the delivery.bytes_per_tick gauge
         self._bytes_mark = 0
+        # The columnar way in's tick edge (transports/zeromq.py sets it
+        # to its ``_stage_edge``; None: no transport holds messages):
+        # awaited as every pump flush starts, work or none, and by
+        # ``stop`` before its drain, so what the transport holds, or
+        # its socket still does, is staged ahead of ``dispatch_tick``'s
+        # fold and never held longer than an interval.
+        self.ingest_edge = None
         # (period_ms | None, late) of the flush the pump is in, left by
         # _run and counted by _note_period once the flush has work
         self._pump_note: tuple[float | None, bool] | None = None
@@ -185,6 +192,7 @@ class TickBatcher:
             except asyncio.CancelledError:
                 pass
             self._task = None
+        await self._ingest_edge()
         await self.flush()  # drain whatever is left
         while self._queue:
             # governed flushes take at most the admitted tier — keep
@@ -260,6 +268,7 @@ class TickBatcher:
             # clock: lateness is never repaid with a burst of short
             # ticks
             start = loop.time()
+            await self._ingest_edge()
             self._pump_note = (
                 None if last_start is None else (start - last_start) * 1e3,
                 late,
@@ -274,6 +283,17 @@ class TickBatcher:
                 # an idle flush opens no trace and leaves the note: a
                 # flush that is not the pump's must not count it
                 self._pump_note = None
+
+    async def _ingest_edge(self) -> None:
+        """Stage what the columnar way in holds (``ingest_edge``). A
+        fault in the drain is the transport's to surface (its recv
+        loop meets the same socket): the flush goes ahead."""
+        if self.ingest_edge is None:
+            return
+        try:
+            await self.ingest_edge()
+        except Exception:
+            logger.exception("tick-edge ingest drain failed — flush proceeds")
 
     # region: entity-sim stages (--entity-sim)
 

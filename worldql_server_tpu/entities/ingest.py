@@ -1,9 +1,20 @@
 """ColumnarIngest: the wire→SoA entity fast path (PR 11).
 
-Sits between the transport recv loop and the EntityPlane: a whole recv
-batch's wire buffers go through ONE GIL-releasing native decode
-(``protocol/entity_wire.wql_decode_entities``) that classifies each
-buffer and lands every fast buffer's entities in shared SoA columns.
+Sits between the transport recv loop and the EntityPlane. The
+transport hands every buffer it takes from its socket to ``hold``; the
+HELD batch is staged by ONE ``process_batch`` a tick edge (the pump
+calls the transport's drain before each of its flushes, so an update
+received, or still in the socket, when a flush starts folds in that
+flush), or sooner when ``hold`` says so: the batch reached
+``RECV_DRAIN_MAX`` messages or ``_RUN_ROWS_MAX`` rows, or the buffer is
+anything but an entity-update LocalMessage, which is then staged with
+everything held before it and so routed on receipt, in arrival order.
+A staging is a whole batch's wire buffers through ONE GIL-releasing
+native decode (``protocol/entity_wire.wql_decode_entities``) that
+classifies each buffer and lands every fast buffer's entities in
+shared SoA columns (a call costs ~150 us whatever it holds and ~26 us
+a message: staged a receive, a tick's 80 messages cost 15 ms of loop,
+staged once 2.3; PERF.md section 6, PR 37).
 This module then walks the batch IN ARRIVAL ORDER, coalescing
 consecutive fast buffers into one ``EntityPlane.ingest_columns`` run
 (zero per-entity Python) and routing everything else — removals,
@@ -51,7 +62,17 @@ _MSG_COUNTER = {
 
 
 class ColumnarIngest:
-    """One per server (``--entity-sim``). Event-loop owned."""
+    """One per server (``--entity-sim``). Event-loop owned.
+
+    ``hold`` takes a buffer (and, on a cluster shard, its trace ctx in
+    lockstep) and says whether the held batch must be staged now;
+    ``stage`` cuts the batch and stages it. Cuts are made without an
+    await, in the order the buffers left the socket, and staged one at
+    a time in the order they were cut (the decoder's scratch columns
+    are shared, and a slow-routed message may await): so whichever
+    task cuts (the recv loop at a bound or a buffer that cannot wait,
+    the pump at its flush's start, ``stop``), arrival order holds
+    across fast and slow messages."""
 
     def __init__(self, plane, sender_known, governor=None, metrics=None,
                  wire="auto", on_error=None):
@@ -61,9 +82,17 @@ class ColumnarIngest:
         self.metrics = metrics
         self._wire = entity_wire.shared() if wire == "auto" else wire
         self._on_error = on_error
+        # the held batch: wire buffers awaiting the next staging, the
+        # cluster trace ctxs in lockstep (none on a plain server), and
+        # the rows the buffers looked like holding when they were taken
+        self._held: list[bytes] = []
+        self._held_ctxs: list[tuple[int, int]] = []
+        self._held_rows = 0
+        self._staging = asyncio.Lock()  # one staging at a time, FIFO
         # stats (entity_ingest gauge)
-        self.batches = 0        # recv batches through the native decode
+        self.batches = 0        # held batches through the native decode
         self.fast_messages = 0  # messages consumed columnar
+        self.edge_messages = 0  # ... of them staged by a flush-start drain
         self.slow_messages = 0  # messages routed through the object path
         self.dropped = 0        # unknown sender / shed / decode-contained
         self.rows = 0           # entity rows staged columnar
@@ -84,15 +113,57 @@ class ColumnarIngest:
             "active": int(self.active),  # 0/1: prometheus-friendly
             "batches": self.batches,
             "fast_messages": self.fast_messages,
+            "edge_messages": self.edge_messages,
             "slow_messages": self.slow_messages,
             "dropped": self.dropped,
             "rows": self.rows,
             "decode_fallbacks": self.decode_fallbacks,
         }
 
+    def hold(self, data: bytes, ctx: tuple[int, int] | None = None) -> bool:
+        """Take one buffer into the held batch. True = stage now: the
+        batch is at a bound, or this buffer is not an entity update
+        and must not wait (``stage`` routes it behind what was held
+        before it)."""
+        rows = entity_wire.peek_update_rows(data)
+        self._held.append(data)  # wql: allow(unbounded-ingest) — staged at RECV_DRAIN_MAX messages, below
+        if ctx is not None:
+            self._held_ctxs.append(ctx)  # wql: allow(unbounded-ingest) — lockstep with _held, same bound
+        self._held_rows += rows
+        return (
+            rows == 0
+            or len(self._held) >= RECV_DRAIN_MAX
+            or self._held_rows >= _RUN_ROWS_MAX
+        )
+
+    async def stage(self, slow_route, edge: bool = False) -> None:
+        """Cut the held batch and stage it (``process_batch``).
+        ``edge``: the cut is a flush-start drain's, and its fast
+        messages count as ``edge_messages``. Never raises."""
+        datas, ctxs, rows = self._held, self._held_ctxs, self._held_rows
+        if not datas:
+            return
+        self._held, self._held_ctxs, self._held_rows = [], [], 0
+        try:
+            await self._staging.acquire()
+        except BaseException:
+            # cancelled in the queue (a stopping pump): the cut goes
+            # back to the head of the batch for stop()'s staging
+            self._held[:0] = datas
+            self._held_ctxs[:0] = ctxs
+            self._held_rows += rows
+            raise
+        try:
+            fast = self.fast_messages
+            await self.process_batch(datas, slow_route, ctxs=ctxs or None)
+            if edge:
+                self.edge_messages += self.fast_messages - fast
+        finally:
+            self._staging.release()
+
     async def process_batch(self, datas: list[bytes], slow_route,
                             ctxs: list[tuple[int, int]] | None = None) -> None:
-        """Consume one recv batch. ``slow_route(data, ctx)`` is the
+        """Stage one batch. ``slow_route(data, ctx)`` is the
         transport's ordinary single-message path (decode → router);
         per-message errors are contained here exactly like the
         transport's own loop contains them. Never raises.

@@ -11,7 +11,10 @@ no further signature. This module gives both their own series:
   is in flight.
 * ``gc.pause_ms`` — a ``gc.callbacks`` hook times every collection
   pass. CPython's collector runs inside whatever thread triggered it,
-  which for this server is almost always the event loop.
+  which for this server is almost always the event loop — at any
+  bytecode of it, inside ``Metrics``' own lock too. So the hook takes
+  no lock: it notes the pause, and the lag probe observes the noted
+  pauses into the series when it next wakes (``interval`` later).
 
 ``snapshot()`` feeds the slow-tick dump so every dump carries the
 loop-health context alongside the span tree.
@@ -37,6 +40,8 @@ class LoopMonitor:
         self.max_gc_pause_ms = 0.0
         self.gc_passes = 0
         self._gc_t0: float | None = None
+        # pauses the hook has timed and the probe has not yet observed
+        self._gc_pauses: list[float] = []
         self._installed = False
 
     # region: GC hook
@@ -54,6 +59,7 @@ class LoopMonitor:
             except ValueError:
                 pass
             self._installed = False
+            self._observe_gc()
 
     def _gc_callback(self, phase: str, info: dict) -> None:
         if phase == "start":
@@ -67,7 +73,19 @@ class LoopMonitor:
         self.last_gc_pause_ms = pause_ms
         if pause_ms > self.max_gc_pause_ms:
             self.max_gc_pause_ms = pause_ms
+        # NOT metrics.observe_ms: a pass that began while this thread
+        # was inside Metrics (observe_ms allocates) would wait here for
+        # the lock its own caller holds, and the loop never ran again
+        # (PR 37: traced runs hung around a profiler capture's stop,
+        # whose worker allocates enough to start pass after pass)
         if self.metrics is not None:
+            self._gc_pauses.append(pause_ms)  # emptied every interval
+
+    def _observe_gc(self) -> None:
+        """The noted pauses into ``gc.pause_ms`` (the probe, each time
+        it wakes, and ``uninstall``)."""
+        pauses, self._gc_pauses = self._gc_pauses, []
+        for pause_ms in pauses:
             self.metrics.observe_ms("gc.pause_ms", pause_ms)
 
     # endregion
@@ -85,6 +103,7 @@ class LoopMonitor:
                 self.max_lag_ms = lag_ms
             if self.metrics is not None:
                 self.metrics.observe_ms("loop.lag_ms", lag_ms)
+            self._observe_gc()
 
     def snapshot(self) -> dict:
         """Loop-health context for slow-tick dumps and the gauge."""

@@ -29,10 +29,12 @@ from __future__ import annotations
 
 import ctypes
 import logging
+import struct
 
 import numpy as np
 
 from .native_codec import resolve_lib_path
+from .types import Instruction
 
 logger = logging.getLogger(__name__)
 
@@ -51,6 +53,41 @@ _MIN_ROWS = 4096
 RECV_DRAIN_MAX = 256
 
 WQL_E_CAPACITY = -4
+
+_LOCAL_MESSAGE = int(Instruction.LOCAL_MESSAGE)
+_u16 = struct.Struct("<H").unpack_from
+_i32 = struct.Struct("<i").unpack_from
+_u32 = struct.Struct("<I").unpack_from
+
+
+def peek_update_rows(data: bytes) -> int:
+    """Rows a wire buffer would stage if it is what it looks like: a
+    LocalMessage with entities and no parameter, the one kind the
+    transport may hold for the tick edge. 0 for every other buffer
+    (another instruction, a removal, no entities, bytes that do not
+    parse). A LOOK, a few reads at the table's head: it decides only
+    whether a buffer waits, and ``wql_decode_entities`` still
+    classifies every buffer exactly when the held batch is staged."""
+    try:
+        root = _u32(data, 0)[0]
+        vtable = root - _i32(data, root)[0]
+        if vtable < 0:
+            return 0
+        # slots 0 (instruction), 1 (parameter) and 6 (entities) of the
+        # Message table: its vtable holds two u16 of sizes, then a u16
+        # offset a slot
+        if _u16(data, vtable)[0] < 18:
+            return 0
+        instr, param = _u16(data, vtable + 4)[0], _u16(data, vtable + 6)[0]
+        if not instr or param or data[root + instr] != _LOCAL_MESSAGE:
+            return 0
+        entities = _u16(data, vtable + 16)[0]
+        if not entities:
+            return 0
+        at = root + entities
+        return _u32(data, at + _u32(data, at)[0])[0]
+    except (struct.error, IndexError):
+        return 0
 
 
 class DecodedBatch:

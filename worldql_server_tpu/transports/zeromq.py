@@ -40,6 +40,7 @@ import zmq
 import zmq.asyncio
 
 from ..engine.peers import PassEnd, Peer
+from ..observability.spans import NOOP_SPAN
 from ..protocol.entity_wire import RECV_DRAIN_MAX
 from ..protocol import (
     DeserializeError,
@@ -88,6 +89,9 @@ class ZmqTransport:
         self._push_sockets: dict[uuid_mod.UUID, zmq.asyncio.Socket] = {}
         self._recv_task: asyncio.Task | None = None
         self._recv_handle = None  # SupervisedTask under a supervisor
+        # the columnar recv loop's awaited receive, until the loop or
+        # _stage_edge has taken its message
+        self._awaited: asyncio.Future | None = None
         # Failed-send evictions run as tasks; the loop only weak-refs
         # running tasks, so retain them or a GC pass could drop an
         # eviction mid-flight and leak the dead peer from the map.
@@ -135,6 +139,11 @@ class ZmqTransport:
             )
         else:
             self._recv_task = asyncio.create_task(self._recv_loop(), name="zmq-pull")  # wql: allow(unsupervised-task)
+        fast = getattr(self.server, "entity_ingest", None)
+        ticker = getattr(self.server, "ticker", None)
+        if fast is not None and fast.active and ticker is not None:
+            # the held batch's tick edge: see _stage_edge
+            ticker.ingest_edge = self._stage_edge
 
     async def stop(self) -> None:
         if self._recv_handle is not None:
@@ -147,6 +156,11 @@ class ZmqTransport:
             except (asyncio.CancelledError, Exception):
                 pass
             self._recv_task = None
+        fast = getattr(self.server, "entity_ingest", None)
+        if fast is not None:
+            # what the recv loop held and no flush staged (the ticker
+            # stops first): routed while the peers' sockets are open
+            await fast.stage(self._route_data)
         for sock in self._push_sockets.values():
             sock.close(linger=0)
         self._push_sockets.clear()
@@ -160,12 +174,17 @@ class ZmqTransport:
         concatenated, deserialized-or-dropped, then routed.
 
         Columnar drain (--entity-sim + native codec): everything the
-        socket already holds — bounded by ``RECV_DRAIN_MAX`` — drains
-        into ONE recv batch handed to ``ColumnarIngest.process_batch``,
-        which batch-decodes every entity-update message straight into
-        the plane's SoA columns and routes the rest through
-        ``_route_data`` in arrival order. Without the fast path the
-        loop is the per-message path it always was.
+        socket already holds — bounded by ``RECV_DRAIN_MAX`` — is
+        handed to ``ColumnarIngest.hold``, which keeps entity-update
+        LocalMessages as bytes. The held batch is staged (batch-decoded
+        straight into the plane's SoA columns, the rest routed through
+        ``_route_data`` in arrival order) ONCE a tick edge, by
+        ``_stage_edge`` at the start of every pump flush, and from here
+        only when ``hold`` asks: at ``RECV_DRAIN_MAX`` messages or
+        ``_RUN_ROWS_MAX`` rows held, and for every buffer that is not
+        an entity update, which is thereby routed on receipt behind
+        what was held before it. ``stop`` stages what is left. Without
+        the fast path the loop is the per-message path it always was.
 
         Per-message crash containment: ANY exception escaping the
         processing of one message (a router bug a hostile payload
@@ -182,9 +201,9 @@ class ZmqTransport:
             # supervisor's restart/escalate policy in the chaos suite
             failpoints.fire("zmq.recv")
             await self._give_way()
-            parts = await self._pull.recv_multipart()
             fast = getattr(self.server, "entity_ingest", None)
             if fast is None or not fast.active:
+                parts = await self._pull.recv_multipart()
                 try:
                     await self._process_inbound(parts, limit)
                 except Exception:
@@ -193,68 +212,94 @@ class ZmqTransport:
                         "error processing inbound zmq message — dropped"
                     )
                 continue
-            # Clustered shards receive router-framed bytes (the WQTX
-            # trace prefix, cluster/tracectx.py). Strip it BEFORE the
-            # native entity classifier — a prefixed buffer fails
-            # classification and the whole batch degrades to the
-            # object path (PR 15's KNOWN GAP, closed here) — and
-            # carry the ctx alongside so slow-routed messages still
-            # thread trace_ctx onto their Message.
-            cluster = getattr(self.server, "cluster", None)
-            datas = []
-            ctxs: list[tuple[int, int]] | None = \
-                [] if cluster is not None else None
-            unwrapped = 0
-            data = self._flatten(parts, limit)
-            if data is not None:
-                unwrapped += await self._absorb_inbound(
-                    cluster, data, datas, ctxs
-                )
-            while len(datas) < RECV_DRAIN_MAX:
+            self._awaited = recv = self._pull.recv_multipart()
+            parts = await recv
+            if self._awaited is not recv:
+                continue  # _stage_edge took it, ahead of what it drained
+            self._awaited = None
+            await self._drain(fast, parts)
+
+    async def _drain(self, fast, parts: list[bytes] | None = None,
+                     edge: bool = False) -> None:
+        """Hand ``parts`` and then what the socket holds, at most
+        ``RECV_DRAIN_MAX`` messages, to the ingest; stage when it
+        asks. The recv loop's and the flush-start drain's (``edge``)
+        one way in."""
+        limit = self.server.config.max_message_size
+        # Clustered shards receive router-framed bytes (the WQTX
+        # trace prefix, cluster/tracectx.py). Strip it BEFORE the
+        # native entity classifier — a prefixed buffer fails
+        # classification and the whole batch degrades to the
+        # object path (PR 15's KNOWN GAP, closed here) — and
+        # carry the ctx alongside so slow-routed messages still
+        # thread trace_ctx onto their Message.
+        cluster = getattr(self.server, "cluster", None)
+        unwrapped = 0
+        for _ in range(RECV_DRAIN_MAX):
+            if parts is None:
                 try:
                     parts = await self._pull.recv_multipart(zmq.NOBLOCK)
                 except zmq.Again:
                     break
-                data = self._flatten(parts, limit)
-                if data is not None:
-                    unwrapped += await self._absorb_inbound(
-                        cluster, data, datas, ctxs
-                    )
-            if unwrapped:
-                # the fast-path-through-router proof: router-framed
-                # messages reaching the columnar batch pre-unwrapped
-                self.server.metrics.inc("zmq.ctx_unwrapped", unwrapped)
-            if datas:
-                # contains per message internally; never raises
-                await fast.process_batch(datas, self._route_data,
-                                         ctxs=ctxs)
+            data = self._flatten(parts, limit)
+            parts = None
+            if data is not None:
+                unwrapped += await self._absorb_inbound(
+                    fast, cluster, data, edge
+                )
+        if unwrapped:
+            # the fast-path-through-router proof: router-framed
+            # messages reaching the columnar batch pre-unwrapped
+            self.server.metrics.inc("zmq.ctx_unwrapped", unwrapped)
 
-    async def _absorb_inbound(self, cluster, data: bytes, datas: list,
-                              ctxs: list | None) -> int:
-        """Classify one inbound frame for the columnar batch. Live
-        resharding (cluster/resharding) adds two diverts ahead of the
-        fast path: freeze FENCE frames ack over control instead of
-        decoding, and STALE-EPOCH frames (stamped under an older
-        placement than this shard holds) take the full decode +
-        ownership check — a stale entity frame must never reach the
-        SoA columns directly, it may belong to a world this shard just
-        lost. Everything else joins the batch with its trace ctx in
-        lockstep. Returns 1 when a live trace ctx was stripped."""
-        if cluster is None:
-            datas.append(data)  # wql: allow(unbounded-ingest) — bounded by RECV_DRAIN_MAX in the caller
-            return 0
-        trace_id, t_ctx, epoch, data = cluster.unwrap(data)
-        if data[:4] == cluster.FENCE_MAGIC:
-            cluster.on_fence(data)
-            return 0
-        if cluster.frame_stale(epoch):
-            await self._route_data(
-                data, ctx=(trace_id, t_ctx), epoch=epoch
-            )
-            return 0
-        ctxs.append((trace_id, t_ctx))  # wql: allow(unbounded-ingest) — lockstep with datas, same RECV_DRAIN_MAX bound
-        datas.append(data)  # wql: allow(unbounded-ingest) — bounded by RECV_DRAIN_MAX; admission happens in ColumnarIngest/router
-        return 1 if trace_id else 0
+    async def _stage_edge(self) -> None:
+        """The tick edge of the columnar way in (the pump calls it as
+        each of its flushes starts, ``TickBatcher.ingest_edge``): take
+        what the socket still holds and stage the held batch in ONE
+        pass, so an update received or waiting in libzmq when a flush
+        starts is in that flush's fold. Under ``zmq.stage``: the loop's
+        account charges the pass to ``ingest`` though the pump's task
+        runs it."""
+        if self._pull is None:
+            return  # stopped: stop() staged what was held
+        fast = self.server.entity_ingest    # start() hooked it to one
+        tracer = getattr(self.server, "tracer", None)
+        with tracer.span("zmq.stage") if tracer is not None else NOOP_SPAN:
+            # a message the recv loop's awaited receive already took
+            # out of the socket, while its task has not run yet, came
+            # before everything still in there
+            recv, parts = self._awaited, None
+            if (recv is not None and recv.done() and not recv.cancelled()
+                    and recv.exception() is None):
+                self._awaited, parts = None, recv.result()
+            await self._drain(fast, parts, edge=True)
+            # contains per message internally; never raises
+            await fast.stage(self._route_data, edge=True)
+
+    async def _absorb_inbound(self, fast, cluster, data: bytes,
+                              edge: bool) -> int:
+        """Hand one inbound frame to the ingest. Live resharding
+        (cluster/resharding) adds two diverts ahead of the hold:
+        freeze FENCE frames ack over control instead of decoding, and
+        STALE-EPOCH frames (stamped under an older placement than this
+        shard holds) take the full decode + ownership check — a stale
+        entity frame must never reach the SoA columns directly, it may
+        belong to a world this shard just lost. Everything else is
+        held with its trace ctx in lockstep. Returns 1 when a live
+        trace ctx was stripped."""
+        ctx = None
+        if cluster is not None:
+            trace_id, t_ctx, epoch, data = cluster.unwrap(data)
+            if data[:4] == cluster.FENCE_MAGIC:
+                cluster.on_fence(data)
+                return 0
+            ctx = (trace_id, t_ctx)
+            if cluster.frame_stale(epoch):
+                await self._route_data(data, ctx=ctx, epoch=epoch)
+                return 0
+        if fast.hold(data, ctx):
+            await fast.stage(self._route_data, edge)
+        return 1 if ctx is not None and ctx[0] else 0
 
     def _flatten(self, parts: list[bytes], limit: int) -> bytes | None:
         """Bound + join one multipart message (None = dropped).
