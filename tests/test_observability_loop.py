@@ -1,11 +1,18 @@
 """The event loop's account (ISSUE 24): cumulative span totals, on-loop
 time by innermost open span, the busy/wall identity, the queue-wait
 clock, spans inside a profiler capture, and the /metrics surfaces.
+And beneath the spans (ISSUE 38): the CPU clock of a tick's spans, the
+clocked rule, the loop's own CPU time, and the receive's counters.
+
+The tests that assert times drive FAKE clocks (``FakeClocks``): the
+account, the spans and the profiler hook take their clocks as
+arguments, so nothing here waits for the wall clock of a loaded host.
 """
 
 import asyncio
 import json
 import sys
+import threading
 import time
 import urllib.request
 import uuid
@@ -31,6 +38,11 @@ from worldql_server_tpu.protocol.types import (
 from worldql_server_tpu.spatial.backend import LocalQuery
 from worldql_server_tpu.spatial.cpu_backend import CpuSpatialBackend
 
+import zmq
+import zmq.asyncio
+
+from worldql_server_tpu.transports.zeromq import _CountedPull
+
 from client_util import free_port
 from prom_parser import validate_exposition
 
@@ -39,17 +51,68 @@ def run(coro):
     return asyncio.run(coro)
 
 
-def spin(ms: float) -> None:
-    """Hold the thread (and, on the loop's thread, the loop)."""
-    end = time.perf_counter() + ms / 1e3
-    while time.perf_counter() < end:
-        pass
+class FakeClocks:
+    """The clocks of an account and of its tracer, moved by the test
+    alone. ``spin(ms)`` is ms of work ON the CPU: the wall clock and
+    the calling thread's CPU clock both move. ``wait(ms)`` is ms off
+    it (the GIL, the kernel): the wall clock alone. ``idle(ms)`` makes
+    the loop's next ``select`` sleep that long. With ``tick_ms`` every
+    READING of the wall clock moves it too (a bracket is as long as
+    the readings inside it are many)."""
+
+    def __init__(self, tick_ms: float = 0.0):
+        self.ns = 10**9             # never 0: the account's "no step"
+        self.cpu: dict[int, int] = {}
+        self.cpu_reads = 0
+        self.tick_ns = int(tick_ms * 1e6)
+        self._idle_ns = 0
+
+    def wall_ns(self) -> int:
+        self.ns += self.tick_ns
+        return self.ns
+
+    def wall_s(self) -> float:
+        return self.wall_ns() / 1e9
+
+    def cpu_ns(self) -> int:
+        self.cpu_reads += 1
+        return self.cpu.get(threading.get_ident(), 0)
+
+    def spin(self, ms: float) -> None:
+        me = threading.get_ident()
+        self.cpu[me] = self.cpu.get(me, 0) + int(ms * 1e6)
+        self.ns += int(ms * 1e6)
+
+    def wait(self, ms: float) -> None:
+        self.ns += int(ms * 1e6)
+
+    def idle(self, ms: float) -> None:
+        self._idle_ns += int(ms * 1e6)
+
+    def sleeping_select(self, loop) -> None:
+        """Before the account's ``install`` (which times whatever
+        ``select`` it finds): the selector sleeps what ``idle`` asked."""
+        selector = loop._selector
+
+        def select(timeout=None, _select=selector.select):
+            self.ns, self._idle_ns = self.ns + self._idle_ns, 0
+            return _select(timeout)
+
+        selector.select = select
 
 
-def accounted_tracer():
-    """A tracer whose spans feed a LoopAccount on the running loop."""
-    tracer = Tracer(enabled=True)
-    tracer.loop = LoopAccount().install()
+def accounted_tracer(clocks: FakeClocks | None = None):
+    """A tracer whose spans feed a LoopAccount on the running loop; on
+    ``clocks`` when given, else on the real ones."""
+    if clocks is None:
+        tracer = Tracer(enabled=True, cpu_clock=time.thread_time_ns)
+        tracer.loop = LoopAccount().install()
+        return tracer
+    tracer = Tracer(enabled=True, clock=clocks.wall_s,
+                    cpu_clock=clocks.cpu_ns)
+    clocks.sleeping_select(asyncio.get_running_loop())
+    tracer.loop = LoopAccount(clock=clocks.wall_ns,
+                              cpu_clock=clocks.cpu_ns).install()
     return tracer
 
 
@@ -104,16 +167,17 @@ def test_totals_count_spans_closed_on_worker_threads():
 
 def test_loop_time_goes_to_the_innermost_open_span_across_create_task():
     async def scenario():
-        tracer = accounted_tracer()
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
 
         async def child():
-            spin(5)                     # no span of its own: the span
+            clocks.spin(5)              # no span of its own: the span
             with tracer.span("b"):      # open in the context it was
-                spin(5)                 # made in pays, then "b"
+                clocks.spin(5)          # made in pays, then "b"
 
         async def parent():
             with tracer.span("a"):
-                spin(5)
+                clocks.spin(5)
                 await asyncio.create_task(child())
 
         await asyncio.create_task(parent(), name="parent")
@@ -132,17 +196,23 @@ def test_loop_time_goes_to_the_innermost_open_span_across_create_task():
 
 def test_work_on_a_worker_thread_is_not_loop_time():
     async def scenario():
-        tracer = accounted_tracer()
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+        # the worker's 20 ms begin once the step that started it is
+        # over (a plain callback opens the gate: no step is running)
+        gate = threading.Event()
 
         def on_worker():
-            # (a sleep, not a spin: python work on another thread
+            # (a wait, not a spin: python work on another thread
             # takes the GIL from the loop's steps, and that wait IS in
             # their time; what must not be is the worker's own)
             with tracer.span("d"):      # _CURRENT rode to_thread: nests
-                time.sleep(0.02)
+                gate.wait(10)
+                clocks.wait(20)
 
         async def collect():
             with tracer.span("c"):
+                asyncio.get_running_loop().call_soon(gate.set)
                 await asyncio.to_thread(on_worker)
 
         await asyncio.create_task(collect())
@@ -157,15 +227,16 @@ def test_work_on_a_worker_thread_is_not_loop_time():
 
 def test_a_cancelled_tasks_last_step_is_charged_and_the_clock_stops():
     async def scenario():
-        tracer = accounted_tracer()
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
 
         async def doomed():
             with tracer.span("e"):
-                spin(3)
+                clocks.spin(3)
                 try:
                     await asyncio.sleep(30)
                 finally:
-                    spin(3)             # runs inside the throw() step
+                    clocks.spin(3)      # runs inside the throw() step
 
         task = asyncio.create_task(doomed())
         await asyncio.sleep(0.01)
@@ -190,18 +261,22 @@ def test_a_span_around_an_await_gets_its_wall_but_only_its_own_loop_time():
     its loop time. And the identity: layers + unattributed = busy <=
     wall."""
     async def scenario():
-        tracer = accounted_tracer()
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
         before = tracer.loop.snapshot()
+        received = asyncio.Event()
 
         async def deliver():
             with tracer.span("tick.deliver"):
-                await asyncio.sleep(0.06)
+                clocks.idle(10)         # the loop's next select sleeps
+                await received.wait()
 
         async def recv():
             for _ in range(25):
                 with tracer.span("zmq.recv"):
-                    spin(2)
+                    clocks.spin(2)
                 await asyncio.sleep(0)
+            received.set()
 
         await asyncio.gather(asyncio.create_task(deliver()),
                              asyncio.create_task(recv()))
@@ -244,7 +319,7 @@ def test_unnamed_tasks_are_called_after_their_coroutine():
         tracer = accounted_tracer()
 
         async def drain_peer():
-            spin(1)
+            pass
 
         await asyncio.gather(*(drain_peer() for _ in range(50)))
         tracer.loop.uninstall()
@@ -255,6 +330,296 @@ def test_unnamed_tasks_are_called_after_their_coroutine():
     assert name.startswith("task:") and not any(
         n.startswith("task:Task-") for n in held)
     assert held[name]["steps"] == 50
+
+
+# endregion
+
+# region: the CPU clock beneath a tick's spans (ISSUE 38)
+
+
+def test_a_span_inside_one_step_is_clocked_and_its_parts_add_up():
+    async def scenario():
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+        traces = []
+        tracer.on_trace = traces.append
+        trace = tracer.begin("tick")
+
+        async def flush():
+            with trace.span("tick.dispatch"):
+                clocks.spin(3)          # its own Python
+                clocks.wait(2)          # a worker thread holds the GIL
+            with trace.span("tick.deliver"):
+                # a span of the tracer's nests in the tick's trace, and
+                # is clocked with it
+                with tracer.span("deliver.write"):
+                    clocks.spin(1)
+                    clocks.wait(7)
+            with trace.span("tick.dispatch"):
+                clocks.spin(1)
+            trace.finish()
+
+        await asyncio.create_task(flush())
+        tracer.loop.uninstall()
+        return tracer.span_totals(), traces
+
+    spans, [tick] = run(scenario())
+    assert spans["tick.dispatch"] == {
+        "count": 2, "wall_ms": 6.0, "clocked": 2, "clocked_ms": 6.0,
+        "cpu_ms": 4.0, "off_cpu_ms": 2.0, "loop_ms": 6.0, "steps": 2,
+        "max_step_ms": 5.0,
+    }
+    write = spans["deliver.write"]
+    assert (write["clocked"], write["cpu_ms"], write["off_cpu_ms"]) == (
+        1, 1.0, 7.0)
+    for row in spans.values():
+        if "clocked" in row:
+            assert row["cpu_ms"] + row["off_cpu_ms"] == row["clocked_ms"]
+            assert row["clocked_ms"] <= row["wall_ms"]
+    # the tick's dump carries each clocked span's own reading
+    dumped = {(s["name"], s["dur_ms"]): s.get("cpu_ms")
+              for s in tick.as_dict()["spans"]}
+    assert dumped[("tick.dispatch", 5.0)] == 3.0
+    assert dumped[("deliver.write", 8.0)] == 1.0
+
+
+def test_a_span_around_an_await_adds_to_its_wall_and_to_none_of_the_three():
+    async def scenario():
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+        trace = tracer.begin("tick")
+
+        async def other_task():
+            clocks.spin(20)             # on the same thread's CPU clock
+
+        async def flush():
+            with trace.span("tick.deliver"):
+                clocks.spin(1)
+                await asyncio.create_task(other_task())
+                clocks.spin(1)
+            # one name, one instance of each kind: only the second
+            # is clocked
+            with trace.span("tick.sim.knn"):
+                await asyncio.sleep(0)
+            with trace.span("tick.sim.knn"):
+                clocks.spin(2)
+                clocks.wait(1)
+
+        await asyncio.create_task(flush())
+        tracer.loop.uninstall()
+        return tracer.span_totals(), trace
+
+    spans, trace = run(scenario())
+    assert spans["tick.deliver"]["wall_ms"] == 22.0
+    assert not {"clocked", "clocked_ms", "cpu_ms",
+                "off_cpu_ms"} & set(spans["tick.deliver"])
+    assert all(s.cpu_ms is None for s in trace.spans
+               if s.name == "tick.deliver")
+    knn = spans["tick.sim.knn"]
+    assert (knn["count"], knn["clocked"]) == (2, 1)
+    assert (knn["clocked_ms"], knn["cpu_ms"], knn["off_cpu_ms"]) == (
+        3.0, 2.0, 1.0)
+    assert knn["clocked_ms"] <= knn["wall_ms"]
+
+
+def test_a_span_on_a_worker_thread_is_clocked_on_that_threads_clock():
+    async def scenario():
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+        trace = tracer.begin("tick")
+
+        def on_worker():
+            with trace.span("tick.worker"):
+                clocks.spin(4)
+                clocks.wait(6)          # the loop holds the GIL
+
+        async def collect():
+            with trace.span("tick.collect"):
+                clocks.spin(50)         # the loop's thread, not the worker's
+                await asyncio.to_thread(on_worker)
+
+        await asyncio.create_task(collect())
+        tracer.loop.uninstall()
+        return tracer.span_totals()
+
+    spans = run(scenario())
+    worker = spans["tick.worker"]
+    assert (worker["clocked"], worker["clocked_ms"], worker["cpu_ms"],
+            worker["off_cpu_ms"]) == (1, 10.0, 4.0, 6.0)
+    assert "loop_ms" not in worker and "clocked" not in spans["tick.collect"]
+
+
+def test_a_loose_per_message_span_reads_no_cpu_clock():
+    async def scenario():
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+
+        async def recv():
+            for _ in range(100):
+                with tracer.span("zmq.recv", bytes=64):
+                    with tracer.span("codec.decode"):
+                        clocks.spin(0.1)
+                    with tracer.span("router.handle"):
+                        clocks.spin(0.1)
+
+        reads = clocks.cpu_reads        # (install read it once)
+        await asyncio.create_task(recv())
+        after_messages = clocks.cpu_reads - reads
+        trace = tracer.begin("tick")
+        with trace.span("tick.dispatch"):   # (outside a step: unclocked)
+            pass
+        tracer.loop.uninstall()
+        return tracer.span_totals(), after_messages
+
+    spans, cpu_reads = run(scenario())
+    assert cpu_reads == 0
+    assert spans["zmq.recv"]["count"] == spans["router.handle"]["count"] == 100
+    assert not any("clocked" in row for row in spans.values())
+    # and with the clock off, an explicit trace's spans read none either
+    tracer = Tracer(enabled=True)
+    with tracer.begin("tick").span("tick.dispatch") as span:
+        pass
+    assert span.cpu_ms is None
+
+
+def test_the_loop_gauge_says_how_much_of_busy_was_on_the_cpu():
+    async def scenario():
+        clocks = FakeClocks()
+        tracer = accounted_tracer(clocks)
+        before = tracer.loop.snapshot()
+
+        async def work():
+            clocks.spin(10)
+            clocks.wait(5)              # busy, and not running
+            clocks.idle(20)             # then asleep in the selector
+            await asyncio.sleep(0)
+
+        await asyncio.create_task(work())
+        after = tracer.loop.snapshot()
+        tracer.loop.uninstall()
+        return {k: round(after[k] - before[k], 3) for k in after}
+
+    d = run(scenario())
+    assert (d["wall_ms"], d["busy_ms"], d["cpu_ms"], d["off_cpu_ms"]) == (
+        35.0, 15.0, 10.0, 5.0)
+    assert d["cpu_ms"] <= d["busy_ms"] <= d["wall_ms"]
+
+
+def test_a_spinning_thread_reads_cpu_and_a_sleeping_one_does_not():
+    """The one test on the real clocks, generous: beside each other, a
+    thread that burns 20 ms of CPU and one that sleeps 50 ms."""
+    async def scenario():
+        tracer = accounted_tracer()
+        trace = tracer.begin("tick")
+
+        def spinner():
+            with trace.span("tick.spinner"):
+                c0 = time.thread_time()
+                while time.thread_time() - c0 < 0.02:
+                    pass
+
+        def sleeper():
+            with trace.span("tick.sleeper"):
+                time.sleep(0.05)
+
+        await asyncio.gather(asyncio.to_thread(spinner),
+                             asyncio.to_thread(sleeper))
+        tracer.loop.uninstall()
+        return tracer.span_totals()
+
+    spans = run(scenario())
+    spinner, sleeper = spans["tick.spinner"], spans["tick.sleeper"]
+    assert spinner["clocked"] == sleeper["clocked"] == 1
+    assert spinner["cpu_ms"] >= 19
+    assert sleeper["cpu_ms"] < 10 and sleeper["off_cpu_ms"] >= 40
+    for row in (spinner, sleeper):
+        assert row["cpu_ms"] + row["off_cpu_ms"] == pytest.approx(
+            row["clocked_ms"], abs=0.0011)
+
+
+# endregion
+
+# region: the receive, counted where it happens (ISSUE 38)
+
+
+def test_suspends_counts_only_the_receives_that_found_the_socket_empty():
+    async def scenario():
+        ctx = zmq.asyncio.Context()
+        pull, push = ctx.socket(zmq.PULL), ctx.socket(zmq.PUSH)
+        pull.bind("inproc://counted")
+        push.connect("inproc://counted")
+        counted = _CountedPull(pull)
+        try:
+            # three queued before the loop asks: found waiting
+            for i in range(3):
+                await push.send_multipart([b"early", bytes([i])])
+            await asyncio.sleep(0.05)
+            got = [await counted.recv_multipart() for _ in range(3)]
+            early = counted.stats()
+            # a drain that finds nothing is a call, not a message
+            with pytest.raises(zmq.Again):
+                await counted.recv_multipart(zmq.NOBLOCK)
+            drained = counted.stats()
+            # two asked for before they were sent: each await suspends
+            for i in range(2):
+                recv = counted.recv_multipart()
+                assert not recv.done()
+                await push.send_multipart([b"late", bytes([i])])
+                got.append(await recv)
+            return got, early, drained, counted.stats()
+        finally:
+            pull.close(linger=0)    # (through the wrapper: the socket's)
+            push.close(linger=0)
+            ctx.term()
+
+    got, early, drained, late = run(scenario())
+    assert [parts[0] for parts in got] == [b"early"] * 3 + [b"late"] * 2
+    assert (early["messages"], early["suspends"]) == (3, 0)
+    assert (drained["messages"], drained["suspends"]) == (3, 0)
+    assert (late["messages"], late["suspends"]) == (5, 2)
+    assert 0 < early["take_ns"] < drained["take_ns"] < late["take_ns"]
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_the_recv_loop_counts_with_tracing_on_and_touches_nothing_off(trace):
+    async def scenario():
+        port = free_port()
+        server = WorldQLServer(Config(
+            store_url="memory://", ws_enabled=False, http_enabled=False,
+            zmq_server_host="127.0.0.1", zmq_server_port=port,
+            trace=trace))
+        await server.start()
+        ctx = zmq.asyncio.Context()
+        push = ctx.socket(zmq.PUSH)
+        try:
+            [transport] = server._transports
+            push.connect(f"tcp://127.0.0.1:{port}")
+            for _ in range(20):     # (an unknown sender's: dropped)
+                await push.send(b"not a message")
+                await asyncio.sleep(0.002)
+            for _ in range(200):
+                if server.metrics.snapshot()["gauges"].get(
+                        "zmq_recv", {"messages": 20})["messages"] >= 20:
+                    break
+                await asyncio.sleep(0.01)
+            await asyncio.sleep(0.05)
+            return (type(transport._pull),
+                    server.metrics.snapshot()["gauges"].get("zmq_recv"))
+        finally:
+            push.close(linger=0)
+            ctx.term()
+            await server.stop()
+
+    pull_type, gauge = run(scenario())
+    if not trace:
+        # the socket itself: no wrapper, no counter, no gauge
+        assert pull_type is zmq.asyncio.Socket and gauge is None
+        return
+    assert pull_type is _CountedPull
+    # the 20 sent, and the receive that waits for the 21st
+    assert gauge["messages"] == 21
+    assert 1 <= gauge["suspends"] <= gauge["messages"]
+    assert gauge["take_ns"] > 0
 
 
 # endregion
@@ -362,7 +727,9 @@ def test_spans_annotate_the_profile_only_while_a_capture_is_active(tmp_path):
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     from benchmark.trace_reduce import python_line, read_xplane
 
-    tracer = Tracer(enabled=True)
+    # a wall clock that moves 1 ms a READING: the stop's bracket is as
+    # long as the readings inside it are many, on any host
+    tracer = Tracer(enabled=True, clock=FakeClocks(tick_ms=1.0).wall_s)
     hook = ProfilerHook(tracer=tracer)
     assert tracer.annotate is None
     with tracer.span("before.capture"):
@@ -381,10 +748,15 @@ def test_spans_annotate_the_profile_only_while_a_capture_is_active(tmp_path):
     with tracer.span("after.capture"):
         pass
     # without the python tracer, stopping is quick (it took 1.2 s on
-    # the chip host with it: PERF.md)
+    # the chip host with it: PERF.md). On the hook's own clock: the
+    # bracket holds the stop's span (enter, exit, its loose trace's
+    # finish) and nothing else that reads a clock (how long a stop takes
+    # on a chip host is that host's ``profile.stop_loop_stall_ms``, not
+    # a unit test's to time)
     assert hook.status()["last_stop_ms"] < 1000
     stop = tracer.span_totals()["profile.stop"]
     assert stop["count"] == 1 and stop["wall_ms"] <= hook.last_stop_ms
+    assert (stop["wall_ms"], hook.last_stop_ms) == (1.0, pytest.approx(4.0))
     [pb] = list((tmp_path / "prof").rglob("*.xplane.pb"))
     names = {name for _, _, name in python_line(read_xplane(pb))}
     assert {"tick.deliver", "deliver.drain"} <= names
@@ -449,10 +821,20 @@ def test_metrics_carries_the_new_gauges_in_both_forms():
     assert spans["tick.deliver"]["count"] >= 4
     assert {"count", "wall_ms", "loop_ms", "steps", "max_step_ms"} <= set(
         spans["router.handle"])
-    assert set(LAYERS) | {"busy_ms", "wall_ms", "unattributed",
-                          "rest"} == set(loop_time)
+    assert set(LAYERS) | {"busy_ms", "wall_ms", "unattributed", "rest",
+                          "cpu_ms", "off_cpu_ms"} == set(loop_time)
     assert loop_time["ingest"] > 0 and loop_time["deliver"] > 0
     assert loop_time["busy_ms"] <= loop_time["wall_ms"]
+    assert 0 < loop_time["cpu_ms"] <= loop_time["wall_ms"]
+    # the tick's spans read the CPU clock; the per-message ones do not
+    for name, row in spans.items():
+        if "clocked" in row:
+            assert row["cpu_ms"] + row["off_cpu_ms"] == pytest.approx(
+                row["clocked_ms"], abs=0.0011)
+            assert row["clocked_ms"] <= row["wall_ms"] + 0.001
+    assert spans["deliver.write"]["clocked"] == spans["deliver.write"]["count"]
+    assert "clocked" not in spans["tick.deliver"]       # wraps an await
+    assert "clocked" not in spans["router.handle"]      # a loose trace's
     assert snap["latency"]["tick.queue_wait_ms"]["count"] == 5
     assert "start_mono_ns" in ticks["ticks"][0]
     assert "device_stats_at_dispatch" not in ticks["ticks"][0]["tags"]
@@ -460,6 +842,8 @@ def test_metrics_carries_the_new_gauges_in_both_forms():
     types, samples = validate_exposition(text)
     assert types["wql_spans_wall_ms"] == "gauge"
     assert types["wql_loop_time_busy_ms"] == "gauge"
+    assert types["wql_loop_time_off_cpu_ms"] == "gauge"
+    assert types["wql_spans_off_cpu_ms"] == "gauge"
     assert types["wql_tick_queue_wait_seconds"] == "histogram"
     rows = {labels["name"]: v for name, labels, v in samples
             if name == "wql_spans_count"}

@@ -22,7 +22,15 @@ no jax.
 ``zmq_send`` can make (us, p10 / p50 / p90): a trivial call, a ``poll``
 and a write on an eventfd nobody waits for, and the SENDER's cost of a
 write that wakes a thread blocked in ``epoll_wait`` on it (the I/O
-thread's wake).
+thread's wake). And what the clocks beneath the spans are worth here
+(ISSUE 38, step 0): the cost of one ``time.thread_time_ns()``, of one
+``resource.getrusage(RUSAGE_THREAD)`` and of one ``perf_counter_ns``,
+and four readings that say whether the first two ARE a thread's CPU
+clock (``thread_cpu_ms``): 100 ms of a Python busy loop (wants
+95-105), ``time.sleep(0.1)`` (wants < 2), a thread blocked in
+``Event.wait`` while another spins 100 ms (wants < 2), and two threads
+spinning pure Python through 200 ms of wall (want ~100 each: the GIL),
+and the smallest step the clock makes (its grain).
 
 The last form reads the same two counts from a running server for
 ``--seconds`` and prints them a second: the wakes a flush of the
@@ -35,6 +43,7 @@ import argparse
 import json
 import multiprocessing as mp
 import os
+import resource
 import select
 import statistics
 import threading
@@ -304,8 +313,67 @@ def calibrate() -> dict:
         "p10": q[0], "p50": statistics.median(took), "p90": q[-1]}
     for fd in (quiet, waited):
         os.close(fd)
+    out["perf_counter_ns"] = deciles_us(time.perf_counter_ns)
+    out["thread_time_ns"] = deciles_us(time.thread_time_ns)
+    out["getrusage_thread"] = deciles_us(_rusage_thread_ns)
     print(json.dumps({"calibrate_us": out}), flush=True)
+    sanity = {"thread_time_ns": thread_cpu_sanity(time.thread_time_ns),
+              "getrusage_thread": thread_cpu_sanity(_rusage_thread_ns)}
+    print(json.dumps({"thread_cpu_ms": sanity}), flush=True)
     return out
+
+
+def _rusage_thread_ns() -> int:
+    use = resource.getrusage(resource.RUSAGE_THREAD)
+    return int((use.ru_utime + use.ru_stime) * 1e9)
+
+
+def thread_cpu_sanity(cpu_ns) -> dict:
+    """What ``cpu_ns`` (a clock of the CALLING thread's CPU time) reads,
+    in ms, over four stretches whose answer is known (module docstring):
+    a wall clock reads 100 / 100 / 100 / 200 + 200, a dead one zeros."""
+
+    def spin(ms: float) -> None:
+        end = time.perf_counter() + ms / 1e3
+        while time.perf_counter() < end:
+            pass
+
+    def read(work, *args) -> float:
+        c0 = cpu_ns()
+        work(*args)
+        return (cpu_ns() - c0) / 1e6
+
+    out = {"busy_100ms": read(spin, 100.0),
+           "sleep_100ms": read(time.sleep, 0.1)}
+    readings: dict[str, float] = {}
+
+    def on_thread(key: str, work, *args) -> threading.Thread:
+        def body() -> None:
+            readings[key] = read(work, *args)
+        thread = threading.Thread(target=body)
+        thread.start()
+        return thread
+
+    gate = threading.Event()
+    blocked = on_thread("blocked_beside_a_spinner", gate.wait)
+    spin(100.0)
+    gate.set()
+    blocked.join()
+    pair = [on_thread(f"two_spinners_200ms_wall.{i}", spin, 200.0)
+            for i in (0, 1)]
+    for thread in pair:
+        thread.join()
+    out.update(readings)
+    # the clock's grain: the smallest step it makes while this thread
+    # spins (a kernel that keeps CPU time by sampling steps by its tick)
+    steps, last, end = [], cpu_ns(), time.perf_counter() + 0.1
+    while time.perf_counter() < end:
+        now = cpu_ns()
+        if now != last:
+            steps.append((now - last) / 1e6)
+            last = now
+    out["smallest_step"] = min(steps, default=0.0)
+    return {key: round(ms, 4) for key, ms in out.items()}
 
 
 def watch(pid: int, seconds: float) -> dict:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 
 from ..robustness import failpoints
 from ..robustness.supervisor import Supervisor
@@ -134,6 +135,9 @@ class WorldQLServer:
             # the event loop's account (observability/loop_time.py),
             # installed on the loop by start()
             self.tracer.loop = LoopAccount()
+            # and beneath the wall clock the CPU clock of the thread a
+            # tick's span ran on (observability/spans.py)
+            self.tracer.cpu_clock = time.thread_time_ns
         if hasattr(self.backend, "_note_failure"):  # ResilientBackend
             self.backend.metrics = self.metrics
         # Device telemetry (observability/device.py): compile/retrace

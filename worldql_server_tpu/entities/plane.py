@@ -1252,13 +1252,29 @@ class EntityPlane:
         pos = np.asarray(handle["pos"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
         targets = np.asarray(handle["targets"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
         counts = np.asarray(handle["counts"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
+        # with the tracer's CPU clock on, the wait's two legs are read
+        # apart: the fetches (device wait + D2H, the GIL released) and
+        # what of the re-quantisation, pure compute, its thread was NOT
+        # on the CPU for (the GIL the loop holds, or preemption). That
+        # is a difference of two clocks and is not floored: where the
+        # kernel samples CPU time (10 ms a tick on the chip hosts) one
+        # reading means nothing and the mean of many is unbiased
+        tracer = self.tracer
+        cpu_clock = tracer.cpu_clock if tracer is not None else None
+        if cpu_clock is not None:
+            t_fetched, cpu0 = time.perf_counter(), cpu_clock()
         cubes = cube_coords_batch(pos.astype(np.float64), self.cube_size)
-        knn_ms = (time.perf_counter() - t0) * 1e3
+        if cpu_clock is not None:
+            cpu_ms = (cpu_clock() - cpu0) / 1e6
+        t1 = time.perf_counter()
         out = {
             "mode": mode,
             "pos": pos, "targets": targets, "counts": counts,
-            "cubes": cubes, "cap": handle["cap"], "knn_ms": knn_ms,
+            "cubes": cubes, "cap": handle["cap"], "knn_ms": (t1 - t0) * 1e3,
         }
+        if cpu_clock is not None:
+            out["knn_fetch_ms"] = (t_fetched - t0) * 1e3
+            out["knn_off_cpu_ms"] = (t1 - t_fetched) * 1e3 - cpu_ms
         if mode == "delta":
             out["rows"] = handle["rows"]
             out["dirty_keys"] = handle["dirty_keys"]
@@ -1372,6 +1388,9 @@ class EntityPlane:
         if self.metrics is not None:
             self.metrics.observe_ms("sim.knn_ms", result["knn_ms"])
             self.metrics.observe_ms("sim.apply_ms", self.last_apply_ms)
+            if "knn_fetch_ms" in result:    # collect_tick: the CPU clock
+                for leg in ("knn_fetch_ms", "knn_off_cpu_ms"):
+                    self.metrics.observe_ms(f"sim.{leg}", result[leg])
             if moved_slots.size:
                 self.metrics.inc("sim.index_moves", int(moved_slots.size))
             if pairs:
@@ -1393,6 +1412,9 @@ class EntityPlane:
                 "knn_ms": round(result["knn_ms"], 3),
                 "apply_ms": round(self.last_apply_ms, 3),
             }
+            for leg in ("knn_fetch_ms", "knn_off_cpu_ms"):
+                if leg in result:
+                    tags[leg] = round(result[leg], 3)
             if self._delta_ticks:
                 tags["delta"] = dict(self.last_delta_stats)
             trace.tag(sim=tags)

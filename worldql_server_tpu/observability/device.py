@@ -31,8 +31,9 @@ PR 5 observability substrate:
   dispatch/collect instrumentation points — see
   spatial/tpu_backend.py) and feeds the
   ``device.{encode,h2d,compute,d2h,decode}_ms`` histograms, and
-  ``device.mesh_fetch_ms`` on a tick whose timing carries that leg
-  (parallel/sharded_backend.py).
+  ``device.mesh_fetch_ms`` / ``device.decode_off_cpu_ms`` on a tick
+  whose timing carries that leg (parallel/sharded_backend.py; the
+  decode's bracket with the tracer's CPU clock on).
 * **Live buffer gauge** — :func:`live_device_bytes` sums live jax
   array footprints at scrape time (the ``device`` gauge), without ever
   importing jax on its own: a CPU-backend server that never loaded jax
@@ -224,6 +225,15 @@ class DeviceTelemetry:
                         self.metrics.observe_ms(
                             f"device.{leg}", max(float(value), 0.0)
                         )
+                # only with the tracer's CPU clock on, on a tick that
+                # decoded. A difference of two clocks, and NOT floored:
+                # on a kernel that samples CPU time (the chip hosts,
+                # 10 ms a tick) only the mean of many is the reading
+                off_cpu = timing.get("decode_off_cpu_ms")
+                if off_cpu is not None:
+                    self.metrics.observe_ms(
+                        "device.decode_off_cpu_ms", off_cpu
+                    )
         self.poll_retraces()
 
     # endregion

@@ -99,8 +99,10 @@ class ProfilerHook:
 
     def __init__(self, tracer=None):
         self._lock = threading.Lock()
-        #: the server's Tracer: its spans annotate the capture
+        #: the server's Tracer: its spans annotate the capture, and
+        #: its wall clock times the stop
         self.tracer = tracer
+        self._clock = tracer.clock if tracer is not None else time.perf_counter
         self.active_dir: str | None = None
         self.captures = 0
         self.last_stop_ms = 0.0
@@ -134,12 +136,12 @@ class ProfilerHook:
             if self.tracer is not None:
                 self.tracer.annotate = None
                 span = self.tracer.span("profile.stop")
-            t0 = time.perf_counter()
+            t0 = self._clock()
             # spanned: what stopping cost the loop stays readable after
             # the fact as `spans["profile.stop"].wall_ms`
             with span:
                 jax.profiler.stop_trace()
-            self.last_stop_ms = (time.perf_counter() - t0) * 1e3
+            self.last_stop_ms = (self._clock() - t0) * 1e3
             log_dir, self.active_dir = self.active_dir, None
             self.captures += 1
             logger.info(

@@ -20,12 +20,27 @@ With tracing on, :class:`LoopAccount` accounts the loop's time itself:
   bookkeeping, tasks older than the install) is ``busy - sum(names)``:
   it shows as ``unattributed`` rather than vanishing.
 
-The identity that holds by construction:
-``sum(layers) + unattributed = busy_ms <= wall_ms``.
+* **On the CPU.** Busy says the loop was not waiting in its selector;
+  it cannot say the loop's thread was RUNNING. ``cpu_ms`` is that
+  thread's CPU time since install (``time.thread_time_ns``, read by
+  ``snapshot``, which runs on it: nothing is paid a step), and
+  ``off_cpu_ms`` = ``busy_ms - cpu_ms`` is the busy time it was off
+  the CPU: waiting for the GIL a worker thread holds, in the kernel,
+  or preempted (less the CPU the selector itself takes, some us a
+  ``select``: that is in ``cpu_ms`` and not in ``busy_ms``, so on a
+  loop nobody makes wait the difference can be a little below 0). The
+  steps carry a serial, so a span can tell whether it entered and left
+  inside ONE step, where its own CPU reading means something
+  (``spans.py``).
+
+The identities that hold by construction:
+``sum(layers) + unattributed = busy_ms <= wall_ms``;
+``cpu_ms <= busy_ms (+ the selector's own CPU) <= wall_ms``.
 
 Work on OTHER threads (``asyncio.to_thread``: the collect worker, the
 WAL writer) is not loop time and is not charged; it does compete for
-the GIL, which stretches the loop's steps and is inside their time.
+the GIL, which stretches the loop's steps and is inside their time:
+``off_cpu_ms`` is where that shows.
 
 With tracing off nothing here is constructed: no factory is installed
 and no coroutine is wrapped.
@@ -140,7 +155,12 @@ class LoopAccount:
     then that thread's alone, and ``snapshot`` (a scrape, also on the
     loop) reads between steps."""
 
-    def __init__(self):
+    def __init__(self, clock=time.perf_counter_ns,
+                 cpu_clock=time.thread_time_ns):
+        #: the wall clock of the steps and the CPU clock of the calling
+        #: thread, both ns (a test's fake ones)
+        self._clock = clock
+        self._cpu_clock = cpu_clock
         self._loop = None
         self._thread = 0
         #: name -> [loop ns, stretches charged, longest stretch ns]
@@ -152,7 +172,11 @@ class LoopAccount:
         self._mark = 0
         self._owner = None
         self._running = None
+        # the steps' serial: that of the step running, on the loop's
+        # thread (what span_entered hands a span, and in_step compares)
+        self._serial = 0
         self._t_install = 0
+        self._cpu_install = 0
         self._idle_ns = 0
         self._selector = None       # the loop's, while its select is timed
         self._prev_factory = None
@@ -164,7 +188,9 @@ class LoopAccount:
         self._thread = threading.get_ident()
         self._prev_factory = loop.get_task_factory()
         loop.set_task_factory(self._task_factory)
-        self._t_install = time.perf_counter_ns()
+        clock = self._clock
+        self._t_install = clock()
+        self._cpu_install = self._cpu_clock()
         selector = getattr(loop, "_selector", None)
         if selector is not None:
             # busy = wall - blocked in select: the one place a selector
@@ -172,11 +198,11 @@ class LoopAccount:
             self._selector = selector
 
             def timed_select(timeout=None, _select=selector.select):
-                t0 = time.perf_counter_ns()
+                t0 = clock()
                 try:
                     return _select(timeout)
                 finally:
-                    self._idle_ns += time.perf_counter_ns() - t0
+                    self._idle_ns += clock() - t0
 
             selector.select = timed_select
         return self
@@ -222,27 +248,44 @@ class LoopAccount:
         cur = _CURRENT.get()
         self._running = timed
         self._owner = cur[2] if cur is not None else None
-        self._mark = time.perf_counter_ns()
+        self._serial += 1
+        self._mark = self._clock()
         try:
             return resume(*args)
         finally:
-            self._charge(self._owner, time.perf_counter_ns() - self._mark)
+            self._charge(self._owner, self._clock() - self._mark)
             self._mark = 0
             self._owner = self._running = None
 
-    def span_entered(self, name: str) -> None:
+    def span_entered(self, name: str) -> int:
         """The charge moves to ``name`` (called by a span's enter; a
-        no-op off the loop's thread and outside a timed step)."""
-        if self._mark and threading.get_ident() == self._thread:
-            now = time.perf_counter_ns()
-            self._charge(self._owner, now - self._mark)
-            self._mark, self._owner = now, name
+        no-op off the loop's thread and outside a timed step). Returns
+        where the span stands, for ``in_step`` when it exits: the
+        serial of the step that is running, -1 on any other thread, 0
+        where nobody can tell (on the loop's thread outside a timed
+        step, or no loop accounted)."""
+        if threading.get_ident() != self._thread:
+            return -1 if self._loop is not None else 0
+        if not self._mark:
+            return 0
+        now = self._clock()
+        self._charge(self._owner, now - self._mark)
+        self._mark, self._owner = now, name
+        return self._serial
+
+    def in_step(self, entered: int) -> bool:
+        """Whether the caller still stands where ``span_entered`` said:
+        on a thread of its own, or in the same step of the loop. A span
+        that does shared its thread's CPU clock with no other task."""
+        if threading.get_ident() != self._thread:
+            return entered == -1
+        return entered > 0 and bool(self._mark) and entered == self._serial
 
     def span_exited(self, name: str, parent: str | None) -> None:
         """``name`` is charged up to now; the charge moves back to the
         span around it, or (None) to the task when there is none."""
         if self._mark and threading.get_ident() == self._thread:
-            now = time.perf_counter_ns()
+            now = self._clock()
             self._charge(name, now - self._mark)
             self._mark, self._owner = now, parent
 
@@ -263,17 +306,26 @@ class LoopAccount:
         """The ``loop_time`` gauge, all in ms since install: the layers,
         ``busy_ms``/``wall_ms``, ``unattributed`` (busy no step
         claimed) and ``rest`` = busy - ingest - dispatch - deliver, the
-        one key a per-tick metric reads beside those three."""
+        one key a per-tick metric reads beside those three; ``cpu_ms``
+        (the loop's thread on the CPU: the caller must BE that thread)
+        and ``off_cpu_ms`` = busy - cpu. Not floored: both are read by
+        their growth between two scrapes, and on a loop that waits for
+        nobody the selector's own CPU (some us a ``select``: in
+        ``cpu_ms``, outside ``busy_ms``) makes that growth negative."""
         layers = dict.fromkeys(LAYERS, 0)
         for name, (ns, _, _) in list(self._held.items()):
             layers[layer_of(name)] += ns
         out = {layer: round(ns / 1e6, 3) for layer, ns in layers.items()}
-        wall_ns = time.perf_counter_ns() - self._t_install
+        wall_ns = self._clock() - self._t_install
         out["wall_ms"] = round(wall_ns / 1e6, 3)
         if self._selector is not None:
             busy_ns = wall_ns - self._idle_ns
             claimed = sum(layers.values())
             out["busy_ms"] = round(busy_ns / 1e6, 3)
+            if threading.get_ident() == self._thread:
+                cpu_ns = self._cpu_clock() - self._cpu_install
+                out["cpu_ms"] = round(cpu_ns / 1e6, 3)
+                out["off_cpu_ms"] = round((busy_ns - cpu_ns) / 1e6, 3)
             out["unattributed"] = round((busy_ns - claimed) / 1e6, 3)
             out["rest"] = round(
                 (busy_ns - layers["ingest"] - layers["dispatch"]

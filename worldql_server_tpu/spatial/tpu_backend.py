@@ -63,6 +63,7 @@ from . import jaxconf  # noqa: F401  (must precede jax import)
 import jax
 import jax.numpy as jnp
 
+from ..observability.spans import current_cpu_clock
 from ..protocol.types import Replication, Vector3
 from ..queries.kinds import PARAM_LANES as _QUERY_PARAM_LANES
 from ..utils import retrace
@@ -2839,10 +2840,21 @@ class TpuSpatialBackend(SpatialBackend):
     def _timed_decode(timing: dict, decode, *args):
         """The collect's last leg: the fetched ids walked into per-query
         UUID lists, bracketed into ``decode_ms`` of the tick's timing
-        (``timing`` IS the published ``last_device_timing``)."""
+        (``timing`` IS the published ``last_device_timing``). Beneath a
+        tick's span with the tracer's CPU clock on (the collect's
+        worker thread carries the tick's context), also what of it this
+        thread was NOT on the CPU for, ``decode_off_cpu_ms``: a compute
+        leg, so all of that is the GIL or preemption."""
+        cpu_clock = current_cpu_clock()
         t_decode = time.perf_counter()
+        if cpu_clock is not None:
+            cpu0 = cpu_clock()
         out = decode(*args)
+        if cpu_clock is not None:
+            cpu_ms = (cpu_clock() - cpu0) / 1e6
         timing["decode_ms"] = (time.perf_counter() - t_decode) * 1e3
+        if cpu_clock is not None:   # (not floored: observability/device.py)
+            timing["decode_off_cpu_ms"] = timing["decode_ms"] - cpu_ms
         return out
 
     def _compact_applicable(self, t_cap: int) -> bool:

@@ -81,10 +81,54 @@ def _valid_socket_addr(parameter: str) -> bool:
     return port.isdigit() and 0 < int(port) < 65536
 
 
+class _CountedPull:
+    """The PULL socket of a TRACED server: the receive measured where
+    it happens, outside every span (the ``zmq_recv`` gauge). A message
+    found waiting costs the loop the ``recv_multipart`` CALL alone
+    (pyzmq's Python, a ``getsockopt(EVENTS)``, the non-blocking
+    receive): ``take_ns``, ``perf_counter_ns`` around every call, those
+    of a drain that found nothing too. One that arrives on an idle
+    socket costs a suspended await besides: zmq's fd handler, a task
+    wake-up, two trips through the selector. ``suspends`` counts the
+    calls whose future was not done on return; ``messages`` those that
+    took a multipart or suspended for one (a suspended receive ends
+    with a message or with the loop, so at a scrape at most one is
+    still to arrive). ``suspends`` / ``messages`` is the socket's
+    queue as the loop sees it: near 1 a wake cycle a message, near 0 a
+    backlog drained. Built by ``start`` only with tracing on: an
+    untraced server's ``_pull`` is the socket itself."""
+
+    __slots__ = ("_sock", "_recv", "messages", "suspends", "take_ns")
+
+    def __init__(self, sock):
+        self._sock = sock
+        self._recv = sock.recv_multipart
+        self.messages = self.suspends = self.take_ns = 0
+
+    def recv_multipart(self, flags=0):
+        t0 = time.perf_counter_ns()
+        recv = self._recv(flags)
+        self.take_ns += time.perf_counter_ns() - t0
+        if not recv.done():
+            self.suspends += 1
+            self.messages += 1
+        elif not recv.cancelled() and recv.exception() is None:
+            self.messages += 1      # (else zmq.Again: nothing was there)
+        return recv
+
+    def stats(self) -> dict:
+        return {"messages": self.messages, "suspends": self.suspends,
+                "take_ns": self.take_ns}
+
+    def __getattr__(self, name):    # close, setsockopt: the socket's
+        return getattr(self._sock, name)
+
+
 class ZmqTransport:
     def __init__(self, server):
         self.server = server
         self.ctx = zmq.asyncio.Context()
+        # (a _CountedPull around it when the server is traced)
         self._pull: zmq.asyncio.Socket | None = None
         self._push_sockets: dict[uuid_mod.UUID, zmq.asyncio.Socket] = {}
         self._recv_task: asyncio.Task | None = None
@@ -117,6 +161,10 @@ class ZmqTransport:
         # their 120 s patience (PERF.md, PR 25).
         self._pull.setsockopt(zmq.BACKLOG, _LISTEN_BACKLOG)
         self._pull.bind(f"tcp://{config.zmq_server_host}:{config.zmq_server_port}")
+        tracer = getattr(self.server, "tracer", None)
+        if tracer is not None and tracer.enabled:
+            self._pull = _CountedPull(self._pull)
+            self.server.metrics.gauge("zmq_recv", self._pull.stats)
         logger.info(
             "ZeroMQ PULL server listening on %s:%s",
             config.zmq_server_host,
