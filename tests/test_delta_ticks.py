@@ -34,6 +34,7 @@ from worldql_server_tpu.robustness.resilient import ResilientBackend
 from worldql_server_tpu.spatial.delta_ticks import (
     TemporalCoherence, row_signatures,
 )
+from worldql_server_tpu.spatial.hashing import spatial_keys
 from worldql_server_tpu.spatial.quantize import cube_coords_batch
 from worldql_server_tpu.spatial.tpu_backend import TpuSpatialBackend
 
@@ -465,39 +466,79 @@ def _vel_flex(v):
     return np.asarray(v, np.float32).astype("<f4").tobytes()
 
 
-def test_delta_sim_parity_under_randomized_churn():
+def _audit_kept_columns(pl):
+    """What the plane keeps beside its columns equals a recount: the
+    key of every live slot, the count of live movers."""
+    cap = pl._cap
+    live = pl._live[:cap]
+    assert np.array_equal(
+        pl._key[:cap][live],
+        spatial_keys(pl._wid[:cap], pl._cube[:cap], 0)[live],
+    )
+    assert pl._n_moving == int(np.count_nonzero(
+        live & (pl._vel[:cap] != 0.0).any(axis=1)
+    ))
+
+
+@pytest.mark.parametrize("case", ["movers", "still"])
+def test_delta_sim_parity_under_randomized_churn(case):
     """>= 200 sim ticks of randomized churn — client updates, joins,
     leaves, movers, a forced capacity-tier change, and a mid-run
     abort — keep the delta plane's live targets, positions, cubes and
-    frame count identical to the full-recompute plane."""
+    frame count identical to the full-recompute plane. ``still`` is
+    the benchmark's shape: nobody has a velocity, 16 entities share a
+    cube, and every tick the wire steps a few of them, some across a
+    cube face. Both cases also hold a still entity the device reflects
+    at the bounds while the wire had not touched it, and a slot that
+    changes hands between a dispatch and its apply."""
     rng = np.random.default_rng(31)
     owner = uuid.UUID(int=4242)
+    still = case == "still"
+    bounds = 390.0
 
     def make(mode):
         be = TpuSpatialBackend(16)
         return EntityPlane(
-            be, None, cube_size=16, k=4, dt=0.05, bounds=400.0,
+            be, None, cube_size=16, k=4, dt=0.05, bounds=bounds,
             delta_ticks=mode,
         )
 
     planes = [make("on"), make("off")]
-    ids = [uuid.uuid4() for _ in range(220)]
-    pos = rng.uniform(-350, 350, (220, 3))
-    vel = np.zeros((220, 3), np.float32)
-    vel[:12] = rng.uniform(-25, 25, (12, 3))  # a few movers, rest idle
+    ids = [uuid.uuid4() for _ in range(224)]
+    vel = np.zeros((224, 3), np.float32)
+    if still:
+        corners = rng.integers(-20, 20, (14, 3)) * 16.0
+        pos = np.repeat(corners, 16, axis=0) + rng.uniform(1, 15, (224, 3))
+    else:
+        pos = rng.uniform(-350, 350, (224, 3))
+        vel[:12] = rng.uniform(-25, 25, (12, 3))  # a few movers, rest idle
     alive = set(range(200))
-    for pl in planes:
-        pl.ingest(_ent_msg(owner, [
-            Entity(uuid=ids[i], world_name="w",
-                   position=Vector3(*pos[i]),
-                   flex=_vel_flex(vel[i]) if vel[i].any() else None)
-            for i in sorted(alive)
-        ]))
 
-    def tick(pl):
+    def send(entities, parameter=None):
+        for pl in planes:
+            pl.ingest(_ent_msg(owner, entities, parameter))
+
+    def ent(eid, p, v=None):
+        return Entity(uuid=eid, world_name="w", position=Vector3(*p),
+                      flex=_vel_flex(v) if v is not None else None)
+
+    send([ent(ids[i], pos[i], vel[i] if vel[i].any() else None)
+          for i in sorted(alive)])
+
+    def tick(pl, between=None):
         handle = pl.dispatch_tick()
         assert handle is not None
+        if between is not None:
+            between(pl)
         return pl.apply(pl.collect_tick(handle))
+
+    # outside the random churn: `far` is registered three bounds out
+    # with no velocity, so the device reflects it TWICE, a tick each
+    # (1178 -> -398 -> -382), the second time over the cube face at
+    # -384 while it is not dirty: only its cube-mate `mate` is, which
+    # steps over the same face; `leaver`'s slot goes to `heir` while a
+    # tick that computes it is in flight
+    far, mate, leaver, heir = (uuid.uuid4() for _ in range(4))
 
     next_id = 200
     for t in range(205):
@@ -505,32 +546,58 @@ def test_delta_sim_parity_under_randomized_churn():
         if op < 0.15 and alive:  # client position update
             i = sorted(alive)[int(rng.integers(0, len(alive)))]
             p = rng.uniform(-350, 350, 3)
-            for pl in planes:
-                pl.ingest(_ent_msg(owner, [Entity(
-                    uuid=ids[i], world_name="w", position=Vector3(*p),
-                )]))
+            send([ent(ids[i], p)])
         elif op < 0.25 and alive:  # leave
             i = sorted(alive)[int(rng.integers(0, len(alive)))]
             alive.discard(i)
-            for pl in planes:
-                pl.ingest(_ent_msg(owner, [Entity(uuid=ids[i])],
-                                   parameter="entity.remove"))
-        elif op < 0.35 and next_id < 220:  # join
+            send([Entity(uuid=ids[i])], parameter="entity.remove")
+        elif op < 0.35 and next_id < 224:  # join
             i = next_id
             next_id += 1
             alive.add(i)
-            for pl in planes:
-                pl.ingest(_ent_msg(owner, [Entity(
-                    uuid=ids[i], world_name="w",
-                    position=Vector3(*pos[i]),
-                )]))
+            send([ent(ids[i], pos[i])])
+        if still and alive:
+            # the cell's walk: steps inside the cube, one in four of
+            # them over a face into the next cube
+            walkers = rng.choice(sorted(alive), 5, replace=False)
+            for i in walkers:
+                cur = planes[0]._pos[planes[0]._slot_of[ids[i]]]
+                step = rng.integers(-1, 2, 3) * (
+                    16.0 if rng.random() < 0.25 else 0.125
+                )
+                send([ent(ids[i], np.clip(cur + step, -380, 380))])
+        between = None
+        if t == 30:
+            send([ent(far, (3 * bounds + 8.0, 3.0, 3.0)),
+                  ent(mate, (-388.0, 4.0, 4.0))])
+        elif t == 31:
+            slot = planes[0]._slot_of[far]
+            assert planes[0]._pos[slot, 0] == -bounds - 8.0
+            assert not planes[0]._window_dirty[slot]
+            send([ent(mate, (-383.0, 4.0, 4.0))])
+        elif t == 32:
+            slot = planes[0]._slot_of[far]
+            assert planes[0]._pos[slot, 0] == 8.0 - bounds
+            assert planes[0]._cube[slot, 0] != cube_coords_batch(
+                np.array([[-bounds - 8.0, 3.0, 3.0]]), 16)[0, 0]
+        elif t == 60:
+            send([ent(leaver, pos[0] + 1.0)])
+        elif t == 61:
+            send([ent(leaver, pos[0] + 2.0)])  # dirty: the tick computes it
+
+            def between(pl):
+                slot = pl._slot_of[leaver]
+                pl.ingest(_ent_msg(owner, [Entity(uuid=leaver)],
+                                   parameter="entity.remove"))
+                pl.ingest(_ent_msg(owner, [ent(heir, pos[1] + 1.0)]))
+                assert pl._slot_of[heir] == slot
         if t == 100:
             # mid-run abort: the in-flight tick drops on BOTH planes
             for pl in planes:
                 h = pl.dispatch_tick()
                 assert h is not None
                 pl.abort_tick()
-        frames = [tick(pl) for pl in planes]
+        frames = [tick(pl, between) for pl in planes]
         cap = planes[0]._cap
         assert planes[0]._cap == planes[1]._cap
         live = planes[0]._live[:cap]
@@ -546,11 +613,122 @@ def test_delta_sim_parity_under_randomized_churn():
             getattr(f, "wire", None) or b"" for f, _ in fr
         ) for fr in frames]
         assert wires[0] == wires[1], f"tick {t}: frame bytes diverged"
+        for pl in planes:
+            _audit_kept_columns(pl)
     on = planes[0]
     assert on.delta_sim_ticks > 100
     assert on.delta_reused > 0
     assert on.delta_mispredicts == 0
     assert planes[1].delta_sim_ticks == 0
+    # both ways to the closure ran: the kept column tested with isin
+    # (keys written since the last dispatch) and the sorted view
+    assert 0 < on.dispatch_scan_rows
+    assert on.quantised_rows < planes[1].quantised_rows
+
+
+def test_delta_tick_host_work_follows_the_dirty_window():
+    """A delta tick of a still world quantises no more rows than were
+    dirty and, once the key column has stood still for a tick, reads
+    no pass as long as the tier; a world with ONE mover pays the
+    velocity scan again, and stops paying it when the mover stops."""
+    owner = uuid.UUID(int=7)
+    metrics = Metrics()
+    pl = EntityPlane(
+        TpuSpatialBackend(16), None, cube_size=16, k=4, dt=0.05,
+        bounds=1000.0, delta_ticks="on", metrics=metrics,
+    )
+    rng = np.random.default_rng(5)
+    corners = rng.permutation(20 ** 3)[:40]
+    corners = np.stack(
+        [corners % 20, corners // 20 % 20, corners // 400], 1
+    ) * 16.0 - 160.0
+    pos = np.repeat(corners, 16, axis=0) + rng.uniform(1, 15, (640, 3))
+    ids = [uuid.uuid4() for _ in range(640)]
+    pl.ingest(_ent_msg(owner, [
+        Entity(uuid=ids[i], world_name="w", position=Vector3(*pos[i]))
+        for i in range(640)
+    ]))
+    cap = pl._cap
+    assert cap == 1024
+
+    def tick(updates=()):
+        """One tick after the given (index, position[, velocity])
+        updates: (rows quantised, rows read in tier-long passes)."""
+        if updates:
+            pl.ingest(_ent_msg(owner, [
+                Entity(uuid=ids[u[0]], world_name="w",
+                       position=Vector3(*u[1]),
+                       flex=_vel_flex(u[2]) if len(u) > 2 else None)
+                for u in updates
+            ]))
+        before = dict(metrics.counters)
+        pl.apply(pl.collect_tick(pl.dispatch_tick()))
+        return tuple(
+            metrics.counters[name] - before.get(name, 0)
+            for name in ("sim.quantised_rows", "sim.dispatch_scan_rows")
+        )
+
+    def steps(rows, by=0.125):
+        return [(i, pl._pos[pl._slot_of[ids[i]]] + by) for i in rows]
+
+    assert tick() == (cap, 0)                 # cold: one full tick
+    assert tick() == (0, 0)                   # nothing dirty: a replay
+    # the registrations wrote keys: tested with isin this tick, sorted
+    # at the next (the column stood still), binary-searched from then
+    assert tick(steps([3, 99, 200])) == (3, cap)
+    assert tick(steps([3, 99, 200, 401])) == (4, cap)
+    assert tick(steps([5, 17, 300])) == (3, 0)
+    assert tick(steps(range(0, 640, 64))) == (10, 0)
+    assert pl.delta_sim_ticks == 5 and pl.full_sim_ticks == 1
+    # a step over a cube face: churn writes a key as the tick applies
+    assert tick([(8, pos[8] + (16.0, 0.0, 0.0))]) == (1, 0)
+    assert pl.last_churn == 1
+    assert tick(steps([8])) == (1, cap)       # stale: isin
+    assert tick(steps([8])) == (1, cap)       # stood still: sorted
+    assert tick(steps([8])) == (1, 0)
+    # one mover: it is dirty every tick, and found by the scan
+    moving = (1.0, 0.0, 0.0)
+    assert tick([(40, pos[40], moving)]) == (1, cap)
+    assert pl._n_moving == 1
+    assert tick() == (1, cap)                 # dirty by the scan alone
+    assert tick(steps([3])) == (2, cap)
+    # it stops: the scan goes with it
+    stop = pl._pos[pl._slot_of[ids[40]]].copy()
+    assert tick([(40, stop, (0.0, 0.0, 0.0))]) == (1, 0)
+    assert pl._n_moving == 0
+    assert tick(steps([3])) == (1, 0)
+    assert pl.delta_mispredicts == 0
+    assert pl.stats()["quantised_rows"] == metrics.counters[
+        "sim.quantised_rows"]
+    assert pl.stats()["dispatch_scan_rows"] == metrics.counters[
+        "sim.dispatch_scan_rows"]
+
+
+def test_predicted_cubes_replay_the_kernel_reflection():
+    """The dispatch predicts a dirty row's landing cube by replaying
+    the kernel's integration: ONE reflection a tick, so a still row
+    five bounds out lands outside the other wall, and the closure
+    audit finds it where it was predicted."""
+    owner = uuid.UUID(int=11)
+    pl = EntityPlane(TpuSpatialBackend(16), None, cube_size=16, k=4,
+                     bounds=392.0, delta_ticks="on")
+    eid = uuid.uuid4()
+    pl.ingest(_ent_msg(owner, [     # bystanders: one dirty row is no churn
+        Entity(uuid=uuid.uuid4(), world_name="w",
+               position=Vector3(20.0 * i, 1.0, 1.0)) for i in range(7)
+    ]))
+
+    def place_and_tick(x):
+        pl.ingest(_ent_msg(owner, [Entity(
+            uuid=eid, world_name="w", position=Vector3(x, 3.0, 3.0))]))
+        pl.apply(pl.collect_tick(pl.dispatch_tick()))
+        return float(pl._pos[pl._slot_of[eid], 0])
+
+    assert place_and_tick(10.0) == 10.0                 # cold: full
+    assert place_and_tick(5 * 392.0 + 0.5) == -1176.5   # a delta tick
+    assert place_and_tick(-1176.5) == 392.5
+    assert pl.delta_sim_ticks == 2 and pl.index_moves == 2
+    assert pl.delta_mispredicts == 0
 
 
 def test_delta_sim_tier_change_falls_back_and_recovers():

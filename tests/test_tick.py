@@ -13,7 +13,7 @@ from worldql_server_tpu.ops.tick import (
     example_state,
     make_tick_fn,
 )
-from worldql_server_tpu.spatial.hashing import spatial_keys
+from worldql_server_tpu.spatial.hashing import spatial_key, spatial_keys
 from worldql_server_tpu.spatial.quantize import coord_clamp
 
 
@@ -43,6 +43,21 @@ def test_device_keys_match_host_keys():
         device_spatial_keys(jnp.asarray(worlds), jnp.asarray(cubes), seed=3)
     )
     np.testing.assert_array_equal(host, dev)
+
+
+def test_scalar_key_matches_the_vectorized_keys():
+    """``spatial_key`` (one row in Python ints: the entity plane's
+    registration path) is ``spatial_keys`` bit for bit, the dead world
+    (-1), negative cubes and the int64 extremes included."""
+    rng = np.random.default_rng(6)
+    worlds = rng.integers(-1, 50, 400).astype(np.int32)
+    cubes = rng.integers(-2**62, 2**62, (400, 3))
+    cubes[:6] = [[0, 0, 0], [-1, -1, -1], [2**63 - 1] * 3,
+                 [-2**63] * 3, [16, -32, 48], [1, 0, -1]]
+    for seed in (0, 3):
+        want = spatial_keys(worlds, cubes, seed)
+        got = [spatial_key(worlds[i], cubes[i], seed) for i in range(400)]
+        assert got == want.tolist()
 
 
 def test_tick_counts_and_targets_match_numpy():

@@ -50,6 +50,20 @@ index can never diverge structurally — both are derived from the same
 host columns, and the index mutation happens in the same event-loop
 turn as the position writeback.
 
+What a DELTA tick reads (``delta_ticks``): its host legs follow the
+rows its dirty window names, not the capacity tier. The plane keeps
+beside its columns what those legs would otherwise recompute every
+tick — ``_key`` (the spatial key of every slot's registered cube,
+written wherever ``_cube`` is), a sorted view of it (valid until a key
+is written), and ``_n_moving`` (live slots with a velocity, kept at
+every write of ``_vel``) — so ``dispatch_tick`` finds the dirty-cube
+closure by binary search and scans the velocity column only while
+somebody moves, and ``collect_tick`` hands the f64 quantiser only the
+rows that were dirty at dispatch or that the device handed back
+changed: every other closure row still holds the position its
+registered cube was quantised from. Counters ``sim.quantised_rows``
+and ``sim.dispatch_scan_rows`` say what a tick paid.
+
 Tick-path discipline: ``dispatch_tick``/``collect_tick`` are the
 sim-tick hot functions — no per-entity Python, host syncs only at the
 designated collect points (tools/check: host-sync-in-sim-tick). Frame
@@ -76,7 +90,7 @@ from ..ops.tick import EntityState, make_tick_fn
 from ..protocol import entity_wire
 from ..robustness import failpoints
 from ..protocol.types import Entity, Instruction, Message, Vector3
-from ..spatial.hashing import spatial_keys
+from ..spatial.hashing import spatial_key, spatial_keys
 from ..spatial.quantize import cube_coords_batch
 from ..utils.names import SanitizeError, sanitize_world_name
 from ..utils.retrace import GUARD
@@ -228,7 +242,24 @@ class EntityPlane:
         self._pid = np.full(self._cap, -1, np.int32)
         #: cube currently registered in the authoritative index
         self._cube = np.zeros((self._cap, 3), np.int64)
+        #: ``spatial_keys(_wid, _cube, 0)`` of every slot, written
+        #: wherever ``_cube`` is: the delta closure tests THIS column
+        #: instead of hashing the tier every tick (a dead slot keeps
+        #: its last key; ``_live`` masks it)
+        self._key = np.zeros(self._cap, np.int64)
+        #: the live slots in key order, ``(keys, slots)``: while it
+        #: stands the closure is two binary searches a dirty key. A
+        #: write of ``_key`` or ``_live`` (alloc, release, churn) drops
+        #: it; it is sorted again only by a delta dispatch that finds
+        #: no such write since the LAST dispatch (keys that change
+        #: every tick are tested with ``np.isin``, never sorted)
+        self._key_view: tuple[np.ndarray, np.ndarray] | None = None
+        self._key_written = False
         self._live = np.zeros(self._cap, bool)
+        #: live slots whose velocity is not zero (by ``_is_moving``):
+        #: kept at every write of ``_vel``, so a still world's delta
+        #: dispatch knows without a scan that nothing integrates
+        self._n_moving = 0
         #: slots mutated by wire ingest since the LAST dispatch — the
         #: post-tick position writeback must not clobber them
         self._touched = np.zeros(self._cap, bool)
@@ -294,6 +325,11 @@ class EntityPlane:
         self.delta_fallbacks = 0
         self.delta_mispredicts = 0
         self.last_delta_stats: dict = {}
+        #: rows ``collect_tick`` handed the f64 quantiser, and rows the
+        #: delta dispatch read in passes as long as the capacity tier
+        #: (the velocity scan, the closure's key test)
+        self.quantised_rows = 0
+        self.dispatch_scan_rows = 0
 
         self._n = 0                     # slot high-water mark
         self._free: list[int] = []      # recycled slots below _n
@@ -481,7 +517,11 @@ class EntityPlane:
         self._pos[rows] = buf.pos[rows]
         hv = rows[buf.has_vel[rows]]
         if hv.size:
-            self._vel[hv] = buf.vel[hv]
+            vel = buf.vel[hv]
+            self._n_moving += int(
+                np.count_nonzero(_is_moving(vel))
+            ) - int(np.count_nonzero(_is_moving(self._vel[hv])))
+            self._vel[hv] = vel
         # a client update must win over the in-flight tick's writeback,
         # and its rows must ship to the device twin at this dispatch
         self._touched[rows] = True
@@ -659,6 +699,9 @@ class EntityPlane:
         self._pos[slot, 2] = p.z
         vel = _decode_velocity(ent.flex)
         if vel is not None:
+            self._n_moving += int(_is_moving(vel)) - int(
+                _is_moving(self._vel[slot])
+            )
             self._vel[slot] = vel
         self._touched[slot] = True
         self._device_dirty[slot] = True
@@ -720,9 +763,17 @@ class EntityPlane:
             self._pos[slot].astype(np.float64), self.cube_size
         )
         self._cube[slot] = cube
+        self._key[slot] = spatial_key(self._wid[slot], cube)
+        self._keys_changed()
         self._ref_add(
             int(self._wid[slot]), cube, int(self._pid[slot]),
         )
+
+    def _keys_changed(self) -> None:
+        """A slot's key or liveness was written: the sorted view is
+        stale, and the next delta dispatch tests the column itself."""
+        self._key_view = None
+        self._key_written = True
 
     def _ref_key(self, wid: int, cube, pid: int) -> tuple:
         return (wid, int(cube[0]), int(cube[1]), int(cube[2]), pid)
@@ -791,10 +842,12 @@ class EntityPlane:
             if not slots:
                 del self._peer_slots[pid]
         self._live[slot] = False
+        self._keys_changed()
         self._touched[slot] = False
         self._wid[slot] = -1
         self._pid[slot] = -1
         self._pos[slot] = _DEAD_POS
+        self._n_moving -= int(_is_moving(self._vel[slot]))
         self._vel[slot] = 0.0
         self._uuid_bytes[slot] = 0
         # the parked values must reach the device twin
@@ -902,6 +955,7 @@ class EntityPlane:
         self._wid = grow2(self._wid, -1, np.int32)
         self._pid = grow2(self._pid, -1, np.int32)
         self._cube = grow2(self._cube, 0, np.int64, 3)
+        self._key = grow2(self._key, 0, np.int64)
         self._live = grow2(self._live, False, bool)
         self._touched = grow2(self._touched, False, bool)
         self._uuid_bytes = grow2(self._uuid_bytes, 0, np.uint8, 16)
@@ -985,7 +1039,12 @@ class EntityPlane:
         """Launch one simulation tick from the host columns (event-loop
         thread; tick.sim.integrate span): fold the staged update
         columns, pick the delta or full path, launch the kernel (when
-        any device work is owed), and enqueue the D2H prefetch.
+        any device work is owed), and enqueue the D2H prefetch. A
+        delta tick reads the dirty window's rows and the closure they
+        name, through the kept key column; the passes it still makes
+        over the whole tier (the velocity scan while somebody moves,
+        the key test or sort after a key was written) are counted in
+        ``sim.dispatch_scan_rows``.
         Returns an opaque handle for ``collect_tick`` or None when idle
         / a previous tick is still in flight (sim ticks never stack:
         the writeback of tick N is input to tick N+1)."""
@@ -1073,22 +1132,34 @@ class EntityPlane:
         tb = np.float32(2.0 * self.bounds)  # the kernel's weak-f32 2*b
         b = np.float32(self.bounds)
         p = self._pos[slots] + self._vel[slots] * dt
-        p = np.where(p > b, tb - p, p)
-        p = np.where(p < -b, -tb - p, p)
+        # ONE reflection a tick, both sides judged before either is
+        # applied, as the kernel does (a row beyond three bounds comes
+        # back outside the other wall)
+        over, under = p > b, p < -b
+        p = np.where(over, tb - p, p)
+        p = np.where(under, -tb - p, p)
         return cube_coords_batch(p.astype(np.float64), self.cube_size)
 
     def _dispatch_tick_delta(self, cap: int, t0: float) -> dict | None:
         """Delta path: build the dirty-cube closure and launch the
         tick kernel over ONLY it, at a pow2 sub-tier. Returns None to
         fall back to the full path (cold cache, tier change, or churn
-        past ``delta_rebuild_threshold`` — the rebuild threshold)."""
+        past ``delta_rebuild_threshold`` — the rebuild threshold).
+        Dirty rows are the window's, plus the movers when the kept
+        count says there are any; the closure comes from
+        ``_closure_rows``. The handle carries what ``collect_tick``
+        needs to tell which rows can have a new cube."""
         if not self._have_last or self._last_cap != cap:
             self._note_delta_fallback("cold")
             return None
         live = self._live[:cap]
         n_live = int(np.count_nonzero(live))
-        moving = live & (self._vel[:cap] != 0.0).any(axis=1)
-        dirty = (self._window_dirty[:cap] & live) | moving
+        dirty = self._window_dirty[:cap] & live
+        if self._n_moving:
+            # somebody integrates: the scan that finds them is the one
+            # pass over the tier a world with movers still pays
+            dirty |= live & _is_moving(self._vel[:cap])
+            self._note_scan(cap)
         dirty_slots = np.flatnonzero(dirty)
         if dirty_slots.size == 0 and not self._window_dirty_cubes:
             # the world did not change: zero device work, pure replay
@@ -1107,9 +1178,7 @@ class EntityPlane:
         # dirty cubes: every cube a dirty entity occupies now or can
         # reach this tick, plus cubes vacated by removals
         wid_col = self._wid[:cap]
-        cube_col = self._cube[:cap]
-        parts = [spatial_keys(wid_col[dirty_slots],
-                              cube_col[dirty_slots], 0)]
+        parts = [self._key[dirty_slots]]
         if dirty_slots.size:
             parts.append(spatial_keys(
                 wid_col[dirty_slots], self._predict_cubes(dirty_slots), 0
@@ -1122,10 +1191,8 @@ class EntityPlane:
         dirty_keys = np.unique(np.concatenate(parts))
         # closure: every live entity in a dirty cube (a same-hash
         # collision only ADDS members — conservative, never wrong)
-        closure = live & np.isin(
-            spatial_keys(wid_col, cube_col, 0), dirty_keys
-        )
-        rows = np.flatnonzero(closure)
+        rows, scanned = self._closure_rows(live, dirty_keys)
+        self._note_scan(scanned)
         tier = max(_DELTA_MIN_TIER, _next_pow2(max(int(rows.size), 1)))
         if rows.size > threshold or tier >= cap:
             self._note_delta_fallback("closure")
@@ -1162,6 +1229,11 @@ class EntityPlane:
             "mode": "delta",
             "rows": rows,
             "dirty_keys": dirty_keys,
+            # what collect_tick needs to tell the rows that can have a
+            # new cube: the positions given (jnp.asarray copied them)
+            # and which closure rows were dirty at dispatch
+            "pos_in": pos_sub,
+            "dirty_in": dirty[rows],
             "pos": new_state.position,
             "targets": targets,
             "counts": counts,
@@ -1169,6 +1241,45 @@ class EntityPlane:
             "tier": tier,
             "t0": t0,
         }
+
+    def _note_scan(self, rows: int) -> None:
+        """Count rows a delta dispatch read in a tier-long pass."""
+        self.dispatch_scan_rows += rows
+        if self.metrics is not None:
+            self.metrics.inc("sim.dispatch_scan_rows", rows)
+
+    def _closure_rows(self, live: np.ndarray,
+                      dirty_keys: np.ndarray) -> tuple[np.ndarray, int]:
+        """The live slots whose kept key is one of ``dirty_keys``
+        (sorted, unique, not empty), ascending, and the rows read in
+        tier-long passes to find them. Through the sorted view while
+        it stands; a dispatch that finds it dropped sorts it again if
+        no key was written since the LAST dispatch (``cap`` rows read,
+        once), and otherwise tests the kept column with ``np.isin``
+        (``cap`` rows read, as every tick did)."""
+        cap = live.shape[0]
+        written, self._key_written = self._key_written, False
+        scanned = 0
+        if self._key_view is None:
+            if written:
+                return np.flatnonzero(
+                    live & np.isin(self._key[:cap], dirty_keys)
+                ), cap
+            slots = np.flatnonzero(live)
+            keys = self._key[slots]
+            order = np.argsort(keys, kind="stable")
+            self._key_view = (keys[order], slots[order])
+            scanned = cap
+        keys, slots = self._key_view
+        lo = np.searchsorted(keys, dirty_keys, "left")
+        counts = np.searchsorted(keys, dirty_keys, "right") - lo
+        ends = np.cumsum(counts)
+        # the runs [lo, lo + counts) laid end to end
+        rows = slots[
+            np.arange(ends[-1]) + np.repeat(lo - (ends - counts), counts)
+        ]
+        rows.sort()
+        return rows, scanned
 
     def precompile(self, max_compiles: int = 32) -> dict:
         """Boot-time shape precompilation for the sim kernels (the
@@ -1240,10 +1351,16 @@ class EntityPlane:
         """Wait out the device and fetch results (worker thread;
         tick.sim.knn span). The three fetches below are the sim tick's
         designated device→host sync points; everything else stays
-        vectorized. Also re-quantizes the integrated positions to
-        cubes host-side in f64 — the AUTHORITATIVE quantizer, so the
-        index coupling follows the golden grid, not the device's f32
-        twin."""
+        vectorized. Also re-quantizes integrated positions to cubes
+        host-side in f64 — the AUTHORITATIVE quantizer, so the index
+        coupling follows the golden grid, not the device's f32 twin.
+        A full tick quantises the tier. A delta tick quantises the
+        closure rows that were dirty at dispatch or came back with
+        another position than they were given (``quantised``: indices
+        into ``rows``; ``cubes`` is aligned with it): a row that was
+        not dirty holds the position its registered cube was
+        quantised from, and the same position has the same cube.
+        ``quantised_rows`` feeds the counter ``sim.quantised_rows``."""
         t0 = time.perf_counter()
         mode = handle.get("mode", "full")
         if mode == "replay":
@@ -1263,7 +1380,22 @@ class EntityPlane:
         cpu_clock = tracer.cpu_clock if tracer is not None else None
         if cpu_clock is not None:
             t_fetched, cpu0 = time.perf_counter(), cpu_clock()
-        cubes = cube_coords_batch(pos.astype(np.float64), self.cube_size)
+        moved_pos = pos
+        if mode == "delta":
+            # only a row that was dirty at dispatch, or that the device
+            # handed back changed (a mover, a reflection at the bounds,
+            # a NaN: `!=` counts it), can have a new cube: every other
+            # closure row holds the position its registered cube was
+            # quantised from. The pads are never read.
+            n = int(handle["rows"].size)
+            quantised = np.flatnonzero(
+                handle["dirty_in"]
+                | (pos[:n] != handle["pos_in"][:n]).any(axis=1)
+            )
+            moved_pos = pos[quantised]
+        cubes = cube_coords_batch(
+            moved_pos.astype(np.float64), self.cube_size
+        )
         if cpu_clock is not None:
             cpu_ms = (cpu_clock() - cpu0) / 1e6
         t1 = time.perf_counter()
@@ -1271,6 +1403,7 @@ class EntityPlane:
             "mode": mode,
             "pos": pos, "targets": targets, "counts": counts,
             "cubes": cubes, "cap": handle["cap"], "knn_ms": (t1 - t0) * 1e3,
+            "quantised_rows": int(cubes.shape[0]),
         }
         if cpu_clock is not None:
             out["knn_fetch_ms"] = (t_fetched - t0) * 1e3
@@ -1278,6 +1411,7 @@ class EntityPlane:
         if mode == "delta":
             out["rows"] = handle["rows"]
             out["dirty_keys"] = handle["dirty_keys"]
+            out["quantised"] = quantised
         return out
 
     def abort_tick(self) -> None:
@@ -1385,7 +1519,10 @@ class EntityPlane:
         self.frames += len(pairs)
         self.last_apply_ms = (time.perf_counter() - t0) * 1e3
         self.last_knn_ms = result["knn_ms"]
+        quantised_rows = result.get("quantised_rows", 0)  # replay: none
+        self.quantised_rows += quantised_rows
         if self.metrics is not None:
+            self.metrics.inc("sim.quantised_rows", quantised_rows)
             self.metrics.observe_ms("sim.knn_ms", result["knn_ms"])
             self.metrics.observe_ms("sim.apply_ms", self.last_apply_ms)
             if "knn_fetch_ms" in result:    # collect_tick: the CPU clock
@@ -1440,11 +1577,12 @@ class EntityPlane:
         (replay) theirs. Returns ``(pos, targets, counts,
         moved_slots)`` for the shared apply tail — ``pos`` is the
         device-integrated frame position column, exactly what the full
-        path hands it."""
+        path hands it. Every written-back row takes its position; only
+        the rows ``collect_tick`` quantised are compared with their
+        registered cube and churned — the others cannot have moved."""
         rows = result["rows"]
         n = int(rows.size)
         pos_sub = result["pos"][:n]
-        cubes_sub = result["cubes"][:n]
         self._last_targets[rows] = result["targets"][:n]
         self._last_counts[rows] = result["counts"][:n]
         self._last_pos[rows] = pos_sub
@@ -1457,10 +1595,16 @@ class EntityPlane:
         wb = self._live[rows] & ~self._touched[rows]
         wrows = rows[wb]
         self._pos[wrows] = pos_sub[wb]
-        moved = np.any(cubes_sub[wb] != self._cube[wrows], axis=1)
-        moved_slots = wrows[moved]
+        # churn among the rows collect_tick quantised: the others came
+        # back with the position their registered cube stands for
+        quantised = result["quantised"]
+        qwb = wb[quantised]
+        qrows = rows[quantised[qwb]]
+        qcubes = result["cubes"][qwb]
+        moved = np.any(qcubes != self._cube[qrows], axis=1)
+        moved_slots = qrows[moved]
         if moved_slots.size:
-            self._apply_churn(moved_slots, cubes_sub[wb][moved])
+            self._apply_churn(moved_slots, qcubes[moved])
 
         # defensive closure audit: every written-back row must land in
         # a cube the dispatch predicted dirty — unreachable inside the
@@ -1468,11 +1612,8 @@ class EntityPlane:
         # clean cube replayed stale neighbors, so it forces the next
         # tick onto the full path instead of trusting the replay state
         if moved_slots.size:
-            landed = spatial_keys(
-                self._wid[moved_slots], cubes_sub[wb][moved], 0
-            )
             bad = int(np.count_nonzero(
-                ~np.isin(landed, result["dirty_keys"])
+                ~np.isin(self._key[moved_slots], result["dirty_keys"])
             ))
             if bad:
                 self.delta_mispredicts += bad
@@ -1502,6 +1643,8 @@ class EntityPlane:
         wids = self._wid[moved_slots]
         pids = self._pid[moved_slots]
         self._cube[moved_slots] = new_cubes
+        self._key[moved_slots] = spatial_keys(wids, new_cubes, 0)
+        self._keys_changed()
         self.index_moves += int(moved_slots.size)
 
         # refcount transitions (O(churn) host work, like any index
@@ -1696,11 +1839,20 @@ class EntityPlane:
             "delta_recomputed": self.delta_recomputed,
             "delta_fallbacks": self.delta_fallbacks,
             "delta_mispredicts": self.delta_mispredicts,
+            "quantised_rows": self.quantised_rows,
+            "dispatch_scan_rows": self.dispatch_scan_rows,
             "last_integrate_ms": round(self.last_integrate_ms, 3),
             "last_knn_ms": round(self.last_knn_ms, 3),
             "last_apply_ms": round(self.last_apply_ms, 3),
             "last_churn": self.last_churn,
         }
+
+
+def _is_moving(vel):
+    """Does the tick integrate this velocity (``[..., 3]``)? The one
+    rule the mover count and the delta dispatch's scan share: any
+    component that is not zero — a NaN counts."""
+    return (np.asarray(vel) != 0.0).any(axis=-1)
 
 
 def _decode_velocity(flex: bytes | None):
