@@ -44,7 +44,7 @@ import numpy as np
 
 ROOT = Path(__file__).resolve().parent
 CUBE = 16                 # the server's default sub_region_size
-OCCUPANCY_CAP = 256       # bench.py's config-5 crowd: Zipf, capped
+OCCUPANCY_CAP = 256       # the north-star crowd: Zipf, capped
 SPAN = 800.0              # crowd lives in ±SPAN per axis
 TICK = 0.05
 #: a cold 1M-row boot compiles for five minutes; none has come near this
@@ -109,7 +109,7 @@ def build_native() -> None:
 def zipf_cube_counts(rng, n: int) -> tuple[np.ndarray, np.ndarray]:
     """→ (cell ids, occupancy) of a Zipf(1)-popularity crowd over the
     cube grid, occupancy capped and the excess waterfilled down the
-    ranking — the shape of bench.py's ``make_positions``."""
+    ranking (BASELINE configs[4]'s crowd)."""
     cells_axis = int(SPAN * 2 / CUBE)
     n_ranked = min(max(n // 4, 1024), cells_axis ** 3)
     cell_ids = rng.permutation(cells_axis ** 3)[:n_ranked]

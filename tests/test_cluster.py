@@ -168,6 +168,21 @@ def _maybe(fn):
         return None
 
 
+async def _connect(port: int, peers: list, **kw) -> ZmqPeer:
+    """A peer through the router, kept in ``peers`` for the teardown;
+    retried while the tier is still binding."""
+    last = None
+    for _ in range(100):
+        try:
+            peer = await ZmqPeer.connect(port, **kw)
+            peers.append(peer)
+            return peer
+        except Exception as exc:
+            last = exc
+            await asyncio.sleep(0.05)
+    raise AssertionError(f"client could not connect: {last!r}")
+
+
 async def _drain_cluster_e2e(tmp_path):
     config = _cluster_config(tmp_path)
     world_map = WorldMap(2)
@@ -181,19 +196,10 @@ async def _drain_cluster_e2e(tmp_path):
     peers: list[ZmqPeer] = []
     try:
         async def connect(peer_uuid, token=None):
-            last = None
-            for _ in range(100):
-                try:
-                    peer = await ZmqPeer.connect(
-                        config.zmq_server_port, peer_uuid=peer_uuid,
-                        token=token,
-                    )
-                    peers.append(peer)
-                    return peer
-                except Exception as exc:
-                    last = exc
-                    await asyncio.sleep(0.05)
-            raise AssertionError(f"client could not connect: {last!r}")
+            return await _connect(
+                config.zmq_server_port, peers,
+                peer_uuid=peer_uuid, token=token,
+            )
 
         a = await connect(uuid_a)
         b = await connect(uuid_b)
@@ -603,6 +609,136 @@ async def _drain_cluster_e2e(tmp_path):
 def test_cluster_end_to_end(tmp_path):
     """The ISSUE 14 acceptance path, one cluster boot end to end."""
     asyncio.run(asyncio.wait_for(_drain_cluster_e2e(tmp_path), 300))
+
+
+async def _shed_audit():
+    n_shards = 2
+    config = Config(
+        store_url="memory://",
+        http_enabled=False, ws_enabled=False,
+        zmq_server_host="127.0.0.1",
+        zmq_server_port=_port_block(n_shards + 1),
+        spatial_backend="cpu", tick_interval=0.02,
+        max_batch=32, overload="on",
+        supervisor_backoff=0.005,
+        cluster_shards=n_shards,
+    )
+    world_map = WorldMap(n_shards)
+    worlds = [
+        _world_for_shard(world_map, i, "scale") for i in range(n_shards)
+    ]
+    runtime = ClusterRuntime(config)
+    await runtime.start()
+    peers: list[ZmqPeer] = []
+    try:
+        async def connect(**kw):
+            return await _connect(config.zmq_server_port, peers, **kw)
+
+        flooders = [
+            (await connect(), worlds[i % n_shards])
+            for i in range(2 * n_shards)
+        ]
+        for client, world in flooders:
+            await client.send(Message(
+                instruction=Instruction.AREA_SUBSCRIBE,
+                world_name=world, position=POS,
+            ))
+        # receiver homed on shard 0, world owned by shard 1: every one
+        # of its frames crosses the 1→0 ring
+        rx = await connect(peer_uuid=_uuid_for_shard(world_map, 0))
+        tx = await connect(peer_uuid=_uuid_for_shard(world_map, 1))
+        for c in (rx, tx):
+            await c.send(Message(
+                instruction=Instruction.AREA_SUBSCRIBE,
+                world_name=worlds[1], position=POS,
+            ))
+        await asyncio.sleep(0.3)
+
+        def router_shed() -> int:
+            return runtime.metrics.snapshot()["counters"].get(
+                "cluster.router_shed_local", 0
+            )
+
+        async def flood(client, world, pace_s, until) -> int:
+            sent = 0
+            while not until():
+                for _ in range(16):
+                    await client.send(Message(
+                        instruction=Instruction.LOCAL_MESSAGE,
+                        world_name=world, position=POS, parameter="load",
+                    ))
+                    sent += 1
+                await asyncio.sleep(pace_s)
+            return sent
+
+        # balanced: every flooder bursts its own shard's world, and the
+        # pair sends across the ring
+        end = time.monotonic() + 1.0
+        offered = sum(await asyncio.gather(
+            *(flood(c, w, 0.002, lambda: time.monotonic() > end)
+              for c, w in flooders),
+            flood(tx, worlds[1], 0.05, lambda: time.monotonic() > end),
+        ))
+        # hotspot: the whole fleet converges on shard 0's world until
+        # it REJECTs and the refusals move to the router tier
+        end = time.monotonic() + 20.0
+        offered += sum(await asyncio.gather(*(
+            flood(c, worlds[0], 0.001,
+                  lambda: router_shed() > 0 or time.monotonic() > end)
+            for c, _ in flooders
+        )))
+        assert router_shed() > 0, (
+            "the router tier never shed for a drowning shard"
+        )
+
+        def books():
+            counters = [
+                runtime.supervisor.shard_state(i).get("counters", {})
+                for i in range(n_shards)
+            ]
+            arrived = sum(
+                c.get("messages.local_message", 0) for c in counters
+            )
+            shed_shard = sum(
+                c.get("overload.shed_local", 0)
+                + c.get("overload.drop_oldest", 0)
+                for c in counters
+            )
+            return arrived, shed_shard, router_shed()
+
+        # shard counters ride ~1 s state pushes: the books close when
+        # the last push has landed, and never if a message was lost
+        deadline = time.monotonic() + 40
+        while True:
+            arrived, shed_shard, at_router = books()
+            if offered == arrived + at_router or time.monotonic() > deadline:
+                break
+            await asyncio.sleep(0.1)
+        admitted = arrived - shed_shard
+        assert offered == admitted + shed_shard + at_router, (
+            offered, arrived, shed_shard, at_router
+        )
+        assert admitted > 0
+        latency = runtime.metrics.snapshot()["latency"]
+        # the federated histograms the shards close at socket-write-
+        # complete advanced, the cross-shard one among them
+        assert (latency.get("cluster.e2e_ms") or {}).get("count", 0) > 0
+        assert (latency.get("cluster.xshard_ms") or {}).get("count", 0) > 0
+    finally:
+        for peer in peers:
+            try:
+                peer.close()
+            except Exception:
+                pass
+        await runtime.stop()
+
+
+def test_cluster_shed_audit_is_exact_across_both_tiers():
+    """A hotspot storm behind the router: every LocalMessage offered is
+    admitted by a shard, shed by a shard's governor or shed at the
+    router for it: offered == admitted + shed-at-shard + shed-at-router,
+    to the message (the router's forward leg loses nothing)."""
+    asyncio.run(asyncio.wait_for(_shed_audit(), 180))
 
 
 # ---------------------------------------------------------------------

@@ -409,6 +409,12 @@ def test_ws_handoff_delivers_through_worker():
             c1 = await WsClient.connect(server.config.ws_port)
             c2 = await WsClient.connect(server.config.ws_port)
             for c in (c1, c2):
+                # connect() returns once the handshake echo is SENT; the
+                # server inserts the peer after it has read that echo
+                deadline = asyncio.get_event_loop().time() + 10
+                while server.peer_map.get(c.uuid) is None:
+                    assert asyncio.get_event_loop().time() < deadline
+                    await asyncio.sleep(0.005)
                 assert server.peer_map.get(c.uuid).shard is not None
                 await c.send(Message(
                     instruction=Instruction.AREA_SUBSCRIBE,

@@ -202,6 +202,50 @@ def test_delta_query_parity_under_randomized_churn():
     assert bes[1].delta_reused == 0 and bes[1].delta_recomputed == 0
 
 
+def test_low_churn_pass_replays_the_clean_majority():
+    """The steady regime delta ticks exist for: the same batch tick
+    over tick with ~1 % of its query rows fresh and a couple of
+    subscriptions moving. More than four rows in five replay, and every
+    tick equals the full recompute lane for lane."""
+    rng = np.random.default_rng(4242)
+    n, m, warm, ticks = 2048, 256, 2, 10
+    bes = [TpuSpatialBackend(16), TpuSpatialBackend(16)]
+    assert bes[0].configure_delta_ticks("auto")
+    peers = [uuid.UUID(int=i + 1) for i in range(n)]
+    pos = rng.uniform(-400, 400, (n, 3))
+    cubes = cube_coords_batch(pos, 16)
+    for be in bes:
+        be.bulk_add_subscriptions("w", peers, cubes)
+        be.flush()
+    q_pos = pos[rng.integers(0, n, m)].copy()
+    sid = np.full(m, -1, np.int32)
+    reuse = []
+    for tick in range(warm + ticks):
+        rows = np.unique(rng.integers(0, m, max(2, m // 100)))
+        q_pos[rows] = pos[rng.integers(0, n, rows.size)]
+        mv = np.unique(rng.integers(0, n, 2))
+        new_cubes = cube_coords_batch(
+            rng.uniform(-400, 400, (mv.size, 3)), 16
+        )
+        movers = [peers[i] for i in mv]
+        for be in bes:
+            be.bulk_move_subscriptions(
+                "w", movers, cubes[mv], movers, new_cubes
+            )
+        cubes[mv] = new_cubes
+        cols = _staged(q_pos, sid, m)
+        outs = [
+            be.collect_local_batch(be.dispatch_staged_batch(*cols))
+            for be in bes
+        ]
+        assert outs[0] == outs[1], f"tick {tick} diverged"
+        if tick >= warm:
+            stats = bes[0].last_delta_stats
+            assert stats["fallback"] == ""
+            reuse.append(stats["reused"] / stats["batch"])
+    assert np.mean(reuse) > 0.8, reuse
+
+
 def test_delta_off_is_pinned_to_the_pre_delta_pipeline():
     """--delta-ticks off: the handle shapes, counters and coherence
     state are untouched — byte-for-byte the old dispatch pipeline."""

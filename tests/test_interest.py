@@ -1278,6 +1278,84 @@ def test_churn_property_replay_matches_ground_truth(delta_ticks):
     assert mgr.resyncs > 0
 
 
+def test_a_mostly_static_world_costs_a_fraction_of_the_broadcast_bytes():
+    """What interest management is for: a static majority in one cube
+    and a moving minority, the same ticks served twice through real
+    ``deliver_batch``. Off re-sends every visible entity to every
+    watcher every tick; on sends each watcher what changed. The
+    watchers' replayed state equals the server's ledger, no delta is
+    refused, and the bytes delivered a tick fall at least five times."""
+    import asyncio
+
+    from tests.test_entity_sim import ent_msg, make_plane, vel_flex
+    from worldql_server_tpu.engine.peers import Peer, PeerMap
+    from worldql_server_tpu.protocol.types import (
+        Entity, Instruction, Vector3,
+    )
+
+    rng = np.random.default_rng(1813)
+    peers = [uuid.UUID(int=i + 1) for i in range(4)]
+    static = [
+        [(uuid.UUID(int=100 * (p + 1) + i), rng.uniform(4, 12, 3))
+         for i in range(4)]
+        for p in range(len(peers))
+    ]
+    movers = [(uuid.UUID(int=9000 + i), rng.uniform(6, 10, 3))
+              for i in range(2)]
+    warm, ticks = 3, 12
+
+    async def serve(interest: bool):
+        _, plane = make_plane(k=8)
+        mgr = None
+        if interest:
+            mgr = plane.interest = InterestManager()
+        peer_map = PeerMap()
+        inboxes = {p: [] for p in peers}
+        for p in peers:
+            async def send_raw(data, inbox=inboxes[p]):
+                m = deserialize_message(data)
+                if m.instruction == Instruction.LOCAL_MESSAGE:
+                    inbox.append(m)
+            await peer_map.insert(Peer(p, "loopback", send_raw, "test"))
+        for p, ents in zip(peers, static):
+            plane.ingest(ent_msg(p, [
+                Entity(uuid=e, position=Vector3(*xyz), world_name="w")
+                for e, xyz in ents
+            ]))
+        plane.ingest(ent_msg(peers[0], [
+            Entity(uuid=e, position=Vector3(*xyz), world_name="w",
+                   flex=vel_flex(1.0, 0.5))
+            for e, xyz in movers
+        ]))
+        bytes0 = 0
+        for t in range(warm + ticks):
+            if t == warm:
+                bytes0 = peer_map.bytes_delivered
+            handle = plane.dispatch_tick()
+            await peer_map.deliver_batch(
+                plane.apply(plane.collect_tick(handle))
+            )
+        return (peer_map.bytes_delivered - bytes0) / ticks, inboxes, \
+            mgr, plane
+
+    off, off_inboxes, _, _ = asyncio.run(serve(False))
+    on, on_inboxes, mgr, plane = asyncio.run(serve(True))
+    assert all(off_inboxes[p] for p in peers) and off > 0
+    deltas = 0
+    for p in peers:
+        rc = ReplayClient()
+        for m in on_inboxes[p]:
+            assert rc.apply(m)
+        s = rc.stats()
+        assert s["deltas_refused"] == 0 and s["gaps_seen"] == 0
+        deltas += s["deltas_applied"]
+        ledger = ledger_of(mgr, plane, p)
+        assert rc.snapshot()["w"] == ledger
+        assert len(ledger) > 1
+    assert deltas > 0, "movement never rode a delta frame"
+    assert off >= 5.0 * on, (off, on)
+
+
 def test_churn_ledger_equals_visible_set_without_lod():
     """The ledger-vs-targets cross-check the property above leans on:
     with LOD off and no bandwidth cap, the manager's committed state

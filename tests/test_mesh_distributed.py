@@ -94,7 +94,7 @@ def test_two_process_distributed_mesh_parity():
             "HOME": os.environ.get("HOME", "/root"),
             # JAX_PLATFORMS (plural) is load-bearing: without it a
             # TPU-less host with libtpu installed hangs enumerating
-            # the plugin (see tests/test_bench.py ENV)
+            # the plugin, and the child idles out its whole timeout
             "JAX_PLATFORMS": "cpu",
             "JAX_PLATFORM_NAME": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",

@@ -300,6 +300,42 @@ def test_gauge_floor_objective_ignores_unmeasured_samples():
     assert obj.level == OK and obj.burn_fast == 0.0
 
 
+def test_default_set_is_judged_whole_and_a_healthy_registry_burns_nothing():
+    """The registry a server boots with, on the series a healthy
+    server feeds: every objective gets a verdict at every evaluation
+    (a latency objective on live observations, not an empty series)
+    and none enters BURNING."""
+    clock = [0.0]
+    interval, specs = load_objectives(None)
+    metrics = Metrics()
+    metrics.gauge("deliveries_per_s_per_core", lambda: 50_000.0)
+    eng = SloEngine(
+        metrics, specs, eval_interval_s=interval, clock=lambda: clock[0],
+    )
+    latencies = [
+        o["series"] for o in specs if o["kind"] == "latency_p99"
+    ]
+    evals = 8
+    for t in range(evals):
+        clock[0] = float(t)
+        for series in latencies:
+            for _ in range(20):
+                metrics.observe_ms(series, 1.0)
+        eng.evaluate()
+    status = eng.status()
+    assert status["evals"] == evals and status["state"] == "ok"
+    assert set(status["objectives"]) == {
+        o["name"] for o in DEFAULT_OBJECTIVES
+    }
+    for name, verdict in status["objectives"].items():
+        traj = eng.trajectory(name)
+        assert len(traj) == evals, name
+        assert all(e["level"] == OK for e in traj), (name, traj)
+        if verdict["kind"] == "latency_p99":
+            assert verdict["bad_fraction"] == 0.0, name
+    assert eng.healthz() == {"state": "ok", "burning": []}
+
+
 # ---------------------------------------------------------------------------
 # unit: incident recorder debounce + bounded ring
 

@@ -6,18 +6,22 @@ import threading
 import time
 import uuid
 
+import numpy as np
 import pytest
 
 from worldql_server_tpu.engine.config import Config
 from worldql_server_tpu.engine.metrics import Metrics
 from worldql_server_tpu.engine.peers import Peer, PeerMap
 from worldql_server_tpu.engine.router import Router
+from worldql_server_tpu.engine.staging import QueryStaging
 from worldql_server_tpu.engine.ticker import TickBatcher
 from worldql_server_tpu.observability.spans import Tracer
+from worldql_server_tpu.parallel import ShardedTpuSpatialBackend, make_fanout_mesh
 from worldql_server_tpu.protocol import deserialize_message
 from worldql_server_tpu.protocol.types import Instruction, Message, Vector3
 from worldql_server_tpu.robustness import failpoints
 from worldql_server_tpu.robustness.overload import OverloadGovernor
+from worldql_server_tpu.spatial.backend import to_cube
 from worldql_server_tpu.spatial.cpu_backend import CpuSpatialBackend
 from worldql_server_tpu.spatial.tpu_backend import TpuSpatialBackend
 from worldql_server_tpu.storage.memory_store import MemoryRecordStore
@@ -28,11 +32,15 @@ def run(coro):
 
 
 class Harness:
-    def __init__(self, backend_cls, interval=0.03, max_batch=16_384, **kw):
+    def __init__(self, backend_cls, interval=0.03, max_batch=16_384,
+                 staged=False, **kw):
         config = Config()
         self.backend = backend_cls(config.sub_region_size)
         self.store = MemoryRecordStore(config)
         self.peer_map = PeerMap(on_remove=self.backend.remove_peer)
+        if staged and self.backend.supports_staged_dispatch():
+            # as engine/server.py binds it: by the backend's capability
+            kw["staging"] = QueryStaging(self.backend)
         self.ticker = TickBatcher(
             self.backend, self.peer_map, interval, max_batch=max_batch, **kw
         )
@@ -184,6 +192,330 @@ def test_mutations_between_ticks_apply_before_flush():
         assert [m.parameter for m in h.locals_for(b)] == ["m"]
 
     run(scenario())
+
+
+# region: an index that changes under the tick (ROADMAP M8)
+#
+# The rule the served path keeps: AreaSubscribe / AreaUnsubscribe write
+# the index at receipt, a LocalMessage waits for its flush, so a
+# message is OWED to the subscribers its cube has when its flush
+# starts, not when it arrived. A reference replays index writes and
+# flushes in the server's order; CpuSpatialBackend behind the same
+# router and ticker is that reference here.
+
+
+def cube_pos(k: int) -> Vector3:
+    return Vector3(16.0 * k + 5.0, 5.0, 5.0)
+
+
+class Churn:
+    """One harness replaying a script: peers by index, every message
+    labelled by its order, each flush's deliveries taken down as
+    {label: [recipient, ...]} and, a peer, in the order they came."""
+
+    def __init__(self, harness: Harness):
+        self.h = harness
+        self.peers: list[uuid.UUID] = []
+        self.sent = 0
+        self.seen: list[int] = []
+        self.flushes: list[tuple[dict, list]] = []
+
+    async def join(self, n: int) -> None:
+        for _ in range(n):
+            self.peers.append(await self.h.add_peer())
+            self.seen.append(0)
+
+    async def sub(self, i: int, k: int) -> None:
+        await self.h.subscribe(self.peers[i], cube_pos(k))
+
+    async def unsub(self, i: int, k: int) -> None:
+        await self.h.router.handle_message(Message(
+            instruction=Instruction.AREA_UNSUBSCRIBE,
+            sender_uuid=self.peers[i], world_name="world",
+            position=cube_pos(k),
+        ))
+
+    async def msg(self, i: int, k: int) -> None:
+        await self.h.local(self.peers[i], cube_pos(k), f"m{self.sent}")
+        self.sent += 1
+
+    async def drop(self, i: int) -> None:
+        await self.h.peer_map.remove(self.peers[i])
+
+    def bulk_move(self, i: int, old: range, new: range) -> None:
+        """Every cube of ``old`` to its partner in ``new`` in one call
+        where the backend has one, else a subscription at a time, as
+        entities/plane.py falls back."""
+        backend, who = self.h.backend, self.peers[i]
+        bulk = getattr(backend, "bulk_move_subscriptions", None)
+        if bulk is None:
+            for k in old:
+                assert backend.remove_subscription("world", who, cube_pos(k))
+            for k in new:
+                assert backend.add_subscription("world", who, cube_pos(k))
+            return
+        cubes = [
+            np.array([to_cube(cube_pos(k), backend.cube_size) for k in ks])
+            for ks in (old, new)
+        ]
+        assert bulk(
+            "world", [who] * len(old), cubes[0], [who] * len(new), cubes[1]
+        ) == (len(old), len(new))
+
+    def wait_compaction(self) -> None:
+        wait = getattr(self.h.backend, "wait_compaction", None)
+        if wait is not None:
+            wait()
+
+    async def flush(self) -> None:
+        await self.h.ticker.flush()
+        by_message: dict[str, list[int]] = {}
+        by_peer = []
+        for i, peer in enumerate(self.peers):
+            got = [m.parameter for m in self.h.locals_for(peer)]
+            fresh, self.seen[i] = got[self.seen[i]:], len(got)
+            by_peer.append(fresh)
+            for label in fresh:
+                by_message.setdefault(label, []).append(i)
+        self.flushes.append((by_message, by_peer))
+
+
+async def churn_subscribe_after(c: Churn):
+    await c.join(5)
+    await c.sub(0, 0)
+    await c.sub(1, 0)
+    await c.msg(0, 0)
+    await c.sub(2, 0)           # after the message, before its flush
+    await c.flush()
+    await c.msg(0, 0)
+    await c.msg(1, 1)           # nobody there yet
+    await c.sub(3, 0)
+    await c.sub(4, 1)
+    await c.flush()
+    await c.msg(1, 0)
+    await c.msg(0, 1)
+    await c.flush()
+
+
+async def churn_unsubscribe_after(c: Churn):
+    await c.join(4)
+    for i in range(4):
+        await c.sub(i, 0)
+    await c.msg(0, 0)
+    await c.unsub(1, 0)         # after the message, before its flush
+    await c.flush()
+    await c.msg(0, 0)
+    await c.unsub(2, 0)
+    await c.flush()
+    await c.msg(0, 0)
+    await c.flush()
+    await c.msg(0, 0)
+    await c.unsub(3, 0)         # the cube's last listener
+    await c.flush()
+
+
+async def churn_move_between_messages(c: Churn):
+    await c.join(4)
+    for i in range(3):
+        await c.sub(i, 0)
+    await c.sub(3, 1)
+    for _ in range(3):
+        for src, dst in ((0, 1), (1, 0)):
+            await c.msg(0, src)
+            await c.unsub(1, src)   # peer 1 moves between two messages
+            await c.sub(1, dst)     # of one tick
+            await c.msg(0, src)
+            await c.msg(3, dst)
+            await c.msg(2, dst)
+        await c.flush()
+    await c.unsub(1, 0)
+    await c.sub(1, 1)
+    await c.msg(0, 0)
+    await c.msg(3, 1)
+    await c.flush()
+
+
+async def churn_same_signature(c: Churn):
+    """The same (sender, cube) every flush: a device backend may replay
+    it from its reuse cache only while the cube stands unchanged."""
+    await c.join(5)
+    for i in range(3):
+        await c.sub(i, 0)
+    await c.sub(4, 1)
+
+    async def tick():
+        await c.msg(0, 0)
+        await c.msg(0, 1)       # a clean cube beside the changing one
+        await c.flush()
+
+    await tick()
+    await tick()                # unchanged: a replay is right
+    await c.sub(3, 0)
+    await tick()                # one more listener
+    await c.unsub(1, 0)
+    await tick()                # one fewer
+    await c.unsub(2, 0)
+    await c.sub(2, 0)           # left and came back: the same set
+    await tick()
+    await tick()
+
+
+async def churn_bulk_move(c: Churn):
+    await c.join(3)
+    for k in range(64):
+        await c.sub(1, k)
+    await c.sub(2, 0)
+    await c.sub(2, 100)
+
+    async def tick():
+        for k in (0, 63, 100, 163):
+            await c.msg(0, k)
+
+    await tick()
+    await c.flush()
+    await tick()
+    c.bulk_move(1, range(64), range(100, 164))    # messages queued
+    await tick()
+    await c.flush()
+    await tick()
+    await c.flush()
+    c.bulk_move(1, range(100, 132), range(32))    # half of it back
+    await tick()
+    await c.msg(0, 31)
+    await c.msg(0, 131)
+    await c.msg(0, 132)
+    await c.flush()
+
+
+async def churn_remove_peer(c: Churn):
+    await c.join(4)
+    for i in range(3):
+        await c.sub(i, 0)
+    await c.sub(1, 1)
+    await c.msg(0, 0)
+    await c.msg(2, 1)
+    await c.drop(1)             # a recipient, its messages queued
+    await c.flush()
+    await c.msg(0, 0)
+    await c.msg(2, 1)
+    await c.flush()
+    await c.sub(3, 0)
+    await c.sub(3, 1)
+    await c.msg(0, 0)
+    await c.msg(2, 1)
+    await c.flush()
+
+
+async def churn_compaction(c: Churn):
+    """More index writes between two flushes than the delta log may
+    hold (the device backends are built with a threshold of 32): the
+    flush that takes the messages starts a base+delta fold, writes land
+    while it runs, a later flush swaps it in."""
+    await c.join(10)
+    for i in range(10):
+        await c.sub(i, 0)
+
+    async def tick():
+        for k in (0, 3, 7):
+            await c.msg(0, k)
+            await c.msg(9, k)
+
+    await tick()
+    await c.flush()
+    for i in range(10):
+        for k in range(1, 8):
+            await c.sub(i, k)
+    for i in range(5, 10):
+        await c.unsub(i, 0)
+    await tick()
+    await c.flush()             # 70 rows in the log: the fold starts
+    await c.unsub(2, 3)         # a write beside the fold in flight
+    await c.sub(5, 0)
+    await tick()
+    await c.flush()
+    c.wait_compaction()
+    await c.unsub(3, 7)
+    await tick()
+    await c.flush()             # the folded base is swapped in
+    await tick()
+    await c.flush()
+
+
+CHURN_SCRIPTS = {
+    "subscribe-after-message": churn_subscribe_after,
+    "unsubscribe-after-message": churn_unsubscribe_after,
+    "move-between-messages": churn_move_between_messages,
+    "same-signature-cube-changed": churn_same_signature,
+    "bulk-move-64-cubes": churn_bulk_move,
+    "remove-queued-recipient": churn_remove_peer,
+    "churn-forces-compaction": churn_compaction,
+}
+
+
+def served_backend(name: str, compact_threshold=None):
+    """What Harness builds its backend from: a device backend armed
+    as engine/server.py's build_backend arms it."""
+    if name == "cpu":
+        return CpuSpatialBackend
+
+    def make(cube_size):
+        if name == "sharded":
+            backend = ShardedTpuSpatialBackend(
+                cube_size, make_fanout_mesh(1, 4),
+                compact_threshold=compact_threshold,
+            )
+        else:
+            backend = TpuSpatialBackend(
+                cube_size, compact_threshold=compact_threshold
+            )
+        assert backend.configure_delta_ticks(Config().delta_ticks)
+        return backend
+
+    return make
+
+
+@pytest.mark.parametrize("pattern", list(CHURN_SCRIPTS))
+@pytest.mark.parametrize("backend", [
+    "cpu", "tpu", "tpu-list-path", "sharded",
+])
+def test_index_changes_under_the_tick_match_the_cpu_reference(
+    backend, pattern
+):
+    """Subscriptions that change while messages wait: every flush of
+    the script delivers, message by message and in every peer's order,
+    what a CPU index written and flushed in the same order delivers.
+    The device backends dispatch staged columns, as the server binds
+    them; ``tpu-list-path`` takes the object-list dispatch a desynced
+    staging window falls back to."""
+    script = CHURN_SCRIPTS[pattern]
+    threshold = 32 if pattern == "churn-forces-compaction" else None
+    staged = backend != "tpu-list-path"
+
+    async def replay(backend_cls):
+        churn = Churn(Harness(backend_cls, interval=60.0, staged=staged))
+        await script(churn)
+        return churn
+
+    async def scenario():
+        want = await replay(CpuSpatialBackend)
+        got = await replay(served_backend(backend, threshold))
+        assert len(got.flushes) == len(want.flushes) >= 3
+        for n, (g, w) in enumerate(zip(got.flushes, want.flushes)):
+            assert g == w, f"flush {n} of {pattern} on {backend}"
+        assert sum(len(f[0]) for f in want.flushes) > 0
+        if backend == "cpu":
+            return
+        device = got.h.backend
+        assert (device.staged_dispatches > 0) == staged
+        if pattern == "same-signature-cube-changed" and staged:
+            assert device.delta_reused > 0, "the reuse cache never replayed"
+        if pattern == "churn-forces-compaction":
+            assert device.compactions >= 1, "no base+delta fold ran"
+
+    run(scenario())
+
+
+# endregion
 
 
 def test_cancel_mid_flush_does_not_redeliver():

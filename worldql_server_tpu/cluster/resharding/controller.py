@@ -1,8 +1,8 @@
 """Autosharding: the router-side hot-shard watcher.
 
 Zipfian traffic pins one hot world to one shard no matter how large
-``--cluster-shards N`` is — bench's own zipf block lands ~60% of load
-in a few capped cubes. This controller closes the loop the manual
+``--cluster-shards N`` is — the north star's Zipf crowd lands ~60% of
+load in a few capped cubes. This controller closes the loop the manual
 ``POST /reshard`` surface leaves open: it watches the per-shard
 overload state the control channel already mirrors (the shard
 governors fold tick-wall/queue/shed pressure into their exported

@@ -22,7 +22,7 @@ REJECT) — so a drowning shard's refusals cost one decode here instead
 of a socket write, a queue slot and a decode there. Every router-side
 shed is counted per class (``cluster.router_shed_*``): offered ==
 forwarded + shed-at-router, and forwarded == admitted + shed-at-shard,
-the exact-accounting invariant bench config 11 gates.
+the exact-accounting invariant tests/test_cluster.py holds.
 
 The router is also the cluster's observability front door (ISSUE 15):
 every forward is stamped with a trace context (``tracectx.py`` —
@@ -37,7 +37,7 @@ pid lanes.
 
 ``ClusterRuntime`` composes the router with the shard-process
 supervisor — ``python -m worldql_server_tpu --cluster-shards N`` boots
-it; scenarios, bench config 11 and the e2e suite embed it.
+it; scenarios and the e2e suite embed it.
 """
 
 from __future__ import annotations
@@ -1021,9 +1021,9 @@ class ClusterRouter:
 
 class ClusterRuntime:
     """Supervisor + router composition: the thing ``--cluster-shards
-    N`` boots. Also embedded by the scenario engine, bench config 11
-    and the e2e suite (the router runs in the embedding process; the
-    shards are always real subprocesses)."""
+    N`` boots. Also embedded by the scenario engine and the e2e suite
+    (the router runs in the embedding process; the shards are always
+    real subprocesses)."""
 
     def __init__(self, config, metrics: Metrics | None = None):
         config.validate()

@@ -12,7 +12,7 @@ that counter staying at zero across the churn property is the proof.
 
 :class:`LegacyClient` consumes the pre-interest stream (one
 ``entity.frame`` per entity plus ``entity.remove``) into the same
-snapshot shape, so tests and the bench can assert byte-for-byte state
+snapshot shape, so tests can assert byte-for-byte state
 parity between ``--interest on`` and ``off``.
 """
 

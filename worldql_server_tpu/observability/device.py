@@ -103,8 +103,7 @@ def live_device_bytes() -> int:
 
 
 class DeviceTelemetry:
-    """Per-server device telemetry hub (one per WorldQLServer; the
-    bench builds its own around a bare backend)."""
+    """Per-server device telemetry hub (one per WorldQLServer)."""
 
     def __init__(self, metrics=None, tracer=None, backend=None):
         self.metrics = metrics

@@ -597,7 +597,7 @@ class OverloadGovernor:
         ShedMirror` acts on — the level it mirrors for router-side
         admission plus the shed counters that close the cluster-wide
         exact-accounting audit (offered == admitted + shed-at-router +
-        shed-at-shard, bench config 11)."""
+        shed-at-shard, tests/test_cluster.py)."""
         return {
             "level": self.level,
             "state": self._state,

@@ -4,8 +4,7 @@ First-class hostile workloads driving a real server over real ZeroMQ:
 ``CATALOG`` maps names to :class:`~.engine.Scenario` classes;
 :func:`run_scenario` produces one structured survival + SLO report.
 Consumed by ``python -m worldql_server_tpu.scenarios`` (CI scenario
-smoke), ``bench.py --config 10`` (the perf-gated suite record) and
-tests/test_scenarios.py.
+smoke) and tests/test_scenarios.py.
 """
 
 from .catalog import (

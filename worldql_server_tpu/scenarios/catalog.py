@@ -15,7 +15,7 @@ Four hostile workloads, each giving a different plane its adversary:
 Every scenario sizes itself per shape ("smoke" = 1-core CI seconds,
 "full" = a real box) and declares its survival + SLO checks; the
 runner (engine.py) turns them into one structured report consumed by
-the CLI, bench config 10 and the test suite.
+the CLI and the test suite.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Adversarial scenario engine (ROADMAP item 5b, ISSUE 12).
 
-Every bench config before this PR drove well-behaved synthetic load;
+Every workload before this PR drove well-behaved synthetic load;
 the governor, durability, session and entity planes had never met an
 adversary. A :class:`Scenario` here is a first-class, declarative
 hostile workload: it boots a REAL :class:`WorldQLServer` over real
@@ -9,13 +9,10 @@ a declared list of survival + SLO :class:`Check` s — no lost resumed
 state, bounded handshake p99, governor back to OK, exact shed
 accounting — producing one structured report.
 
-The same library serves three masters:
+The same library serves two masters:
 
 * ``python -m worldql_server_tpu.scenarios`` — operator/CI CLI
   (``--check`` exits non-zero on any failed check);
-* ``bench.py --config 10`` — the scenario suite as a bench record,
-  wired into the CI perf gate (``checks_failed`` is a gated leaf: one
-  newly failing scenario assertion fails the build);
 * pytest — tests/test_scenarios.py runs the smoke shapes directly.
 
 Shapes: every scenario sizes itself from ``shape`` ∈ {"smoke",
@@ -165,7 +162,7 @@ class Scenario:
 
     async def drive(self, ctx: ScenarioContext) -> dict:
         """Run the hostile workload; returns the SLO value dict the
-        checks (and the bench record) are computed from."""
+        checks are computed from."""
         raise NotImplementedError
 
     def checks(self, ctx: ScenarioContext, slo: dict) -> list[Check]:

@@ -173,10 +173,9 @@ def areamap_probe(n_subs: int, n_queries: int, cube_size: int = 16,
                   seed: int = 11) -> dict | None:
     """Reference-class CPU calibration (``wql_areamap_probe``): build
     a reference-shaped cube→peers hash map of ``n_subs`` rows and
-    resolve ``n_queries`` lookups against it, single native thread —
-    the ``vs_reference`` row in the bench JSON. None when the native
-    library predates the symbol (the bench row degrades to absent,
-    never wrong)."""
+    resolve ``n_queries`` lookups against it, single native thread.
+    None when the native library predates the symbol (absent, never
+    wrong)."""
     if _native is None or getattr(_native, "_areamap", None) is None:
         return None
     out = np.zeros(3, np.float64)
