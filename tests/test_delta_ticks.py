@@ -814,6 +814,419 @@ def test_non_pow2_cube_size_disables_entity_delta():
 
 # endregion
 
+# region: the splice compares before it writes (ISSUE 42)
+
+SPL_K = 8
+SPL_TICKS = 20
+_SPL_PEERS = [uuid.UUID(int=0x5000 + i) for i in range(5)]
+#: one compile a shape for every plane of every case: the planes of a
+#: trio close over the same static parameters
+_SPL_SHARED: dict = {}
+
+
+class _NamesTheClosure(EntityPlane):
+    """The plane as it was before ISSUE 42: a delta tick names its
+    whole closure to the interest manager."""
+
+    def _apply_delta(self, result):
+        owed = self._owed
+        mark = len(owed[0]) if owed is not None else 0
+        out = super()._apply_delta(result)
+        if owed is not None:
+            del owed[0][mark:]
+            owed[0].append(result["rows"])
+        return out
+
+
+def _raw_order(pl):
+    """The op's own answer for the plane's columns, recipients nearest
+    first: what the plane's tick sorts away."""
+    import jax
+
+    from worldql_server_tpu.ops.tick import EntityState, make_tick_fn
+
+    if "op" not in _SPL_SHARED:
+        _SPL_SHARED["op"] = jax.jit(make_tick_fn(
+            cube_size=16, k=SPL_K, dt=0.05, bounds=1000.0, pallas=False))
+    cap = pl._cap
+    _, targets, _ = _SPL_SHARED["op"](EntityState(
+        pl._pos[:cap].copy(), np.zeros((cap, 3), np.float32),
+        pl._wid[:cap].copy(), pl._pid[:cap].copy()))
+    return np.asarray(targets)
+
+
+class _Trio:
+    """Three planes fed the same wire, tick by tick: the plane as it is
+    (``new``), a twin that names every closure row to its manager as
+    the plane did before, and a reference that runs full ticks and
+    names nothing (``changed=None``: the whole scan). Ten cubes side by
+    side along x, six entities each, five owners, nobody moving."""
+
+    def __init__(self):
+        from worldql_server_tpu.interest import InterestManager, ReplayClient
+
+        def make(cls, mode):
+            pl = cls(TpuSpatialBackend(16), None, cube_size=16, k=SPL_K,
+                     dt=0.05, bounds=1000.0, delta_ticks=mode,
+                     metrics=Metrics())
+            pl._tick_fn = _SPL_SHARED.setdefault("tick", pl._tick_fn)
+            pl.interest = InterestManager(metrics=Metrics())
+            return pl
+
+        self.new = make(EntityPlane, "on")
+        self.twin = make(_NamesTheClosure, "on")
+        self.ref = make(EntityPlane, "off")
+        self.planes = (self.new, self.twin, self.ref)
+        self.clients = {p: ReplayClient() for p in _SPL_PEERS}
+        self.ids: dict = {}      # (cube, j) -> uuid
+        self.owner: dict = {}
+        self.pos: dict = {}
+        self.roster: list = []   # slots allocated or released, this tick
+        self.served = 0
+        for c in range(10):
+            for j in range(6):
+                self.register((c, j), (16.0 * c + 2 + 2 * j, 3.0 + j, 5.0),
+                              _SPL_PEERS[(c + j) % 5])
+
+    # -- the wire
+
+    def send(self, owner, entities, parameter=None):
+        for pl in self.planes:
+            pl.ingest(_ent_msg(owner, entities, parameter))
+
+    def register(self, name, xyz, owner, vel=None):
+        self.ids[name], self.owner[name] = uuid.uuid4(), owner
+        self.place(name, xyz, vel)
+        self.roster.append(self.new._slot_of[self.ids[name]])
+
+    def place(self, name, xyz, vel=None, planes=None):
+        self.pos[name] = np.asarray(xyz, np.float32)
+        ent = Entity(uuid=self.ids[name], world_name="w",
+                     position=Vector3(*(float(v) for v in xyz)),
+                     flex=_vel_flex(vel) if vel is not None else None)
+        for pl in planes or self.planes:
+            pl.ingest(_ent_msg(self.owner[name], [ent]))
+
+    def step(self, name, d=(0.125, 0.0, 0.0)):
+        self.place(name, self.pos[name] + np.asarray(d, np.float32))
+
+    def remove(self, name):
+        self.roster.append(self.new._slot_of[self.ids[name]])
+        self.send(self.owner[name], [Entity(uuid=self.ids[name])],
+                  parameter="entity.remove")
+        del self.pos[name]
+
+    # -- one tick of all three, held to each other
+
+    def tick(self, skip=False, between=None):
+        """Returns ``new``'s result and pairs; frames (bytes, order,
+        recipients) are the same from all three."""
+        out, result = [], None
+        for pl in self.planes:
+            handle = pl.dispatch_tick()
+            assert handle is not None
+            if between is not None:
+                between(pl)
+            res = pl.collect_tick(handle)
+            result = result or res
+            out.append(pl.apply(res, skip_frames=skip))
+        wires = [[(m.wire, list(to)) for m, to in pairs] for pairs in out]
+        assert wires[0] == wires[1], "the plane against its old self"
+        assert wires[0] == wires[2], "the plane against full ticks"
+        for m, to in out[0]:
+            for peer in to:
+                assert self.clients[peer].apply(m)
+        self.served += bool(out[0])
+        self.roster.clear()
+        return result, out[0]
+
+    def counters(self, pl):
+        return pl.interest.metrics.snapshot()["counters"]
+
+    def settle(self):
+        """The three managers hold the same ledgers, and they are what
+        a client that applied ``new``'s frames holds (as float32 bytes:
+        a NaN is a position here)."""
+        for peer in _SPL_PEERS:
+            held = [pl.interest.ledger(peer, pl._peer_ids[peer])
+                    for pl in self.planes]
+            assert held[0] == held[1] == held[2]
+            got = self.clients[peer].snapshot().get("w", {})
+            assert {k.bytes: np.asarray(v, np.float32).tobytes()
+                    for k, v in got.items()} == {
+                key: pos_b for key, (_w, pos_b) in held[0].items()}
+            stats = self.clients[peer].stats()
+            assert stats["deltas_refused"] == 0 and stats["gaps_seen"] == 0
+        for name in ("interest.rows_diffed", "interest.entries"):
+            assert self.counters(self.new)[name] \
+                == self.counters(self.twin)[name] \
+                == self.counters(self.ref)[name]
+
+
+def _spl_step_inside(w, t, seen):
+    for j in range(3):
+        w.step(((t + j) % 10, j))
+
+
+def _spl_order_only(w, t, seen):
+    """One entity a tick jumps inside its cube: its cube-mates' sets
+    stand, the order the op hands them in does not."""
+    cube, rng = t % 10, np.random.default_rng(t)
+    before = _raw_order(w.new)
+    w.place((cube, 0), (16.0 * cube + rng.integers(8, 120) / 8.0,
+                        rng.integers(8, 120) / 8.0, 5.0))
+
+    def after(result, mover=w.new._slot_of[w.ids[(cube, 0)]]):
+        now = _raw_order(w.new)
+        order_only = (before != now).any(axis=1) & (
+            np.sort(before, axis=1) == np.sort(now, axis=1)).all(axis=1)
+        order_only[mover] = False
+        assert np.isin(np.flatnonzero(order_only), result["rows"]).all()
+        seen["order_only_rows"] = seen.get("order_only_rows", 0) \
+            + int(order_only.sum())
+        if order_only.any() and result.get("mode") == "delta":
+            # the closure held them and the splice named the mover alone
+            assert seen["spliced_this_tick"] == 1
+    return {"after": after}
+
+
+def _spl_cube_crossing(w, t, seen):
+    if t % 2 == 0 and t // 2 < 9:
+        w.step((t // 2, 1), (16.0, 0.0, 0.0))     # into the next cube
+    w.step(((t + 5) % 10, 3))
+
+    def after(result):
+        seen["churn"] = seen.get("churn", 0) + w.new.last_churn
+    return {"after": after}
+
+
+def _spl_roster(w, t, seen):
+    if t % 3 == 0:
+        w.register(("new", t), (16.0 * (t % 10) + 9.5, 9.0, 9.0),
+                   _SPL_PEERS[t % 5])
+    elif t % 3 == 1:
+        w.remove(("new", t - 1))
+    if t % 6 == 5:                  # a slot released and taken at once
+        w.remove((t % 10, 2))
+        w.register(("heir", t), (16.0 * (t % 10) + 7.25, 2.0, 2.0),
+                   _SPL_PEERS[(t + 1) % 5])
+    w.step(((t + 3) % 10, 4))
+
+
+def _spl_touched_midflight(w, t, seen):
+    cube = t % 10
+    w.step((cube, 0))
+
+    late = {name: w.pos[name] + np.float32(0.25)
+            for name in ((cube, 1), ((cube + 4) % 10, 2))}
+
+    def between(pl):
+        # lands after the dispatch: the tick computed both rows (one
+        # in its closure, one it replays) from positions the wire has
+        # since replaced
+        for name, xyz in late.items():
+            w.place(name, xyz, planes=[pl])
+    return {"between": between}
+
+
+def _spl_skip_streak(w, t, seen):
+    for j in range(2):
+        w.step(((t + 2 * j) % 10, j + 1))
+    return {"skip": 4 <= t <= 9 or t in (13, 14)}
+
+
+def _spl_neg_zero_and_nan(w, t, seen):
+    if t == 2:
+        w.place((0, 0), (-0.0, 3.0, 5.0))   # 0 quantises into cube 0 too
+    elif t == 4:
+        w.place((1, 0), (float("nan"), 3.0, 5.0))
+    elif t == 9:
+        w.place((0, 0), (0.0, 3.0, 5.0))    # other bits, the same place
+    elif t == 11:
+        w.place((1, 0), (float("nan"), 3.0, 5.0))   # the same NaN again
+    elif t == 14:
+        w.place((1, 0), (18.0, -0.0, 5.0))
+    # their cube-mates keep both cubes in the closure
+    w.step((0, 1 + t % 4))
+    w.step((1, 1 + t % 4))
+
+
+def _spl_occupancy_over_k(w, t, seen):
+    if t == 0:
+        for j in range(6):          # 12 in cube 3, k = 8
+            w.register(("crowd", j), (16.0 * 3 + 1.5 + j, 12.0, 9.0 + j % 2),
+                       _SPL_PEERS[j % 5])
+    rng = np.random.default_rng(100 + t)
+    w.place((3, t % 6), (16.0 * 3 + rng.integers(8, 120) / 8.0,
+                         rng.integers(8, 120) / 8.0, 5.0))
+
+    def after(result):
+        slot = w.new._slot_of[w.ids[(3, 0)]]
+        assert w.new._last_counts[slot] == 12 > SPL_K
+    return {"after": after}
+
+
+def _spl_replay(w, t, seen):
+    if t % 3 != 2:
+        w.step((t % 10, 0))
+        return {}
+
+    def after(result):
+        assert result["mode"] == "replay"
+        seen["replays"] = seen.get("replays", 0) + 1
+    return {"after": after}
+
+
+def _spl_abort_then_full(w, t, seen):
+    w.step((t % 10, 5))
+    if t in (7, 15):
+        for pl in w.planes:
+            assert pl.dispatch_tick() is not None
+            pl.abort_tick()
+        w.step(((t + 1) % 10, 5))
+
+        def after(result):
+            assert result["mode"] == "full"
+            seen["fulls"] = seen.get("fulls", 0) + 1
+        return {"after": after}
+
+
+def _spl_movers(w, t, seen):
+    """Somebody integrates: a mover's row differs every tick, and the
+    compare costs its one pass and saves nothing for that row."""
+    if t == 0:
+        for c in (2, 6, 8):
+            w.place((c, 0), w.pos[(c, 0)], vel=(2.5, 0.0, 0.0))
+    w.step(((t + 1) % 10, 3))
+
+    def after(result):
+        if t > 0 and result.get("mode") == "delta":
+            assert seen["spliced_this_tick"] >= 3
+    return {"after": after}
+
+
+SPLICE_CASES = {
+    "step_inside": _spl_step_inside,
+    "order_only": _spl_order_only,
+    "cube_crossing": _spl_cube_crossing,
+    "roster": _spl_roster,
+    "touched_midflight": _spl_touched_midflight,
+    "skip_streak": _spl_skip_streak,
+    "neg_zero_and_nan": _spl_neg_zero_and_nan,
+    "occupancy_over_k": _spl_occupancy_over_k,
+    "replay": _spl_replay,
+    "abort_then_full": _spl_abort_then_full,
+    "movers": _spl_movers,
+}
+
+
+@pytest.mark.parametrize("case", list(SPLICE_CASES))
+def test_spliced_rows_serve_the_frames_of_the_whole_closure(case):
+    """A delta tick names to the interest manager the closure rows it
+    found changed, not the closure. Tick by tick the frames are the
+    bytes, in the order and to the recipients, of a twin that names
+    every closure row (the plane before ISSUE 42) and of a plane that
+    runs full ticks and names nothing; at the end the three ledgers are
+    one, and are what a client holds."""
+    w = _Trio()
+    w.tick()                        # cold: a full tick on every plane
+    w.tick()                        # nothing dirty: a replay
+    seen: dict = {}
+    for t in range(SPL_TICKS):
+        plan = SPLICE_CASES[case](w, t, seen) or {}
+        before = w.new.spliced_rows
+        result, _pairs = w.tick(skip=plan.get("skip", False),
+                                between=plan.get("between"))
+        seen["spliced_this_tick"] = w.new.spliced_rows - before
+        if "after" in plan:
+            plan["after"](result)
+    # a last served tick settles whatever a shed streak still owed
+    w.tick()
+    w.settle()
+
+    new, twin = w.new, w.twin
+    assert new.delta_mispredicts == 0 == twin.delta_mispredicts
+    assert new.delta_sim_ticks >= SPL_TICKS - 2 and w.served >= 10
+    assert new.delta_recomputed == twin.delta_recomputed
+    # the mechanism engaged: fewer rows written and named than compared
+    # (a crossing, a registration or a removal changes the answer of
+    # both cubes' rows; a step or a mover that of its own row)
+    wide = case in ("cube_crossing", "roster", "occupancy_over_k")
+    assert 0 < new.spliced_rows < new.delta_recomputed / (1 if wide else 2)
+    assert new.stats()["spliced_rows"] == new.metrics.counters[
+        "sim.spliced_rows"] == new.spliced_rows
+    scanned = [w.counters(pl)["interest.rows_scanned"] for pl in w.planes]
+    assert scanned[0] < scanned[1] < scanned[2]
+    assert w.counters(new)["interest.hinted_ticks"] \
+        == w.counters(twin)["interest.hinted_ticks"] > 0
+    assert w.counters(w.ref).get("interest.hinted_ticks") is None
+    if case == "order_only":
+        assert seen["order_only_rows"] >= 10
+    if case == "cube_crossing":
+        assert seen["churn"] >= 9 and new.index_moves == w.ref.index_moves
+    if case == "replay":
+        assert seen["replays"] >= 6
+    if case == "abort_then_full":
+        assert seen["fulls"] == 2 and new.full_sim_ticks == 3
+    if case == "skip_streak":
+        assert new.frames_skipped == 8
+
+
+def test_spliced_rows_are_the_rows_whose_answer_changed():
+    """The counts the benchmark reads: a delta tick's
+    ``sim.spliced_rows`` is the number of live rows whose answer
+    (recipients as a set with multiplicity, count, position bits)
+    differs from what the retained columns held, wherever a full tick
+    puts them; the retained columns then equal the full tick's; and the
+    manager's ``interest.rows_scanned`` grows by those rows and the
+    roster's."""
+    w = _Trio()
+    w.tick()
+    w.tick()
+    new, ref = w.new, w.ref
+    total = 0
+    for t in range(12):
+        _spl_roster(w, t, {})
+        w.step((t % 10, 0), (0.0, 0.125, 0.0))
+        if t % 4 == 1:
+            w.step(((t + 2) % 10, 1), (16.0, 0.0, 0.0))
+        roster = np.unique(np.asarray(w.roster, np.intp))
+        cap = new._cap
+        held = [c.copy() for c in
+                (new._last_pos, new._last_targets, new._last_counts)]
+        truth: dict = {}
+        collect = ref.collect_tick
+
+        def spy(handle, collect=collect):
+            truth.update(collect(handle))
+            return truth
+        ref.collect_tick = spy
+        scanned0 = w.counters(new)["interest.rows_scanned"]
+        spliced0 = new.metrics.counters.get("sim.spliced_rows", 0)
+        result, _ = w.tick()
+        ref.collect_tick = collect
+        assert result["mode"] == "delta" and truth["mode"] == "full"
+        live = new._live[:cap]
+        differ = live & (
+            (held[0].view(np.uint32) != truth["pos"].view(np.uint32)).any(1)
+            | (held[1] != truth["targets"]).any(axis=1)
+            | (held[2] != truth["counts"]))
+        assert differ[result["rows"]].sum() == differ.sum()
+        for kept, full in zip(
+                (new._last_pos, new._last_targets, new._last_counts),
+                (truth["pos"], truth["targets"], truth["counts"])):
+            assert np.array_equal(kept[live].view(np.uint32),
+                                  np.asarray(full)[live].view(np.uint32))
+        spliced = new.metrics.counters["sim.spliced_rows"] - spliced0
+        assert spliced == int(differ.sum())
+        assert w.counters(new)["interest.rows_scanned"] - scanned0 \
+            == len(np.union1d(np.flatnonzero(differ), roster))
+        total += spliced
+    assert 12 <= total < new.delta_recomputed
+
+
+# endregion
+
 # region: e2e — mostly-idle world over real ZMQ shows reuse in /metrics
 
 

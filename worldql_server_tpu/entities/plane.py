@@ -61,8 +61,13 @@ closure by binary search and scans the velocity column only while
 somebody moves, and ``collect_tick`` hands the f64 quantiser only the
 rows that were dirty at dispatch or that the device handed back
 changed: every other closure row still holds the position its
-registered cube was quantised from. Counters ``sim.quantised_rows``
-and ``sim.dispatch_scan_rows`` say what a tick paid.
+registered cube was quantised from. ``apply`` then writes, and names
+to the interest manager, the closure rows whose answer (recipients as
+a sorted row, count, position bits) is not the one the retained
+columns hold: the closure is ~16 rows a dirty cube, the rows whose
+answer changed are about the rows that moved. Counters
+``sim.quantised_rows``, ``sim.dispatch_scan_rows`` and
+``sim.spliced_rows`` say what a tick paid.
 
 Tick-path discipline: ``dispatch_tick``/``collect_tick`` are the
 sim-tick hot functions — no per-entity Python, host syncs only at the
@@ -115,6 +120,9 @@ _SCATTER_MIN_BUCKET = 64
 #: closure pads up to this before the sub-kernel launches, so steady
 #: low-churn serving reuses a handful of compiled shapes
 _DELTA_MIN_TIER = 64
+#: minor dimension a fetched block needs to come back row-major: a
+#: TPU's lane row (``canonical_tick_fn``)
+_ROW_LANES = 128
 #: world-name fallback envelope for wire-path registrations (the world
 #: is always resolved before this is consulted)
 _WIRE_MSG = Message(instruction=Instruction.LOCAL_MESSAGE)
@@ -179,6 +187,35 @@ class _StageBuf:
             out = np.zeros(cap, bool)
             out[:old] = getattr(self, name)
             setattr(self, name, out)
+
+
+def canonical_tick_fn(**static):
+    """The plane's tick: ``ops.tick.make_tick_fn(**static)`` with every
+    row's recipients SORTED, and laid out for the host. The op hands
+    them nearest first (its contract); nothing on the host reads that
+    order, both frame legs sort a row themselves, and a neighbour's
+    step re-orders rows whose SET stood. Sorted on the device (one
+    ``[tier, k]`` int32 row sort where the device idles), in full and
+    delta ticks alike, the retained columns are canonical everywhere,
+    in the form of the interest manager's snapshot (-1 first), and the
+    delta splice's compare is of sets, in one pass.
+
+    The sorted block comes back as ``[tier * k / 128, 128]`` (flat
+    where that does not divide): the same bytes, and
+    ``collect_tick``'s reshape to ``[tier, k]`` is then ROW-major. A
+    TPU hands an ``[N, K]`` block with K < 128 back column-major, and
+    on the chip hosts a compare of 18,600 such rows with row-major
+    ones costs 1.82 ms where two row-major blocks cost 0.11."""
+    tick = make_tick_fn(**static)
+
+    def canonical_tick(state: EntityState):
+        new_state, targets, counts = tick(state)
+        flat = jnp.sort(targets, axis=1).reshape(-1)
+        if flat.size % _ROW_LANES == 0:
+            flat = flat.reshape(-1, _ROW_LANES)
+        return new_state, flat, counts
+
+    return canonical_tick
 
 
 def _scatter_update(state: EntityState, idx, pos, vel, wid, pid):
@@ -310,9 +347,10 @@ class EntityPlane:
         self._last_counts: np.ndarray | None = None
         self._last_pos: np.ndarray | None = None
         #: what this plane owes the interest manager's next
-        #: ``build_pairs``: the rows of the columns it reads that may
-        #: differ from what its LAST call read, as ``(closures, roster)``
-        #: — the ``rows`` of every delta tick applied since, and the
+        #: ``build_pairs``: the rows of the columns it reads that
+        #: differ from what its LAST call read, as ``(spliced, roster)``
+        #: — the closure rows every delta tick applied since found
+        #: different from the retained columns and wrote, and the
         #: slots allocated or released since (``live``, uuid, world).
         #: None: the plane cannot name them (no call yet, a full tick,
         #: a shed streak that owes more than a tier) and the manager
@@ -325,11 +363,14 @@ class EntityPlane:
         self.delta_fallbacks = 0
         self.delta_mispredicts = 0
         self.last_delta_stats: dict = {}
-        #: rows ``collect_tick`` handed the f64 quantiser, and rows the
+        #: rows ``collect_tick`` handed the f64 quantiser, rows the
         #: delta dispatch read in passes as long as the capacity tier
-        #: (the velocity scan, the closure's key test)
+        #: (the velocity scan, the closure's key test), and closure
+        #: rows a delta splice found changed, wrote and named (beside
+        #: ``delta_recomputed``, the closure rows it compared)
         self.quantised_rows = 0
         self.dispatch_scan_rows = 0
+        self.spliced_rows = 0
 
         self._n = 0                     # slot high-water mark
         self._free: list[int] = []      # recycled slots below _n
@@ -370,13 +411,12 @@ class EntityPlane:
         # the XLA stencil elsewhere — read HERE from the one rule the
         # ops apply (jaxconf.on_tpu), so that the choice can be logged
         # at the first tick and read from the entity_sim gauge.
+        # What the plane's wrapper adds to the op: canonical_tick_fn.
         self.pallas = jaxconf.on_tpu()
-        self._tick_fn = jax.jit(
-            make_tick_fn(
-                cube_size=cube_size, k=self.k, dt=self.dt,
-                bounds=self.bounds, pallas=self.pallas,
-            )
-        )
+        self._tick_fn = jax.jit(canonical_tick_fn(
+            cube_size=cube_size, k=self.k, dt=self.dt,
+            bounds=self.bounds, pallas=self.pallas,
+        ))
         GUARD.register("entities.sim_tick", self._tick_fn)
         # incremental H2D: one jitted scatter, shape-keyed on
         # (capacity tier, dirty bucket) — the ladder precompiles at boot
@@ -1356,9 +1396,9 @@ class EntityPlane:
         coupling follows the golden grid, not the device's f32 twin.
         A full tick quantises the tier. A delta tick quantises the
         closure rows that were dirty at dispatch or came back with
-        another position than they were given (``quantised``: indices
-        into ``rows``; ``cubes`` is aligned with it): a row that was
-        not dirty holds the position its registered cube was
+        other position bits than they were given (``quantised``:
+        indices into ``rows``; ``cubes`` is aligned with it): a row
+        that was not dirty holds the position its registered cube was
         quantised from, and the same position has the same cube.
         ``quantised_rows`` feeds the counter ``sim.quantised_rows``."""
         t0 = time.perf_counter()
@@ -1367,7 +1407,7 @@ class EntityPlane:
             # nothing was dispatched: the retained tick IS the result
             return {"mode": "replay", "cap": handle["cap"], "knn_ms": 0.0}
         pos = np.asarray(handle["pos"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
-        targets = np.asarray(handle["targets"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
+        targets = np.asarray(handle["targets"]).reshape(-1, self.k)  # wql: allow(host-sync-in-sim-tick) — designated collect point
         counts = np.asarray(handle["counts"])  # wql: allow(host-sync-in-sim-tick) — designated collect point
         # with the tracer's CPU clock on, the wait's two legs are read
         # apart: the fetches (device wait + D2H, the GIL released) and
@@ -1383,14 +1423,18 @@ class EntityPlane:
         moved_pos = pos
         if mode == "delta":
             # only a row that was dirty at dispatch, or that the device
-            # handed back changed (a mover, a reflection at the bounds,
-            # a NaN: `!=` counts it), can have a new cube: every other
-            # closure row holds the position its registered cube was
-            # quantised from. The pads are never read.
+            # handed back with other BITS than it was given (a mover, a
+            # reflection at the bounds, a -0.0 that came back 0.0), can
+            # have a new cube or a new frame position: every other
+            # closure row holds, bit for bit, the position its
+            # registered cube was quantised from and the retained
+            # column keeps (`_apply_delta` reads the position of these
+            # rows alone). The pads are never read.
             n = int(handle["rows"].size)
             quantised = np.flatnonzero(
                 handle["dirty_in"]
-                | (pos[:n] != handle["pos_in"][:n]).any(axis=1)
+                | (pos[:n].view(np.uint32)
+                   != handle["pos_in"][:n].view(np.uint32)).any(axis=1)
             )
             moved_pos = pos[quantised]
         cubes = cube_coords_batch(
@@ -1441,12 +1485,18 @@ class EntityPlane:
         silent.
 
         With an interest manager the plane tells it which rows to
-        read (``_owed``): a delta tick's closure, whatever earlier
-        delta ticks shed by ``skip_frames`` changed and still owe, and
-        the slots allocated or released since the manager's last call;
-        a replay tick adds none. After a full tick (the first one, a
-        tier change, ``abort_tick``, a mispredict, churn past the
-        threshold) it names none, and the manager scans every row."""
+        read (``_owed``): the closure rows this delta tick's splice
+        found different from the retained columns and wrote
+        (``_apply_delta``), those of earlier delta ticks shed by
+        ``skip_frames`` (the splice runs on every applied delta tick,
+        so a row that differs from what the manager last read was
+        named by the tick that changed it), and the slots allocated or
+        released since the manager's last call; a replay tick adds
+        none. For the closure rows it does not name the plane vouches
+        itself: it compared them, and they hold what the last call
+        read. After a full tick (the first one, a tier change,
+        ``abort_tick``, a mispredict, churn past the threshold) it
+        names none, and the manager scans every row."""
         self._tick_inflight = False
         t0 = time.perf_counter()
         cap = result["cap"]
@@ -1482,10 +1532,12 @@ class EntityPlane:
             # WRITABLE copies: np.asarray of a device buffer is a
             # read-only zero-copy view, and delta ticks splice their
             # sub-results into these in place. ROW-major copies: a TPU
-            # hands an [N, K] column back column-major, and everything
-            # from here on reads and writes rows (the delta splice,
-            # the frame leg's gather of the rows a tick changed — 13 ms
-            # a tick at 28K rows of a column-major 131,072 x 32)
+            # hands an [N, K] column back column-major (the positions
+            # still; the recipients come in lane rows, canonical_tick_fn)
+            # and everything from here on reads and writes rows (the
+            # delta splice, the frame leg's gather of the rows a tick
+            # changed — 13 ms a tick at 28K rows of a column-major
+            # 131,072 x 32)
             if self._delta_ticks:
                 self._last_pos = pos = np.array(pos, order="C")
                 self._last_targets = targets = np.array(targets, order="C")
@@ -1572,37 +1624,61 @@ class EntityPlane:
         return np.unique(np.concatenate([roster, *closures])), roster
 
     def _apply_delta(self, result: dict):
-        """Splice a delta sub-tick over the retained last-tick arrays:
-        closure rows take the freshly computed values, clean rows keep
-        (replay) theirs. Returns ``(pos, targets, counts,
-        moved_slots)`` for the shared apply tail — ``pos`` is the
-        device-integrated frame position column, exactly what the full
-        path hands it. Every written-back row takes its position; only
-        the rows ``collect_tick`` quantised are compared with their
-        registered cube and churned — the others cannot have moved."""
+        """Splice a delta sub-tick over the retained last-tick arrays,
+        COMPARING before it writes: a closure row takes its freshly
+        computed values only where they differ, bit for bit, from what
+        the retained columns hold (recipients, which both sides keep
+        sorted, so a row whose recipients only changed order is equal;
+        ``counts``; the position as ``uint32`` bits: -0.0 and NaN are
+        positions too); every other row, in the closure or not, keeps
+        (replays) its own. The rows written are the rows named to the
+        interest manager (``_owed``) and counted in
+        ``sim.spliced_rows``; ``delta.sim_recomputed`` counts the
+        closure they were found in.
+
+        Only the rows ``collect_tick`` quantised are read for their
+        position, written back and compared with their registered cube:
+        any other closure row was not dirty at dispatch and came back
+        with the bits it was given, which are the bits ``_pos`` and
+        ``_last_pos`` hold (both took them from the last tick that
+        computed the row, and a wire write since would have made it
+        dirty), so it has nothing to write back, no new cube, and the
+        device twin is no staler for it than it was.
+
+        Returns ``(pos, targets, counts, moved_slots)`` for the shared
+        apply tail — ``pos`` is the device-integrated frame position
+        column, exactly what the full path hands it."""
         rows = result["rows"]
         n = int(rows.size)
-        pos_sub = result["pos"][:n]
-        self._last_targets[rows] = result["targets"][:n]
-        self._last_counts[rows] = result["counts"][:n]
-        self._last_pos[rows] = pos_sub
-        if self._owed is not None:
-            self._owed[0].append(rows)
-
-        # writeback + churn for closure rows the wire didn't touch
-        # mid-flight (same mask the full path applies tier-wide);
-        # rows removed mid-flight dropped out of `live` already
-        wb = self._live[rows] & ~self._touched[rows]
-        wrows = rows[wb]
-        self._pos[wrows] = pos_sub[wb]
-        # churn among the rows collect_tick quantised: the others came
-        # back with the position their registered cube stands for
         quantised = result["quantised"]
-        qwb = wb[quantised]
-        qrows = rows[quantised[qwb]]
-        qcubes = result["cubes"][qwb]
-        moved = np.any(qcubes != self._cube[qrows], axis=1)
-        moved_slots = qrows[moved]
+        qrows = rows[quantised]
+        new_t, new_c = result["targets"][:n], result["counts"][:n]
+        differ = _rows_differ(new_t, self._last_targets.take(rows, axis=0))
+        differ |= new_c != self._last_counts[rows]
+        differ[quantised] |= _rows_differ(
+            result["pos"][quantised].view(np.uint32),
+            self._last_pos[qrows].view(np.uint32),
+        )
+        at = np.flatnonzero(differ)
+        spliced = rows[at]          # sorted: a mask of a sorted array
+        self._last_targets[spliced] = new_t[at]
+        self._last_counts[spliced] = new_c[at]
+        self._last_pos[spliced] = result["pos"][at]
+        self.spliced_rows += len(at)
+        if self.metrics is not None:
+            self.metrics.inc("sim.spliced_rows", len(at))
+        if self._owed is not None and len(at):
+            self._owed[0].append(spliced)
+
+        # writeback + churn for the quantised rows the wire didn't
+        # touch mid-flight (same mask the full path applies tier-wide);
+        # rows removed mid-flight dropped out of `live` already
+        wb = self._live[qrows] & ~self._touched[qrows]
+        wrows = qrows[wb]
+        self._pos[wrows] = result["pos"][quantised[wb]]
+        qcubes = result["cubes"][wb]
+        moved = np.any(qcubes != self._cube[wrows], axis=1)
+        moved_slots = wrows[moved]
         if moved_slots.size:
             self._apply_churn(moved_slots, qcubes[moved])
 
@@ -1623,8 +1699,9 @@ class EntityPlane:
                     "forcing a full recompute next tick", bad,
                 )
 
-        # the device twin never saw this sub-tick: closure rows are
-        # stale there until the next full-path scatter re-ships them
+        # the device twin never saw this sub-tick: the rows written
+        # back are stale there until the next full-path scatter
+        # re-ships them
         self._device_dirty[wrows] = True
         return self._last_pos, self._last_targets, self._last_counts, \
             moved_slots
@@ -1840,12 +1917,24 @@ class EntityPlane:
             "delta_fallbacks": self.delta_fallbacks,
             "delta_mispredicts": self.delta_mispredicts,
             "quantised_rows": self.quantised_rows,
+            "spliced_rows": self.spliced_rows,
             "dispatch_scan_rows": self.dispatch_scan_rows,
             "last_integrate_ms": round(self.last_integrate_ms, 3),
             "last_knn_ms": round(self.last_knn_ms, 3),
             "last_apply_ms": round(self.last_apply_ms, 3),
             "last_churn": self.last_churn,
         }
+
+
+def _rows_differ(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Which rows of two ``[n, k]`` blocks differ anywhere. The
+    row-wise ``any`` of a short axis is numpy's slow case (0.64 ms for
+    18,600 x 32 where the compare itself takes 0.2), so eight flags
+    are folded at a time where the width allows."""
+    ne = a != b
+    if ne.shape[1] % 8 == 0 and ne.flags.c_contiguous:
+        return np.bitwise_or.reduce(ne.view(np.uint64), axis=1) != 0
+    return ne.any(axis=1)
 
 
 def _is_moving(vel):

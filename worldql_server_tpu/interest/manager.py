@@ -180,7 +180,10 @@ class _Snapshot:
     ``targets`` holds a row's recipients in canonical form (sorted, a
     dead row's all -1), so a row whose recipients came back in another
     ORDER equals its snapshot, and only the rows a diff kept are ever
-    written. Who owes whom which rows: the caller of
+    written. ``EntityPlane`` keeps its retained columns in the same
+    form (its tick returns every row sorted), so the rows it compares
+    against them itself are compared as this snapshot would. Who owes
+    whom which rows: the caller of
     :meth:`InterestManager.build_pairs` may vouch that every row it
     does not name still holds what the last call read; the snapshot
     then answers for those rows unread, and the named ones are
@@ -352,9 +355,15 @@ class InterestManager:
         part of ``rows`` whose ``live``, uuid or world may differ too.
         The caller owes every such row since that call, those of ticks
         it applied without calling here included; the diff then reads
-        those rows alone. None (the default): the caller cannot name
-        them, and every row is compared. The frames are the same
-        either way."""
+        those rows alone. How the caller knows is its own affair: it
+        may name every row it recomputed, or vouch for the ones it
+        compared itself and found bit for bit what its columns held
+        (``EntityPlane._apply_delta`` does, so ``rows`` is about the
+        rows this diff keeps); a row named in vain costs its compare
+        and nothing else. The sort of the named rows stays: a caller
+        may hand recipients in any order. None (the default): the
+        caller cannot name them, and every row is compared. The frames
+        are the same either way."""
         self._ticks += 1
         if trace is None:
             trace = NULL_TRACE
