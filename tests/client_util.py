@@ -7,7 +7,10 @@ external game plugin would use.
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import faulthandler
 import socket
+import threading
 import uuid as uuid_mod
 
 import zmq
@@ -30,6 +33,52 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+@contextlib.contextmanager
+def dead_port():
+    """A loopback port nobody listens at, and nobody else can take
+    while the block lasts: bound, never listening, so a dial is
+    refused. (A port read from ``free_port()`` is anybody's again, the
+    same test's next ``bind_to_random_port`` included.)"""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        yield s.getsockname()[1]
+
+
+@contextlib.contextmanager
+def zmq_context(kind=zmq.Context):
+    """The one way a test makes and ends a zmq context (``kind``:
+    ``zmq.asyncio.Context`` for awaitable sockets). Its sockets are
+    born with ``LINGER 0``, and it ends by ``destroy(linger=0)``, which
+    closes every socket made from it, the ones a failed test never got
+    to close too: a bare ``term()`` waits for those for ever."""
+    ctx = kind()
+    ctx.setsockopt(zmq.LINGER, 0)
+    try:
+        yield ctx
+    finally:
+        ctx.destroy(linger=0)
+
+
+def stops_on_a_thread(scenario, grace: float = 5.0):
+    """``scenario(stopping)`` on a loop of a thread of its own; it sets
+    ``stopping`` right before the stop under test, which then has
+    ``grace`` seconds. (A stop that waits in ``zmq_ctx_term`` blocks
+    its loop's thread, so no ``wait_for`` on that loop can end it.)
+    Returns what the scenario returned."""
+    stopping, out = threading.Event(), []
+    thread = threading.Thread(
+        target=lambda: out.append(asyncio.run(scenario(stopping))),
+        daemon=True)
+    thread.start()
+    assert stopping.wait(30), "never got as far as the stop"
+    thread.join(grace)
+    if thread.is_alive():
+        faulthandler.dump_traceback(all_threads=True)   # where it waits
+        raise AssertionError(f"stop() still waits after {grace} s")
+    [result] = out
+    return result
 
 
 class WsClient:
@@ -119,14 +168,24 @@ class ZmqClient:
     resume token (kept on ``self.token``); a refused handshake echoes
     ``retry-after:<ms>`` instead (``self.retry_after_ms``)."""
 
-    def __init__(self, ctx, push, pull, uuid: uuid_mod.UUID,
-                 token: str | None = None):
-        self.ctx = ctx
+    #: Connected and not closed yet. The strong reference is the point:
+    #: a client a (failed) test never closed must not be left to the
+    #: collector. Its sockets sit in cycles of their own futures, the
+    #: collector clears weak references before it runs finalizers, so
+    #: pyzmq's ``Context.__del__`` finds its set of sockets empty,
+    #: closes nothing and calls ``term()``, which then waits for ever
+    #: for sockets whose finalizers queue behind it: tier-1's 21-minute
+    #: hang (PERF.md, PR 43). ``tests/conftest.py`` ends what is left
+    #: here after every test.
+    _open: set = set()
+
+    def __init__(self, push, pull, uuid: uuid_mod.UUID):
         self.push = push  # client → server PULL
         self.pull = pull  # server PUSH → client
         self.uuid = uuid
-        self.token = token
+        self.token: str | None = None
         self.retry_after_ms: int | None = None
+        self._end = None  # ends the context (set once connected)
 
     @classmethod
     async def connect(
@@ -135,31 +194,34 @@ class ZmqClient:
         token: str | None = None,
     ) -> "ZmqClient":
         """Handshake (optionally presenting ``token`` to resume a
-        parked session under ``peer_uuid``)."""
-        ctx = zmq.asyncio.Context()
-        pull = ctx.socket(zmq.PULL)
-        client_port = pull.bind_to_random_port(f"tcp://{host}")
-        push = ctx.socket(zmq.PUSH)
-        push.setsockopt(zmq.LINGER, 0)
-        push.connect(f"tcp://{host}:{server_port}")
+        parked session under ``peer_uuid``). A handshake that fails
+        ends the context on its way out."""
+        with contextlib.ExitStack() as stack:
+            ctx = stack.enter_context(zmq_context(zmq.asyncio.Context))
+            pull = ctx.socket(zmq.PULL)
+            client_port = pull.bind_to_random_port(f"tcp://{host}")
+            push = ctx.socket(zmq.PUSH)
+            push.connect(f"tcp://{host}:{server_port}")
 
-        client = cls(ctx, push, pull, peer_uuid or uuid_mod.uuid4())
-        await client.send(
-            Message(
-                instruction=Instruction.HANDSHAKE,
-                parameter=f"{host}:{client_port}",
-                flex=token.encode() if token is not None else None,
-            )
-        )
-        echo = await client.recv()
-        assert echo.instruction == Instruction.HANDSHAKE
-        if echo.parameter is not None:
-            if echo.parameter.startswith("retry-after:"):
-                client.retry_after_ms = int(
-                    echo.parameter.split(":", 1)[1]
+            client = cls(push, pull, peer_uuid or uuid_mod.uuid4())
+            await client.send(
+                Message(
+                    instruction=Instruction.HANDSHAKE,
+                    parameter=f"{host}:{client_port}",
+                    flex=token.encode() if token is not None else None,
                 )
-            else:
-                client.token = echo.parameter
+            )
+            echo = await client.recv()
+            assert echo.instruction == Instruction.HANDSHAKE
+            if echo.parameter is not None:
+                if echo.parameter.startswith("retry-after:"):
+                    client.retry_after_ms = int(
+                        echo.parameter.split(":", 1)[1]
+                    )
+                else:
+                    client.token = echo.parameter
+            client._end = stack.pop_all().close
+        cls._open.add(client)
         return client
 
     @classmethod
@@ -193,6 +255,10 @@ class ZmqClient:
                 return message
 
     async def close(self) -> None:
-        self.push.close(linger=0)
-        self.pull.close(linger=0)
-        self.ctx.term()
+        self._open.discard(self)
+        self._end()
+
+    @classmethod
+    def close_leftovers(cls) -> None:
+        while cls._open:
+            cls._open.pop()._end()

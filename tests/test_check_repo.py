@@ -6,7 +6,10 @@ test keeps it that way between CI runs (the workflow's lint job runs
 the same command).
 """
 
+import ast
 from pathlib import Path
+
+import pytest
 
 from tools.check import check_paths
 
@@ -23,6 +26,28 @@ def test_tooling_is_lint_clean():
     assert violations == [], "\n" + "\n".join(v.render() for v in violations)
 
 
+@pytest.mark.parametrize("where", [
+    "worldql_server_tpu", "tests", "tools", "chip_smoke.py",
+])
+def test_no_zmq_context_is_termed_bare(where):
+    """A zmq context ends by ``destroy(linger=0)``, which closes its
+    sockets first. ``term()`` waits, with no limit, for every socket of
+    the context to be closed by someone: one left open (a failed test,
+    a cancelled handshake) and a server does not come down on SIGTERM;
+    tier-1 lost 1,250 s to one such call (PERF.md, PR 43). Nothing else
+    here has a ``term`` method, so the name alone is the rule.
+    (``benchmark/worker.py`` keeps one: not every PR may edit it.)"""
+    root = REPO / where
+    found = [
+        f"{path.relative_to(REPO)}:{node.lineno}"
+        for path in ([root] if root.is_file() else sorted(root.rglob("*.py")))
+        for node in ast.walk(ast.parse(path.read_text()))
+        if isinstance(node, ast.Call)
+        and isinstance(node.func, ast.Attribute) and node.func.attr == "term"
+    ]
+    assert found == [], f"bare term(): {found}; use destroy(linger=0)"
+
+
 def test_no_runtime_artifacts_committed():
     """Runtime artifacts must never be committed: a stray ``worldql.db``
     (the default sqlite store, created by any server run in the repo
@@ -37,8 +62,6 @@ def test_no_runtime_artifacts_committed():
             text=True, timeout=30, check=True,
         ).stdout.splitlines()
     except Exception:
-        import pytest
-
         pytest.skip("not a git checkout")
     offenders = [
         f for f in tracked

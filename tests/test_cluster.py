@@ -138,6 +138,10 @@ def _cluster_config(tmp_path, n_shards: int = 2) -> Config:
         durability="wal", wal_dir=str(tmp_path / "wal"),
         checkpoint_interval=0,   # SIGKILL must find the WAL un-truncated
         session_ttl=30.0,
+        # these clients never heartbeat, and a case gives a killed shard
+        # 90 s to come back: with the default 25 s a peer that only
+        # waited was swept as stale on loaded cores, then torn down
+        zmq_timeout_secs=600,
         trace=True,              # shards inherit --trace for /debug/ticks
         cluster_shards=n_shards,
         verbose=0,
@@ -761,21 +765,23 @@ def test_world_map_stable_and_covering():
         WorldMap(0)
 
 
+class _TwoShards:
+    """All a ``ClusterRouter`` asks of its supervisor before traffic."""
+    n_shards = 2
+
+    def ctl_send(self, *a, **k):
+        return True
+
+
 def test_shed_mirror_admission_classes():
     """Router-side admission mirrors the governor's class semantics:
     records/entity/subscribe/control always pass; locals+globals shed
     only at REJECT; new handshakes shed at SHED_HIGH+."""
     from worldql_server_tpu.cluster.router import ClusterRouter
 
-    class _Sup:
-        n_shards = 2
-
-        def ctl_send(self, *a, **k):
-            return True
-
     config = Config(ws_enabled=False, zmq_enabled=True,
                     cluster_shards=2, http_enabled=False)
-    router = ClusterRouter(config, _Sup())
+    router = ClusterRouter(config, _TwoShards())
 
     def admit(instruction, level, **kwargs):
         router.mirror.levels[0] = level
@@ -802,4 +808,43 @@ def test_shed_mirror_admission_classes():
     assert admit(Instruction.HANDSHAKE, 1)
     assert not admit(Instruction.HANDSHAKE, 2)
     assert admit(Instruction.HANDSHAKE, 2, flex=b"token")
-    router.ctx.term()
+    router.ctx.destroy(linger=0)
+
+
+@pytest.mark.parametrize("left_open", ["orphan", "refusal"])
+def test_router_stop_closes_a_socket_that_no_list_names(left_open):
+    """``stop()`` ends the context with every socket made from it, not
+    only ``_pull`` and ``_push``: one that nothing files, and a refusal
+    hint's, whose task ``stop()`` cancels but does not wait for. A bare
+    ``term()`` waited for either for ever, on the loop's own thread."""
+    import zmq
+
+    from tests.client_util import free_port, stops_on_a_thread
+    from worldql_server_tpu.cluster.router import ClusterRouter
+
+    async def scenario(stopping):
+        config = Config(ws_enabled=False, zmq_enabled=True,
+                        cluster_shards=2, http_enabled=False,
+                        zmq_server_host="127.0.0.1",
+                        zmq_server_port=free_port())
+        router = ClusterRouter(config, _TwoShards())
+        await router.start()
+        if left_open == "orphan":
+            held = router.ctx.socket(zmq.PUSH)
+        else:
+            # the hint's PUSH gets no pipe before its peer listens,
+            # and nobody ever does: its send waits
+            router.ctx.setsockopt(zmq.IMMEDIATE, 1)
+            router._send_refusal(Message(
+                instruction=Instruction.HANDSHAKE,
+                parameter=f"127.0.0.1:{free_port()}"))
+            await asyncio.sleep(0.1)
+            [held] = router._refusals
+            assert not held.done()
+        stopping.set()
+        await router.stop()
+        return router.ctx.closed, held
+
+    closed, held = stops_on_a_thread(scenario)
+    assert closed
+    assert held.closed if left_open == "orphan" else held.cancelled()

@@ -22,6 +22,8 @@ boots the full stack under load.)
 import asyncio
 import json
 import os
+import random
+import socket
 import time
 import types
 import uuid as uuid_mod
@@ -241,6 +243,59 @@ def _fake_ext(tmp_path, slow_frame_ms=None):
         "rings": {"out": {}, "in": {}},
     }
     return ClusterShardExtension(server, spec)
+
+
+def test_control_loop_loses_no_packet_to_its_own_poll(tmp_path, monkeypatch):
+    """The control channel is SEQPACKET and every caller takes it for
+    reliable (an adopt, an inject, a dump or an export request is sent
+    once). The loop polls it with a timeout, for its state clock; a
+    receive cancelled at a timeout, in the very turn of the loop in
+    which it had taken a datagram, lost that datagram: on a busy loop
+    one packet in five, which was the cluster cases' flake (a proxy
+    never adopted, a capsule without its second shard, an export that
+    never came). Busy loop, packets about a poll apart: all arrive."""
+    from worldql_server_tpu.cluster import shard as shard_mod
+
+    monkeypatch.setattr(shard_mod, "STATE_POLL_S", 0.01)
+    ext = _fake_ext(tmp_path)
+    ext.server.shutdown_requested = asyncio.Event()
+    got = []
+
+    async def handle(data):
+        got.append(int(data))
+
+    ext._handle_control = handle
+    ext._maybe_push_state = lambda: None
+    n = 300
+
+    async def scenario():
+        here, there = socket.socketpair(socket.AF_UNIX, socket.SOCK_SEQPACKET)
+        here.setblocking(False)
+        ext._ctl = here
+
+        async def busy():       # 2 ms a turn of the loop
+            while True:
+                time.sleep(0.002)
+                await asyncio.sleep(0)
+
+        tasks = [asyncio.ensure_future(ext._control_loop()),
+                 asyncio.ensure_future(busy())]
+        rng = random.Random(7)
+        for i in range(n):
+            there.send(str(i).encode())
+            await asyncio.sleep(rng.uniform(0.005, 0.015))
+        for _ in range(200):
+            if len(got) == n:
+                break
+            await asyncio.sleep(0.01)
+        for task in tasks:
+            task.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        here.close()
+        there.close()
+
+    asyncio.run(scenario())
+    assert got == list(range(n)), f"{n - len(got)} of {n} packets lost"
 
 
 def test_frame_stages_attribute_at_least_90_percent(tmp_path):

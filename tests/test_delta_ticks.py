@@ -1299,6 +1299,8 @@ def test_e2e_mostly_idle_world_reuse_fraction_in_metrics():
             fraction = values["wql_delta_reuse_fraction"]
             assert fraction > 0.8, f"reuse_fraction {fraction}"
             assert values.get("wql_delta_sim_reused", 0) > 0
+            await a.close()
+            await b.close()
         finally:
             await server.stop()
 

@@ -43,7 +43,7 @@ import zmq.asyncio
 
 from worldql_server_tpu.transports.zeromq import _CountedPull
 
-from client_util import free_port
+from client_util import free_port, zmq_context
 from prom_parser import validate_exposition
 
 
@@ -544,12 +544,11 @@ def test_a_spinning_thread_reads_cpu_and_a_sleeping_one_does_not():
 
 def test_suspends_counts_only_the_receives_that_found_the_socket_empty():
     async def scenario():
-        ctx = zmq.asyncio.Context()
-        pull, push = ctx.socket(zmq.PULL), ctx.socket(zmq.PUSH)
-        pull.bind("inproc://counted")
-        push.connect("inproc://counted")
-        counted = _CountedPull(pull)
-        try:
+        with zmq_context(zmq.asyncio.Context) as ctx:
+            pull, push = ctx.socket(zmq.PULL), ctx.socket(zmq.PUSH)
+            pull.bind("inproc://counted")
+            push.connect("inproc://counted")
+            counted = _CountedPull(pull)
             # three queued before the loop asks: found waiting
             for i in range(3):
                 await push.send_multipart([b"early", bytes([i])])
@@ -567,10 +566,6 @@ def test_suspends_counts_only_the_receives_that_found_the_socket_empty():
                 await push.send_multipart([b"late", bytes([i])])
                 got.append(await recv)
             return got, early, drained, counted.stats()
-        finally:
-            pull.close(linger=0)    # (through the wrapper: the socket's)
-            push.close(linger=0)
-            ctx.term()
 
     got, early, drained, late = run(scenario())
     assert [parts[0] for parts in got] == [b"early"] * 3 + [b"late"] * 2
@@ -589,25 +584,23 @@ def test_the_recv_loop_counts_with_tracing_on_and_touches_nothing_off(trace):
             zmq_server_host="127.0.0.1", zmq_server_port=port,
             trace=trace))
         await server.start()
-        ctx = zmq.asyncio.Context()
-        push = ctx.socket(zmq.PUSH)
         try:
-            [transport] = server._transports
-            push.connect(f"tcp://127.0.0.1:{port}")
-            for _ in range(20):     # (an unknown sender's: dropped)
-                await push.send(b"not a message")
-                await asyncio.sleep(0.002)
-            for _ in range(200):
-                if server.metrics.snapshot()["gauges"].get(
-                        "zmq_recv", {"messages": 20})["messages"] >= 20:
-                    break
-                await asyncio.sleep(0.01)
-            await asyncio.sleep(0.05)
-            return (type(transport._pull),
-                    server.metrics.snapshot()["gauges"].get("zmq_recv"))
+            with zmq_context(zmq.asyncio.Context) as ctx:
+                push = ctx.socket(zmq.PUSH)
+                [transport] = server._transports
+                push.connect(f"tcp://127.0.0.1:{port}")
+                for _ in range(20):     # (an unknown sender's: dropped)
+                    await push.send(b"not a message")
+                    await asyncio.sleep(0.002)
+                for _ in range(200):
+                    if server.metrics.snapshot()["gauges"].get(
+                            "zmq_recv", {"messages": 20})["messages"] >= 20:
+                        break
+                    await asyncio.sleep(0.01)
+                await asyncio.sleep(0.05)
+                return (type(transport._pull),
+                        server.metrics.snapshot()["gauges"].get("zmq_recv"))
         finally:
-            push.close(linger=0)
-            ctx.term()
             await server.stop()
 
     pull_type, gauge = run(scenario())

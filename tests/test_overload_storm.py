@@ -296,6 +296,10 @@ def test_sigkill_mid_storm_zero_acked_write_loss(tmp_path):
                 except Exception:
                     return  # the SIGKILL landed mid-send
                 i += 1
+                # a send the socket takes at once never yields: give
+                # the writer its turn (a server that drained as fast
+                # as this loop sent starved it for the case's 120 s)
+                await asyncio.sleep(0)
 
         async def write_and_confirm():
             for i in range(60):

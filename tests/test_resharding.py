@@ -672,7 +672,10 @@ async def _readable(client, world: str, want: set,
     return want & seen
 
 
-async def _await_migration(router, timeout_s: float = 60) -> str:
+async def _await_migration(router, timeout_s: float = 90) -> str:
+    # (longer than the coordinator's own wait for the export, 60 s: a
+    # migration that gives up says why in ``describe()``, which a wait
+    # of the same 60 s cut off)
     await _wait(
         lambda: router.migration is not None
         and router.migration.state in ("done", "aborted"),

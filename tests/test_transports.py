@@ -13,10 +13,13 @@ import uuid
 import aiohttp
 import pytest
 import zmq
+import zmq.asyncio
 
 pytest.importorskip("websockets")  # WS transport is half this module
 
-from tests.client_util import WsClient, ZmqClient, free_port
+from tests.client_util import (
+    WsClient, ZmqClient, free_port, stops_on_a_thread, zmq_context,
+)
 from tests.test_robustness_zmq import wait_for
 from worldql_server_tpu.engine.config import Config
 from worldql_server_tpu.engine.peers import FramedPayload
@@ -311,28 +314,26 @@ def test_zmq_unknown_sender_dropped():
             await asyncio.sleep(0.05)
 
             # A message from an unregistered uuid must be ignored
-            # (incoming.rs:64-69): z2 sends without handshaking.
-            import zmq as zmq_sync
-
-            ctx = zmq_sync.Context()
-            push = ctx.socket(zmq_sync.PUSH)
-            push.setsockopt(zmq_sync.LINGER, 0)
-            push.connect(f"tcp://127.0.0.1:{server.config.zmq_server_port}")
-            push.send(
-                serialize_message(
-                    Message(
-                        instruction=Instruction.GLOBAL_MESSAGE,
-                        sender_uuid=uuid.uuid4(),
-                        world_name="@global",
-                        parameter="ghost",
+            # (incoming.rs:64-69): z2 sends without handshaking. (Its
+            # socket stays open over the wait: closed at once with
+            # linger 0, it may never have sent.)
+            with zmq_context() as ctx:
+                push = ctx.socket(zmq.PUSH)
+                push.connect(
+                    f"tcp://127.0.0.1:{server.config.zmq_server_port}")
+                push.send(
+                    serialize_message(
+                        Message(
+                            instruction=Instruction.GLOBAL_MESSAGE,
+                            sender_uuid=uuid.uuid4(),
+                            world_name="@global",
+                            parameter="ghost",
+                        )
                     )
                 )
-            )
-            push.close()
-            ctx.term()
-
-            with pytest.raises(asyncio.TimeoutError):
-                await z1.recv_until(Instruction.GLOBAL_MESSAGE, timeout=0.5)
+                with pytest.raises(asyncio.TimeoutError):
+                    await z1.recv_until(
+                        Instruction.GLOBAL_MESSAGE, timeout=0.5)
             await z1.close()
         finally:
             await server.stop()
@@ -401,18 +402,13 @@ def test_oversized_zmq_frame_cannot_exhaust_memory():
             await asyncio.sleep(0.1)
 
             # raw oversized frame straight at the PULL socket
-            import zmq as zmq_mod
-            import zmq.asyncio as zmq_aio
-            ctx = zmq_aio.Context()
-            hostile = ctx.socket(zmq_mod.PUSH)
-            hostile.setsockopt(zmq_mod.LINGER, 0)
-            hostile.connect(
-                f"tcp://127.0.0.1:{server.config.zmq_server_port}"
-            )
-            await hostile.send(b"\xff" * (1024 * 1024))
-            await asyncio.sleep(0.2)
-            hostile.close(linger=0)
-            ctx.term()
+            with zmq_context(zmq.asyncio.Context) as ctx:
+                hostile = ctx.socket(zmq.PUSH)
+                hostile.connect(
+                    f"tcp://127.0.0.1:{server.config.zmq_server_port}"
+                )
+                await hostile.send(b"\xff" * (1024 * 1024))
+                await asyncio.sleep(0.2)
 
             # the server still serves the well-behaved peer
             await z1.send(Message(
@@ -473,6 +469,78 @@ def test_oversized_ws_frame_closes_only_that_connection():
 
 
 # region: the ZeroMQ peer's synchronous write path (ISSUE 25)
+
+
+def test_a_client_nobody_closed_is_ended_after_its_test_not_by_the_collector():
+    """What ``tests/conftest.py`` does after every test: a connected
+    ``ZmqClient`` stays strongly held until it is closed, so the
+    collector never finalizes its context ahead of its sockets (that
+    ``term()`` waits for ever), and ``close_leftovers`` ends it."""
+    async def scenario():
+        server = make_server(http_enabled=False, ws_enabled=False)
+        await server.start()
+        try:
+            closed = await ZmqClient.connect(server.config.zmq_server_port)
+            await closed.close()
+            return await ZmqClient.connect(server.config.zmq_server_port)
+        finally:
+            await server.stop()
+
+    forgotten = run(scenario())
+    assert ZmqClient._open == {forgotten} and not forgotten.push.closed
+    ZmqClient.close_leftovers()
+    assert not ZmqClient._open
+    assert forgotten.push.closed and forgotten.pull.closed
+
+
+def test_zmq_stop_closes_a_socket_that_no_list_names():
+    """A socket of the transport's context that ``_push_sockets`` does
+    not hold (what a handshake cancelled before its last line leaves
+    behind) is closed with the rest: ``stop()`` returns, and a server
+    comes down on SIGTERM. A bare ``term()`` waited for it for ever."""
+    async def scenario(stopping):
+        server = make_server(http_enabled=False, ws_enabled=False)
+        await server.start()
+        [transport] = server._transports
+        orphan = transport.ctx.socket(zmq.PUSH)
+        orphan.connect(f"tcp://127.0.0.1:{free_port()}")
+        stopping.set()
+        await server.stop()
+        return transport.ctx.closed, orphan.closed
+
+    assert stops_on_a_thread(scenario) == (True, True)
+
+
+def test_zmq_stop_ends_a_handshake_that_still_awaits_its_echo():
+    """The receive task is cancelled inside ``_handshake``, between the
+    peer's PUSH socket being made and being filed: ``stop()`` closes
+    that socket too."""
+    async def scenario(stopping):
+        server = make_server(
+            http_enabled=False, ws_enabled=False, session_ttl=60.0)
+        await server.start()
+        [transport] = server._transports
+        # the echo's PUSH gets no pipe before its peer listens, and
+        # nobody ever does: the send waits
+        transport.ctx.setsockopt(zmq.IMMEDIATE, 1)
+        ident = uuid.uuid4()
+        with zmq_context(zmq.asyncio.Context) as ctx:
+            push = ctx.socket(zmq.PUSH)
+            push.connect(f"tcp://127.0.0.1:{server.config.zmq_server_port}")
+            await push.send(serialize_message(Message(
+                instruction=Instruction.HANDSHAKE, sender_uuid=ident,
+                parameter=f"127.0.0.1:{free_port()}")))
+            # (the token is minted on the handshake's way to that send)
+            assert await wait_for(
+                lambda: server.sessions.get(ident) is not None)
+            await asyncio.sleep(0.1)
+            assert ident not in server.peer_map
+            assert not transport._push_sockets
+            stopping.set()
+            await server.stop()
+        return transport.ctx.closed
+
+    assert stops_on_a_thread(scenario) is True
 
 
 @contextlib.asynccontextmanager

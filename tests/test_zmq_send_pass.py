@@ -16,7 +16,7 @@ import pytest
 import zmq
 import zmq.asyncio
 
-from tests.client_util import ZmqClient, free_port
+from tests.client_util import ZmqClient, dead_port, free_port, zmq_context
 from tests.test_robustness_zmq import wait_for
 from tests.test_transports import (
     frames, nothing_more, recv_parameters, run, zmq_served,
@@ -65,25 +65,24 @@ def closure_pass(payloads, sockets, table):
 
 class Rig:
     """``n`` PUSH sockets dialled to ``n`` PULL sockets of the same
-    context over loopback. Peer ``full`` dials a port nobody listens
-    at with ``SNDHWM`` 3, so it takes exactly 3 frames; peer
-    ``broken`` offers a PULL socket, which refuses every send."""
+    context (a ``zmq_context()``, which closes them) over loopback.
+    Peer ``full`` dials ``dead``, a port nobody listens at, with
+    ``SNDHWM`` 3, so it takes exactly 3 frames; peer ``broken`` offers
+    a PULL socket, which refuses every send."""
 
-    def __init__(self, n, full=None, broken=None):
-        self.ctx = zmq.Context()
+    def __init__(self, ctx, n, full=None, broken=None, dead=None):
         self.pulls, self.senders = [], []
         for p in range(n):
-            pull = self.ctx.socket(zmq.PULL)
+            pull = ctx.socket(zmq.PULL)
             port = pull.bind_to_random_port("tcp://127.0.0.1")
             self.pulls.append(pull)
             if p == broken:
                 self.senders.append(pull)
                 continue
-            push = self.ctx.socket(zmq.PUSH)
-            push.setsockopt(zmq.LINGER, 0)
+            push = ctx.socket(zmq.PUSH)
             if p == full:
                 push.setsockopt(zmq.SNDHWM, HWM)
-                port = free_port()
+                port = dead
             push.connect(f"tcp://127.0.0.1:{port}")
             self.senders.append(push)
         self.silent = {full, broken}
@@ -101,11 +100,6 @@ class Rig:
                 assert not pull.poll(50)
             out.append(got)
         return out
-
-    def close(self):
-        for sock in (*self.senders, *self.pulls):
-            sock.close(linger=0)
-        self.ctx.term()
 
 
 def table_of(seed, n_peers, n_msgs):
@@ -148,8 +142,9 @@ def test_pass_equals_the_closure_on_the_same_table(
         table[full] = list(range(n_msgs))       # more than it can take
     if broken is not None:
         table[broken] = table[broken] or [0]
-    native, closure = (Rig(n_peers, full, broken) for _ in range(2))
-    try:
+    with zmq_context() as ctx, dead_port() as dead:
+        native, closure = (
+            Rig(ctx, n_peers, full, broken, dead) for _ in range(2))
         total, taken, errs = send_pass(
             payloads, [s.underlying for s in native.senders], table)
         want = closure_pass(payloads, closure.senders, table)
@@ -167,9 +162,6 @@ def test_pass_equals_the_closure_on_the_same_table(
                     for p, owed in enumerate(table)]
         assert native.received(table) == expected
         assert closure.received(table) == expected
-    finally:
-        native.close()
-        closure.close()
 
 
 @pytest.mark.parametrize("wrap", [bytes, bytearray, memoryview])
@@ -177,15 +169,13 @@ def test_pass_takes_any_buffer_a_socket_would(send_pass, wrap):
     """The closure's ``send`` takes any buffer; so does the pass (a
     payload that is not ``bytes`` is copied once, zero bytes and all)."""
     payloads = [b"a\x00b", b"", b"c" * 300]
-    rig = Rig(2)
-    try:
+    with zmq_context() as ctx:
+        rig = Rig(ctx, 2)
         total, taken, errs = send_pass(
             [wrap(p) for p in payloads],
             [s.underlying for s in rig.senders], [[0, 1, 2], [2]])
         assert (total, list(taken), list(errs)) == (4, [3, 1], [0, 0])
         assert rig.received([[0, 1, 2], [2]]) == [payloads, [payloads[2]]]
-    finally:
-        rig.close()
 
 
 def test_pass_of_no_peers_sends_nothing(send_pass):
@@ -215,32 +205,27 @@ async def dial_dead_port(server, hwm):
     [transport] = server._transports
     port, ident = free_port(), uuid.uuid4()
     transport.ctx.setsockopt(zmq.SNDHWM, hwm)
-    ctx = zmq.asyncio.Context()
-    push = ctx.socket(zmq.PUSH)
-    push.connect(f"tcp://127.0.0.1:{server.config.zmq_server_port}")
     try:
-        await push.send(serialize_message(Message(
-            instruction=Instruction.HANDSHAKE, sender_uuid=ident,
-            parameter=f"127.0.0.1:{port}")))
-        assert await wait_for(lambda: ident in server.peer_map)
+        with zmq_context(zmq.asyncio.Context) as ctx:
+            push = ctx.socket(zmq.PUSH)
+            push.connect(
+                f"tcp://127.0.0.1:{server.config.zmq_server_port}")
+            await push.send(serialize_message(Message(
+                instruction=Instruction.HANDSHAKE, sender_uuid=ident,
+                parameter=f"127.0.0.1:{port}")))
+            assert await wait_for(lambda: ident in server.peer_map)
     finally:
-        push.close(linger=0)
-        ctx.term()
         transport.ctx.setsockopt(zmq.SNDHWM, 1000)
     return ident, port
 
 
 async def drain_port(port, n):
     """Bind the dead port at last and read ``n`` messages."""
-    ctx = zmq.asyncio.Context()
-    pull = ctx.socket(zmq.PULL)
-    pull.bind(f"tcp://127.0.0.1:{port}")
-    try:
+    with zmq_context(zmq.asyncio.Context) as ctx:
+        pull = ctx.socket(zmq.PULL)
+        pull.bind(f"tcp://127.0.0.1:{port}")
         return [deserialize_message(
             await asyncio.wait_for(pull.recv(), 10)) for _ in range(n)]
-    finally:
-        pull.close(linger=0)
-        ctx.term()
 
 
 @pytest.mark.usefixtures("threads")

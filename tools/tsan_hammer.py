@@ -167,9 +167,7 @@ def hammer(threads: int, iters: int) -> int:
                     raise AssertionError(
                         f"send pass corrupted under concurrency: "
                         f"{total} {list(taken)} {list(errs)}")
-            for sock in (*pushes, *pulls):
-                sock.close(linger=0)
-            ctx.term()
+            ctx.destroy(linger=0)   # (this thread's own sockets)
         except Exception as exc:  # noqa: BLE001 — reported, not dropped
             errors.append(f"thread {tid}: {type(exc).__name__}: {exc}")
 

@@ -138,6 +138,7 @@ def _receiver(n_socks: int, conn) -> None:
             while time.monotonic() < deadline:
                 drain(50)
             conn.send(got)
+            ctx.destroy(linger=0)
             return
         drain(20)
 
@@ -257,6 +258,7 @@ def probe(peers: int, passes: int, threads: list[int]) -> list[dict]:
     for p, here in procs:
         got += here.recv()
         p.join(5)
+    ctx.destroy(linger=0)
     print(json.dumps({"sent": sent, "received": got}), flush=True)
     assert got == sent, (got, sent)
     return lines

@@ -273,13 +273,11 @@ class ClusterRouter:
         if self._http_runner is not None:
             await self._http_runner.cleanup()
             self._http_runner = None
-        for push in self._push:
-            push.close(linger=0)
+        # every socket of the context, a refusal hint's in flight
+        # too (see ZmqTransport.stop); all of them are this loop's
         self._push.clear()
-        if self._pull is not None:
-            self._pull.close(linger=0)
-            self._pull = None
-        self.ctx.term()
+        self._pull = None
+        self.ctx.destroy(linger=0)
 
     # endregion
 

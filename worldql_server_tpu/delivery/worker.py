@@ -498,6 +498,7 @@ def worker_main(worker_id: int, control_path: str, ring_name: str,
         for sink in sinks.values():
             sink.close()
         if zmq_ctx is not None:
-            zmq_ctx.term()
+            # (its sockets are this one thread's: the sinks' above)
+            zmq_ctx.destroy(linger=0)
         ring.close()
         ctl.close()

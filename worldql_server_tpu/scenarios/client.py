@@ -126,7 +126,6 @@ class ZmqPeer:
 
     def close(self) -> None:
         """Hard drop: sockets die with no goodbye — the network-blip
-        shape the session plane exists for."""
-        self.push.close(linger=0)
-        self.pull.close(linger=0)
-        self.ctx.term()
+        shape the session plane exists for. (Both sockets are the
+        calling loop's; nothing else touches them.)"""
+        self.ctx.destroy(linger=0)

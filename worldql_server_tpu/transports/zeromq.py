@@ -209,13 +209,18 @@ class ZmqTransport:
             # what the recv loop held and no flush staged (the ticker
             # stops first): routed while the peers' sockets are open
             await fast.stage(self._route_data)
-        for sock in self._push_sockets.values():
-            sock.close(linger=0)
+        # Every socket of the context, linger 0: the peers' PUSH, the
+        # PULL, and whatever no list names (a handshake cancelled
+        # between ``ctx.socket()`` and ``_push_sockets``, a refusal
+        # hint in flight). A bare ``term()`` waits for those for ever,
+        # on the thread whose collector might have closed them.
+        # ``destroy`` closes from this thread, and no other touches
+        # these sockets: they are the loop's, and the send pass's
+        # helper threads live inside one ``wql_send_pass`` call that
+        # this thread made and has returned from.
         self._push_sockets.clear()
-        if self._pull is not None:
-            self._pull.close(linger=0)
-            self._pull = None
-        self.ctx.term()
+        self._pull = None
+        self.ctx.destroy(linger=0)
 
     async def _recv_loop(self) -> None:
         """PULL loop (incoming.rs:26-75): multipart frames are
