@@ -725,79 +725,253 @@ extern "C" int wql_encode_entity_frames(
   return WQL_OK;
 }
 
-// Encode ONE interest-managed frame (ISSUE 18): a LocalMessage whose
-// parameter is the caller's stamped "entity.frame.{full,fullc,delta}"
-// string, carrying n entities of one world — live entries as
-// positioned entities, departures (tomb[i] != 0) as the same entity
-// at its last-known position plus a 1-byte flex tombstone marker
-// (short flex is ignored by the velocity decode, so pre-interest
-// readers see a harmless entity). The sender is the NIL uuid: these
-// frames originate from the server, not a peer. Byte-identical to
-// wql_encode / serialize_message of the equivalent Message (same
-// builder, same write order, entities field omitted when n == 0 like
-// the object encoders omit empty vectors). One malloc'd buffer; free
-// with wql_buffer_free.
-extern "C" int wql_encode_interest_frame(
-    const uint8_t* param, int32_t param_len, const uint8_t* world,
-    int32_t world_len, const uint8_t* ent_keys, const double* pos,
-    const uint8_t* tomb, int64_t n, uint8_t** out, int64_t* out_len) {
-  static const uint8_t NIL36[] = "00000000-0000-0000-0000-000000000000";
-  static const uint8_t TOMB1[] = {0};
-  if (n < 0 || param == nullptr || world == nullptr || out == nullptr ||
-      out_len == nullptr)
-    return WQL_E_BOUNDS;
+// Interest-managed frames (ISSUE 18; batched from records, ISSUE 44).
+//
+// An interest frame is a LocalMessage whose parameter is the caller's
+// stamped "entity.frame.{full,fullc,delta}" string, carrying n entities
+// of one world — live entries as positioned entities, departures
+// (tomb[i] != 0) as the same entity at its last-known position plus a
+// 1-byte flex tombstone marker (short flex is ignored by the velocity
+// decode, so pre-interest readers see a harmless entity). The sender is
+// the NIL uuid: these frames originate from the server, not a peer.
+//
+// Such an entity has only fixed-length parts, and every offset inside a
+// table is relative, so the bytes write_obj emits for it depend on
+// nothing but (tombstone?, the frame's world, Builder::offset() & 7
+// before the call): the alignment pads are functions of the offset
+// mod 8 and all else is the input. EntityRecords keeps those bytes per
+// (offset & 7, tombstone) as the GENERIC write_obj wrote them on a side
+// Builder padded to that state; an entity is then one memcpy of its
+// record, its uuid unparsed into place and 24 bytes of position. A
+// change of write_obj changes the records with it.
 
-  Builder b(512 + static_cast<size_t>(n) * 160);
-  size_t entities_vec = 0;
-  if (n > 0) {
-    // write_obj_vector without the WQL_MAX_OBJS staging array: frames
-    // are chunked by the caller but the encoder itself has no cap
-    std::vector<size_t> offs(static_cast<size_t>(n));
-    std::vector<uint8_t> keys36(static_cast<size_t>(n) * 36);
-    for (int64_t i = 0; i < n; i++) {
-      uint8_t* ent36 = keys36.data() + 36 * i;
-      unparse_uuid(ent_keys + 16 * i, ent36);
-      const double* p = pos + 3 * i;
-      WqlObj ent;
-      std::memset(&ent, 0, sizeof(ent));
-      ent.uuid = ent36;
-      ent.uuid_len = 36;
-      ent.world = world;
-      ent.world_len = world_len;
-      ent.has_pos = 1;
-      ent.x = p[0];
-      ent.y = p[1];
-      ent.z = p[2];
-      if (tomb != nullptr && tomb[i]) {
-        ent.flex = TOMB1;
-        ent.flex_len = 1;
-      }
-      offs[static_cast<size_t>(i)] = write_obj(b, &ent);
+namespace {
+
+struct EntityRecords {
+  // more than write_obj emits for one such entity, less its world: four
+  // blobs' pads and lengths, a padded Vec3, four uoffsets, the soffset
+  // and a five-slot vtable
+  static constexpr size_t kBound = 160;
+  struct Record {
+    const uint8_t* bytes = nullptr;  // into `store`; null = not built
+    uint32_t len = 0;                // bytes write_obj emitted
+    uint32_t uuid_at = 0;            // the 36 uuid chars, from the front
+    uint32_t pos_at = 0;             // the 24 position bytes
+    uint32_t table_rel = 0;          // table offset-from-end less the state
+  };
+  const uint8_t* world = nullptr;
+  int32_t world_len = -1;
+  std::vector<uint8_t> store;
+  size_t used = 0;
+  Record recs[8][2];
+
+  // Records are per world: another world drops them.
+  void bind(const uint8_t* w, int32_t wl) {
+    if (wl == world_len && (wl == 0 || std::memcmp(w, world, wl) == 0)) {
+      world = w;  // equal bytes, but the caller's pointer of THIS frame
+      return;
     }
-    b.prep(4, static_cast<size_t>(n) * 4);
-    for (int64_t i = n - 1; i >= 0; i--)
-      b.push_uoffset(offs[static_cast<size_t>(i)]);
-    b.push_scalar<uint32_t>(static_cast<uint32_t>(n));
-    entities_vec = b.offset();
+    world = w;
+    world_len = wl;
+    used = 0;
+    const size_t all = 16 * (kBound + static_cast<size_t>(wl));
+    if (store.size() < all) store.resize(all);
+    for (auto& row : recs)
+      for (auto& rec : row) rec = Record();
   }
-  size_t param_off = b.create_blob(param, param_len, true);
-  size_t sender_off = b.create_blob(NIL36, 36, true);
-  size_t world_off = b.create_blob(world, world_len, true);
-  TableBuilder t(b);
-  t.field_u8(MSG_INSTRUCTION, INSTR_LOCAL_MESSAGE, 0);
-  t.field_uoffset(MSG_PARAMETER, param_off);
-  t.field_uoffset(MSG_SENDER, sender_off);
-  t.field_uoffset(MSG_WORLD, world_off);
-  if (entities_vec != 0) t.field_uoffset(MSG_ENTITIES, entities_vec);
-  size_t root = t.end();
-  b.prep(std::max<size_t>(b.minalign, 4), 4);
-  b.push_uoffset(root);
 
-  const size_t len = b.offset();
-  uint8_t* mem = static_cast<uint8_t*>(std::malloc(len ? len : 1));
+  // write_obj's bytes for one sentinel entity at `state`.
+  void emit(Builder& sb, size_t state, bool tomb, uint8_t fill,
+            size_t* table) const {
+    static const uint8_t TOMB1[] = {0};
+    uint8_t uuid36[36];
+    std::memset(uuid36, fill, sizeof(uuid36));
+    uuid36[8] = uuid36[13] = uuid36[18] = uuid36[23] = '-';
+    sb.head = sb.store.size();
+    sb.pad(state);
+    WqlObj ent;
+    std::memset(&ent, 0, sizeof(ent));
+    ent.uuid = uuid36;
+    ent.uuid_len = 36;
+    ent.world = world;
+    ent.world_len = world_len;
+    ent.has_pos = 1;
+    std::memset(&ent.x, fill, sizeof(double));
+    ent.y = ent.x;
+    ent.z = ent.x;
+    if (tomb) {
+      ent.flex = TOMB1;
+      ent.flex_len = 1;
+    }
+    *table = write_obj(sb, &ent);
+  }
+
+  // The record of (state, tomb), built at first use: write_obj runs
+  // twice with different sentinels, and the bytes that differ ARE the
+  // uuid's and the position's places, whatever the world holds.
+  const Record* get(size_t state, bool tomb) {
+    Record& rec = recs[state][tomb ? 1 : 0];
+    if (rec.bytes != nullptr) return &rec;
+    Builder a(256 + static_cast<size_t>(world_len));
+    Builder b(256 + static_cast<size_t>(world_len));
+    size_t table_a, table_b;
+    emit(a, state, tomb, '0', &table_a);
+    emit(b, state, tomb, 'f', &table_b);
+    const size_t len = a.offset() - state;
+    if (b.offset() - state != len || table_a != table_b ||
+        used + len > store.size())
+      return nullptr;
+    const uint8_t* pa = a.store.data() + a.head;
+    const uint8_t* pb = b.store.data() + b.head;
+    size_t at = 0;
+    while (at < len && pa[at] == pb[at]) at++;
+    const size_t pos_at = at;          // the table precedes the blobs
+    while (at < len && pa[at] != pb[at]) at++;
+    if (at - pos_at != 24) return nullptr;
+    while (at < len && pa[at] == pb[at]) at++;
+    const size_t uuid_at = at;         // 8-4-4-4-12, the dashes equal
+    size_t differ = 0;
+    for (; at < len; at++) differ += pa[at] != pb[at];
+    if (uuid_at + 36 > len || differ != 32 ||
+        pa[uuid_at + 35] == pb[uuid_at + 35])
+      return nullptr;
+    uint8_t* dst = store.data() + used;
+    std::memcpy(dst, pa, len);
+    used += len;
+    rec.bytes = dst;
+    rec.len = static_cast<uint32_t>(len);
+    rec.uuid_at = static_cast<uint32_t>(uuid_at);
+    rec.pos_at = static_cast<uint32_t>(pos_at);
+    rec.table_rel = static_cast<uint32_t>(table_a - state);
+    return &rec;
+  }
+};
+
+// 16 key bytes as the 32 hex digits of a canonical uuid string whose
+// dashes are already in place (the record's own).
+inline void put_uuid_digits(const uint8_t* key, uint8_t* out36) {
+  static const char hexd[] = "0123456789abcdef";
+  static const int at[16] = {0,  2,  4,  6,  9,  11, 14, 16,
+                             19, 21, 24, 26, 28, 30, 32, 34};
+  for (int i = 0; i < 16; i++) {
+    out36[at[i]] = hexd[key[i] >> 4];
+    out36[at[i] + 1] = hexd[key[i] & 0xF];
+  }
+}
+
+inline void store_u32(uint8_t* at, size_t v) {
+  const uint32_t w = static_cast<uint32_t>(v);
+  std::memcpy(at, &w, 4);
+}
+
+}  // namespace
+
+// Encode n_frames interest frames in ONE pass (the one export for
+// them). Frame f: parameter params[f], world worlds[f], and the
+// entities [bounds[f], bounds[f + 1]) of three shared columns: [N,16]
+// u8 uuid keys, [N,3] f64 positions, [N] u8 tombstone flags. Every
+// frame is byte-identical to wql_encode / serialize_message of the
+// equivalent Message (same write order; the entities field omitted when
+// a frame has none, as the object encoders omit empty vectors), and
+// does not depend on its neighbours in the batch. Entities are written
+// from records (EntityRecords); the vector, the three blobs and the
+// root table by the generic Builder on a side buffer padded to the
+// frame's alignment state. Frames are laid back to front into one
+// malloc'd buffer, each where it stays: frame f is
+// (*out)[out_off[f] .. +out_len[f]], its parameter's first byte at
+// out_param[f] within it (the caller patches its stamp there). Returns
+// the entities written from records (every entity of the batch), or a
+// negative error. Free with wql_buffer_free.
+extern "C" int64_t wql_encode_interest_frames(
+    int64_t n_frames, const uint8_t* const* params, const int32_t* param_lens,
+    const uint8_t* const* worlds, const int32_t* world_lens,
+    const int64_t* bounds, const uint8_t* ent_keys, const double* pos,
+    const uint8_t* tomb, uint8_t** out, int64_t* out_off, int64_t* out_len,
+    int64_t* out_param) {
+  static const uint8_t NIL36[] = "00000000-0000-0000-0000-000000000000";
+  if (n_frames < 0 || out == nullptr) return WQL_E_BOUNDS;
+
+  // an upper bound a frame: nothing is written past what it uses
+  size_t cap = 8;
+  for (int64_t f = 0; f < n_frames; f++) {
+    const int64_t n = bounds[f + 1] - bounds[f];
+    if (n < 0 || n > INT32_MAX || param_lens[f] < 0 || world_lens[f] < 0 ||
+        params[f] == nullptr || worlds[f] == nullptr)
+      return WQL_E_BOUNDS;
+    cap += 256 + static_cast<size_t>(param_lens[f]) +
+           static_cast<size_t>(world_lens[f]) +
+           static_cast<size_t>(n) *
+               (EntityRecords::kBound + static_cast<size_t>(world_lens[f]));
+  }
+  uint8_t* mem = static_cast<uint8_t*>(std::malloc(cap));
   if (!mem) return WQL_E_ALLOC;
-  std::memcpy(mem, b.store.data() + b.head, len);
+
+  EntityRecords records;
+  Builder head(512);
+  std::vector<size_t> offs;
+  int64_t recorded = 0;
+  uint8_t* end = mem + cap;         // one past the frame being written
+  for (int64_t f = n_frames - 1; f >= 0; f--) {
+    const int64_t lo = bounds[f];
+    const int64_t n = bounds[f + 1] - lo;
+    const uint8_t* world = worlds[f];
+    const int32_t world_len = world_lens[f];
+    size_t off = 0;                 // bytes of this frame so far, from `end`
+    if (n > 0) {
+      records.bind(world, world_len);
+      offs.resize(static_cast<size_t>(n));
+      for (int64_t i = 0; i < n; i++) {
+        const EntityRecords::Record* rec =
+            records.get(off & 7, tomb != nullptr && tomb[lo + i] != 0);
+        if (rec == nullptr) { std::free(mem); return WQL_E_BOUNDS; }
+        uint8_t* at = end - off - rec->len;
+        std::memcpy(at, rec->bytes, rec->len);
+        put_uuid_digits(ent_keys + 16 * (lo + i), at + rec->uuid_at);
+        std::memcpy(at + rec->pos_at, pos + 3 * (lo + i), 24);
+        offs[static_cast<size_t>(i)] = off + rec->table_rel;
+        off += rec->len;
+      }
+      recorded += n;
+      // the vector of table offsets: prep(4, 4n), last entity first
+      const size_t padding = (~off + 1) & 3;
+      std::memset(end - off - padding, 0, padding);
+      off += padding;
+      for (int64_t i = n - 1; i >= 0; i--) {
+        off += 4;
+        store_u32(end - off, off - offs[static_cast<size_t>(i)]);
+      }
+      off += 4;
+      store_u32(end - off, static_cast<size_t>(n));
+    }
+    // parameter, sender, world, root: the generic writer, on a side
+    // Builder that stands where this frame stands mod 8 (n == 0: at 0,
+    // and no Vec3 has raised minalign)
+    const size_t state = off & 7;
+    head.head = head.store.size();
+    head.minalign = n > 0 ? 8 : 1;
+    head.pad(state);
+    size_t param_off = head.create_blob(params[f], param_lens[f], true);
+    size_t sender_off = head.create_blob(NIL36, 36, true);
+    size_t world_off = head.create_blob(world, world_len, true);
+    TableBuilder t(head);
+    t.field_u8(MSG_INSTRUCTION, INSTR_LOCAL_MESSAGE, 0);
+    t.field_uoffset(MSG_PARAMETER, param_off);
+    t.field_uoffset(MSG_SENDER, sender_off);
+    t.field_uoffset(MSG_WORLD, world_off);
+    if (n > 0) t.field_uoffset(MSG_ENTITIES, state);
+    size_t root = t.end();
+    head.prep(std::max<size_t>(head.minalign, 4), 4);
+    head.push_uoffset(root);
+    const size_t head_len = head.offset() - state;
+    std::memcpy(end - off - head_len, head.store.data() + head.head,
+                head_len);
+    const size_t len = off + head_len;
+    out_off[f] = (end - len) - mem;
+    out_len[f] = static_cast<int64_t>(len);
+    // the blob's chars follow its u32 length
+    out_param[f] = static_cast<int64_t>(len - (off + param_off - state) + 4);
+    end -= len;
+  }
   *out = mem;
-  *out_len = static_cast<int64_t>(len);
-  return WQL_OK;
+  return recorded;
 }

@@ -278,6 +278,52 @@ def test_cohort_dedup_shares_template_across_recipients():
     assert ra.stats()["deltas_refused"] == rb.stats()["deltas_refused"] == 0
 
 
+def test_a_cohort_is_its_every_byte_not_its_first_entry():
+    """Two frames that start alike (same kind, world, length, first
+    uuid and first position: the cheap mark the lookup goes by) and
+    differ further on are two cohorts, this tick and the next."""
+    plane = FakePlane()
+    mgr = InterestManager()
+    a, b = uuid.UUID(int=101), uuid.UUID(int=102)
+    pa, pb = plane.pid(a), plane.pid(b)
+    shared, only_a, only_b = (uuid.UUID(int=i) for i in (1, 2, 3))
+    plane.put(0, shared, (1, 1, 1))
+    plane.put(1, only_a, (2, 2, 2))
+    plane.put(2, only_b, (2, 2, 2))
+    vis = {0: [pa, pb], 1: [pa], 2: [pb]}
+    pairs = run_tick(mgr, plane, vis)
+    assert mgr.templates_reused == 0
+    seen = {t[0]: {e.uuid for e in m.entities} for m, t in pairs}
+    assert seen == {a: {shared, only_a}, b: {shared, only_b}}
+    # next tick: the second entry of each moves, to the same place
+    plane._pos[1] = plane._pos[2] = (5, 5, 5)
+    plane._pos[0] = (4, 4, 4)
+    pairs = run_tick(mgr, plane, vis)
+    assert mgr.templates_reused == 0 and len(pairs) == 2
+    seen = {t[0]: {e.uuid for e in m.entities} for m, t in pairs}
+    assert seen == {a: {shared, only_a}, b: {shared, only_b}}
+
+
+def test_a_template_of_the_last_tick_serves_the_same_content_again():
+    """A keyframe a late joiner needs is, byte for byte, the one the
+    first peer got a tick ago: last tick's template is reused."""
+    plane = FakePlane()
+    mgr = InterestManager()
+    a, b = uuid.uuid4(), uuid.uuid4()
+    pa, pb = plane.pid(a), plane.pid(b)
+    plane.put(0, uuid.uuid4(), (3, 3, 3))
+    (first, _), = run_tick(mgr, plane, {0: [pa]})
+    assert mgr.templates_reused == 0
+    (second, to), = run_tick(mgr, plane, {0: [pa, pb]})
+    assert to == [b] and mgr.templates_reused == 1
+    assert second.wire == first.wire        # both at epoch 1, seq 0
+    # ... and is gone a tick later: the cache holds one tick
+    c = uuid.uuid4()
+    run_tick(mgr, plane, {0: [pa, pb]})
+    run_tick(mgr, plane, {0: [pa, pb, plane.pid(c)]})
+    assert mgr.templates_reused == 1
+
+
 def test_desynced_cursor_stamps_diverge_but_both_converge():
     plane = FakePlane()
     mgr = InterestManager()
@@ -839,6 +885,248 @@ def test_template_native_matches_object_path_byte_for_byte():
         buf[native[2]:native[2] + 8] = b"00000005"
         msg = deserialize_message(bytes(buf))
         assert parse_stamp(msg.parameter) == (PARAM_DELTA, 10, 5)
+
+
+def _native_wire():
+    from worldql_server_tpu.protocol import entity_wire
+
+    wire = entity_wire.shared()
+    if wire is None or not wire.can_encode_interest:
+        pytest.skip("native interest encoder unavailable")
+    return wire
+
+
+def _frame_columns(rng, n, share):
+    """``n`` entries with random keys, tombstones at ``share``, and the
+    positions a compare by value would get wrong in the first rows."""
+    keys = rng.integers(0, 256, (n, 16), dtype=np.uint8)
+    pos = rng.normal(0.0, 300.0, (n, 3)).astype(np.float32)
+    odd = np.array([[-0.0, np.nan, 1e-45], [np.inf, -1e-40, 0.0]], np.float32)
+    pos[:min(n, 2)] = odd[:n]
+    tomb = (rng.random(n) < share).astype(np.uint8)
+    if share == 1:
+        tomb[:] = 1
+    return keys, pos, tomb
+
+
+def _object_frame(kind, world, keys, pos, tomb):
+    """``serialize_message`` of the Message an interest frame is."""
+    from worldql_server_tpu.interest.manager import TOMBSTONE_FLEX
+    from worldql_server_tpu.protocol import serialize_message
+    from worldql_server_tpu.protocol.types import (
+        NIL_UUID, Entity, Instruction, Message, Vector3,
+    )
+
+    return serialize_message(Message(
+        instruction=Instruction.LOCAL_MESSAGE,
+        parameter=stamp(kind, 0, 0),
+        sender_uuid=NIL_UUID,
+        world_name=world,
+        entities=[
+            Entity(uuid=uuid.UUID(bytes=key.tobytes()),
+                   position=Vector3(*map(float, p)), world_name=world,
+                   flex=b"\x00" if dead else None)
+            for key, p, dead in zip(keys, pos, tomb)
+        ],
+    ))
+
+
+def _encode_batch(wire, frames):
+    """``[(kind, world, keys, pos, tomb)]`` through the batch export:
+    each frame's bytes, and the place the export names for its
+    parameter."""
+    bounds = np.concatenate(([0], np.cumsum([len(f[2]) for f in frames])))
+    got, at, recorded = wire.encode_interest_frames(
+        [stamp(f[0], 0, 0).encode() for f in frames],
+        [f[1].encode() for f in frames], bounds,
+        np.concatenate([f[2] for f in frames]),
+        np.concatenate([f[3] for f in frames]).astype(np.float64),
+        np.concatenate([f[4] for f in frames]),
+    )
+    assert recorded == bounds[-1]
+    return [bytes(frame) for frame in got], at
+
+
+@pytest.mark.parametrize("share", [0, 0.3, 1])
+@pytest.mark.parametrize("kind", [PARAM_DELTA, PARAM_FULL, PARAM_FULL_CONT])
+@pytest.mark.parametrize("n", [0, 1, 2, 3, 5, 8, 17, 263, 512])
+def test_batch_export_matches_serialize_message_byte_for_byte(n, kind, share):
+    """Every alignment state a frame can stand in: entity counts
+    around the 8-byte period, world names of 0-11 bytes, the three
+    stamped parameters, tombstones nowhere, mixed and everywhere;
+    -0.0, NaN, infinities and subnormals ride the first rows."""
+    wire = _native_wire()
+    rng = np.random.default_rng(n * 31 + len(kind))
+    for world_len in range(12):
+        world = ("wörld-nameXY" if world_len % 2 else "w" * 12)[:world_len]
+        keys, pos, tomb = _frame_columns(rng, n, share)
+        (got,), (at,) = _encode_batch(wire, [(kind, world, keys, pos, tomb)])
+        want = _object_frame(kind, world, keys, pos, tomb)
+        assert got == want, (n, kind, share, world)
+        # the stamp's place is the caller's own bytes, found once here
+        assert at == want.find(stamp(kind, 0, 0).encode())
+        if n:
+            back = deserialize_message(got)
+            assert len(back.entities) == n
+            assert [e.flex is not None for e in back.entities] \
+                == tomb.astype(bool).tolist()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_frame_of_a_batch_does_not_depend_on_its_neighbours(seed):
+    """Several frames of DIFFERENT worlds, kinds and sizes in one
+    call, an empty clear marker among them: each is what it is alone,
+    in every order."""
+    wire = _native_wire()
+    rng = np.random.default_rng(seed)
+    worlds = ["", "a", "arena", "annex-world", "arena"]
+    frames = []
+    for i in range(7):
+        n = int(rng.choice([0, 1, 2, 7, 64, 263, 512]))
+        kind = [PARAM_DELTA, PARAM_FULL, PARAM_FULL_CONT][int(rng.integers(3))]
+        world = worlds[int(rng.integers(len(worlds)))]
+        frames.append((kind, world) + _frame_columns(
+            rng, n, float(rng.choice([0, 0.3, 1]))))
+    frames.append((PARAM_FULL, "gone", ) + _frame_columns(rng, 0, 0))
+    want = [_object_frame(*f) for f in frames]
+    for order in (range(len(frames)), reversed(range(len(frames))),
+                  rng.permutation(len(frames)).tolist()):
+        order = list(order)
+        got, _at = _encode_batch(wire, [frames[i] for i in order])
+        assert got == [want[i] for i in order]
+
+
+class _CountedWire:
+    """The native wire, its interest encode calls counted."""
+
+    def __init__(self, wire):
+        self._wire = wire
+        self.calls = 0
+
+    can_encode_interest = True
+
+    def encode_interest_frames(self, *args):
+        self.calls += 1
+        return self._wire.encode_interest_frames(*args)
+
+
+def _churn_run(wire, budget):
+    """One seeded churn on the fake plane: entries, moves, departures
+    and world hops every tick, peers 0 and 1 with the same view (a
+    cohort), a resync of peer 2, and (``budget``) an empty bucket for
+    peer 3 over a dozen ticks. Returns every tick's ``(frame bytes,
+    recipients)``, the manager, its metrics and each tick's native
+    encode calls."""
+    rng = np.random.default_rng(44)
+    plane = FakePlane(cap=192, worlds=("arena", "annex-world"))
+    plane._wire = wire
+    metrics = Metrics()
+    now = [1000.0]
+    mgr = InterestManager(bandwidth_bytes=budget, metrics=metrics,
+                          clock=lambda: now[0])
+    peers = [uuid.UUID(int=i + 1) for i in range(5)]
+    pids = [plane.pid(p) for p in peers]
+    vis: dict[int, list[int]] = {}
+    ticks, calls = [], []
+    for tick in range(36):
+        now[0] += 0.05
+        for slot in rng.choice(192, 24, replace=False).tolist():
+            roll = rng.random()
+            if slot not in vis or roll < 0.25:      # enter, or re-key
+                seen = [p for p in pids[2:] if rng.random() < 0.5]
+                if rng.random() < 0.7:
+                    seen += pids[:2]                # the cohort, as one
+                plane.put(slot, uuid.UUID(bytes=rng.bytes(16)),
+                          rng.normal(0, 50, 3), wid=int(rng.integers(2)))
+                vis[slot] = seen or [pids[4]]
+            elif roll < 0.4:                        # leave
+                plane.drop(slot)
+                del vis[slot]
+            elif roll < 0.5:                        # hop world
+                plane._wid[slot] ^= 1
+            else:                                   # move
+                plane._pos[slot] += rng.normal(0, 1, 3).astype(np.float32)
+        if tick == 14:
+            mgr.mark_resync(peers[2])
+        if budget and 8 <= tick < 20 and peers[3] in mgr._peers:
+            mgr._peers[peers[3]].tokens = 0.0
+        before = getattr(wire, "calls", 0)
+        pairs = run_tick(mgr, plane, vis)
+        calls.append(getattr(wire, "calls", 0) - before)
+        ticks.append([(m.wire, tuple(t)) for m, t in pairs])
+    ledgers = [mgr.ledger(p, plane.pid(p)) for p in peers]
+    return ticks, mgr, metrics.snapshot()["counters"], calls, ledgers
+
+
+@pytest.mark.parametrize("budget", [0, 2000])
+def test_the_manager_frames_are_the_object_paths(budget):
+    """The same churn driven twice, through the batch export and
+    through the object encoder: every ``(frame bytes, recipient)``
+    pair equal and in order, the same cohort hits, deferrals, shed
+    bytes and ledgers; the native side makes at most ONE encode call
+    a tick and writes every entry it encodes from a record."""
+    wire = _CountedWire(_native_wire())
+    native, n_mgr, n_count, calls, n_ledgers = _churn_run(wire, budget)
+    obj, o_mgr, o_count, _none, o_ledgers = _churn_run(None, budget)
+    for tick, (a, b) in enumerate(zip(native, obj)):
+        assert a == b, tick
+    assert sum(map(len, native)) > 100
+    for name in ("templates_reused", "deferrals", "bytes_shed", "resyncs"):
+        assert getattr(n_mgr, name) == getattr(o_mgr, name), name
+    assert n_mgr.templates_reused > 0
+    if budget:
+        assert n_mgr.deferrals > 0 and n_mgr.bytes_shed > 0
+    assert n_ledgers == o_ledgers and any(n_ledgers)
+    assert max(calls) == 1
+    assert n_count["interest.encode_calls"] == sum(calls)
+    assert n_count["interest.entries_recorded"] \
+        == n_count["interest.entries_encoded"] \
+        == o_count["interest.entries_encoded"] > 0
+    assert "interest.encode_calls" not in o_count
+    assert "interest.entries_recorded" not in o_count
+    for name in ("interest.entries", "delta.frames_reused"):
+        assert n_count[name] == o_count[name]
+
+
+@pytest.mark.parametrize("name, want", [
+    ("interest_encode_calls_per_tick", 1.0),
+    ("interest_record_entry_share", 100.0),
+])
+def test_the_encode_metrics_read_their_counters_or_nothing(name, want):
+    """The two per-layer metrics of ISSUE 44 as the benchmark declares
+    them: read off a scrape that holds the three counters, and nothing
+    (no raise) off a server that has none, as this PR's parent is."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    entry, = [m for m in bench["per_layer"] if m["name"] == name]
+    spec = json.loads(
+        (root / "benchmark/layer_metrics" / f"{name}.json").read_text())
+    assert entry["workloads"] == ["entity-100k-even.random-walk"]
+    for key in ("layer", "unit", "moves", "better"):
+        assert entry[key] == spec[key], key
+    assert (entry["layer"], entry["moves"], entry["source"]) \
+        == ("entity plane", "deliver_p50_ms", "program_counter")
+    read = importlib.import_module(
+        f"benchmark.sources.{spec['source']['kind']}").read
+
+    def scrape(ticks, **counters):
+        return {"counters": {"tick.flushes": ticks, "interest.entries": 9,
+                             **{f"interest.{k}": v
+                                for k, v in counters.items()}}}
+
+    ctx = {"before": scrape(100), "after": scrape(1000),
+           "window_unix": (0.0, 45.0)}
+    assert read(spec["source"], ctx) is None
+    ctx = {"before": scrape(100, encode_calls=90, entries_encoded=5_000,
+                            entries_recorded=5_000),
+           "after": scrape(1000, encode_calls=990, entries_encoded=95_000,
+                           entries_recorded=95_000),
+           "window_unix": (0.0, 45.0)}
+    assert read(spec["source"], ctx) == want
 
 
 def test_interest_off_is_the_default_and_legacy_frames_unstamped():

@@ -207,3 +207,70 @@ def test_max_objs_boundary_roundtrips_and_overflow_is_counted(native):
     at_wire = codec.serialize_message(at_cap)
     codec.deserialize_message(at_wire)
     assert codec.codec_stats["obj_overflow"] == before  # boundary: native
+
+
+@pytest.mark.parametrize("counts", [[], [0], [10_000], [3, 0, 10_000, 1]])
+def test_interest_frames_batch_export_bounds(native_lib, counts):
+    """``wql_encode_interest_frames`` at its edges (zero frames, a frame
+    without entities, 10,000 entries past every object cap): the
+    sanitizer build runs this file, so the record writer's every store
+    is bounds-checked here. Frames are read back by the Python codec."""
+    import numpy as np
+
+    from worldql_server_tpu.protocol import entity_wire
+
+    wire = entity_wire.load()
+    assert wire is not None and wire.can_encode_interest
+    rng = np.random.default_rng(len(counts))
+    total = sum(counts)
+    keys = rng.integers(0, 256, (total, 16), dtype=np.uint8)
+    pos = rng.normal(0.0, 1e4, (total, 3))
+    tomb = (rng.random(total) < 0.25).astype(np.uint8)
+    params = [b"entity.frame.delta:%08x:%08x" % (f, 7)
+              for f in range(len(counts))]
+    worlds = [b"world-%d" % f * (f + 1) for f in range(len(counts))]
+    bounds = np.concatenate(([0], np.cumsum(counts, dtype=np.int64)))
+    frames, at, recorded = wire.encode_interest_frames(
+        params, worlds, bounds, keys, pos, tomb,
+    )
+    assert recorded == total
+    assert len(frames) == len(at) == len(counts)
+    for f, frame in enumerate(map(bytes, frames)):
+        assert frame[at[f]:at[f] + len(params[f])] == params[f]
+        msg = codec.py_deserialize_message(frame)
+        assert msg.instruction == Instruction.LOCAL_MESSAGE
+        assert msg.parameter == params[f].decode()
+        assert msg.sender_uuid == uuid.UUID(int=0)
+        assert msg.world_name == worlds[f].decode()
+        lo, hi = int(bounds[f]), int(bounds[f + 1])
+        assert len(msg.entities) == hi - lo
+        for row in {lo, (lo + hi) // 2, hi - 1} if hi > lo else ():
+            ent = msg.entities[row - lo]
+            assert ent.uuid.bytes == keys[row].tobytes()
+            assert ent.world_name == msg.world_name
+            assert (ent.position.x, ent.position.y, ent.position.z) \
+                == tuple(pos[row])
+            assert (ent.flex == b"\x00") == bool(tomb[row])
+
+
+@pytest.mark.parametrize("frames, bounds, rows", [
+    (2, [0, 5, 3], 5),      # a frame that ends before it starts
+    (1, [0, 6], 5),         # past the columns
+    (1, [-1, 2], 5),
+    (1, [0, 2, 4], 5),      # one bound too many
+])
+def test_interest_frames_batch_refuses_columns_that_do_not_fit(
+        native_lib, frames, bounds, rows):
+    """The pointers go to native code unchecked past this point: a
+    batch whose bounds leave the columns is refused in Python."""
+    import numpy as np
+
+    from worldql_server_tpu.protocol import entity_wire
+
+    wire = entity_wire.load()
+    with pytest.raises(ValueError):
+        wire.encode_interest_frames(
+            [b"p"] * frames, [b"w"] * frames, np.array(bounds),
+            np.zeros((rows, 16), np.uint8), np.zeros((rows, 3)),
+            np.zeros(rows, np.uint8),
+        )

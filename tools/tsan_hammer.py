@@ -5,10 +5,10 @@
     WQL_NATIVE_CODEC=native/libwqlcodec-tsan.so \
       python -m tools.tsan_hammer [--threads 8] [--iters 150]
 
-All five exported entry points release the GIL for their whole body
+All six exported entry points release the GIL for their whole body
 (``wql_decode_entities``, ``wql_encode_queries``,
-``wql_encode_entity_frames``, ``wql_areamap_probe``,
-``wql_send_pass``), so any hidden shared state inside
+``wql_encode_entity_frames``, ``wql_encode_interest_frames``,
+``wql_areamap_probe``, ``wql_send_pass``), so any hidden shared state inside
 ``native/codec.cpp`` / ``spatial.cpp`` / ``sendpass.cpp`` — a static
 scratch buffer, an unguarded counter, lazily-built tables — is a real
 data race the moment two event loops, a collect worker, and a bench
@@ -88,7 +88,8 @@ def hammer(threads: int, iters: int) -> int:
         print("tsan-hammer: native library not loaded — build "
               "native/ first (make -C native [tsan])", file=sys.stderr)
         return 2
-    if not (wire0.can_decode and wire0.can_encode_frames):
+    if not (wire0.can_decode and wire0.can_encode_frames
+            and wire0.can_encode_interest):
         print("tsan-hammer: stale library without the entity entry "
               "points", file=sys.stderr)
         return 2
@@ -114,6 +115,17 @@ def hammer(threads: int, iters: int) -> int:
                 b"".join(uuid.UUID(int=(tid << 64) | i).bytes
                          for i in range(n)),
                 np.uint8).reshape(n, 16)
+            # this thread's own batch of interest frames: three worlds,
+            # an empty frame, tombstones; its frames as one thread alone
+            # encodes them are what every later call must give
+            interest = (
+                [b"entity.frame.delta:%08x:%08x" % (tid, f)
+                 for f in range(4)],
+                [b"w", b"annex", b"", b"w"],
+                np.array([0, 9, 9, 13, n], np.int64),
+                keys, pos, (np.arange(n) % 3 == tid % 3).astype(np.uint8),
+            )
+            want_frames = wire.encode_interest_frames(*interest)
             # the flush's send pass: this thread's own sockets (a
             # libzmq socket belongs to one thread), the one library
             send_pass = zmq_pass.load()
@@ -151,13 +163,17 @@ def hammer(threads: int, iters: int) -> int:
                 frames = wire.encode_frames(keys, keys, pos, b"w")
                 if len(frames) != n or not all(frames):
                     raise AssertionError("encode_frames dropped a frame")
-                # 4. wql_areamap_probe (every few iters: it builds a
+                # 4. wql_encode_interest_frames
+                if wire.encode_interest_frames(*interest) != want_frames:
+                    raise AssertionError(
+                        "interest frames corrupted under concurrency")
+                # 5. wql_areamap_probe (every few iters: it builds a
                 # whole probe table per call)
                 if it % 16 == 0:
                     probe = native_keys.areamap_probe(64, 64, seed=tid)
                     if probe is not None and probe["matched_rows"] < 0:
                         raise AssertionError("areamap probe corrupt")
-                # 5. wql_send_pass
+                # 6. wql_send_pass
                 total, taken, errs = send_pass(payloads, handles, table)
                 got = [[pull.recv(zmq.DONTWAIT) for _ in owed]
                        for pull, owed in zip(pulls, table)]
@@ -183,7 +199,8 @@ def hammer(threads: int, iters: int) -> int:
         return 1
     print(f"tsan-hammer: OK — {threads} threads x {iters} iters over "
           "wql_decode_entities / wql_encode_queries / "
-          "wql_encode_entity_frames / wql_areamap_probe / wql_send_pass")
+          "wql_encode_entity_frames / wql_encode_interest_frames / "
+          "wql_areamap_probe / wql_send_pass")
     return 0
 
 

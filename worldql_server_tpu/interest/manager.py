@@ -125,6 +125,14 @@ def parse_stamp(parameter: str) -> tuple[str, int, int] | None:
         return None
 
 
+#: a cohort template's parameter: the kind's stamp with zeroed fields,
+#: which every recipient's copy overwrites with its own epoch and seq
+_PLACEHOLDER = {
+    kind: stamp(kind, 0, 0).encode()
+    for kind in (PARAM_FULL, PARAM_FULL_CONT, PARAM_DELTA)
+}
+
+
 class _WireFrame:
     """Pre-encoded outbound frame (mirror of entities.plane.WireFrame,
     local so the manager has no import cycle with the plane)."""
@@ -159,6 +167,19 @@ def pack_entries(entries: list) -> tuple:
         np.frombuffer(b"".join(e[2] for e in entries), np.float32).reshape(n, 3),
         np.fromiter((e[3] for e in entries), np.uint8, n),
     )
+
+
+def _same_content(cohorts, frame):
+    """The ``[frame, template]`` of ``cohorts`` whose frame holds
+    ``frame``'s entries byte for byte (bit for bit: -0.0 and NaN are
+    positions too), or None."""
+    for cohort in cohorts or ():
+        held = cohort[0]
+        if (held[2].tobytes() == frame[2].tobytes()
+                and held[3].tobytes() == frame[3].tobytes()
+                and held[4].tobytes() == frame[4].tobytes()):
+            return cohort
+    return None
 
 
 def _fitted(a: np.ndarray, shape: tuple, fill) -> np.ndarray:
@@ -277,7 +298,8 @@ class InterestManager:
         self._shed_level = 0
         self._tier_degraded = False
         #: cohort template cache, swapped wholesale per tick like the
-        #: plane's _frame_cache: content key -> (template, e_off, s_off)
+        #: plane's _frame_cache: a mark of the content -> the cohorts
+        #: that bear it, each ``[frame spec, (head, tail, size)]``
         self._templates: dict = {}
         #: the diff base of every synced peer, and how many rows list
         #: each pid in it (neither LOD cadences nor budgets: those defer
@@ -801,36 +823,53 @@ class InterestManager:
     def _encode_specs(self, plane, specs) -> list:
         """Encode every peer's frame specs with cross-peer cohort
         dedup, apply bandwidth admission, commit ledgers, and emit
-        delivery pairs."""
+        delivery pairs. Two passes: the first resolves every frame's
+        cohort key and queues the ones nothing holds, which ONE call
+        of :meth:`_encode_templates` encodes for the whole tick; the
+        second admits, stamps and commits on the encoded sizes."""
         next_templates: dict = {}
+        missed: list = []
+        keyed: list = []
+        for spec in specs:
+            cohorts = []
+            for frame in spec[2]:
+                kind, wid, keys, pos, tomb = frame
+                # a few of a frame's bytes find the cohorts it could
+                # belong to, all of its bytes settle it: hashing every
+                # frame's ~7.5 KB cost more than the lookup saved
+                mark = (kind, wid, len(tomb), keys[:1].tobytes(),
+                        pos[:1].tobytes())
+                held = next_templates.setdefault(mark, [])
+                cohort = _same_content(held, frame)
+                if cohort is None:
+                    cohort = _same_content(self._templates.get(mark), frame)
+                    # [frame, template]: None until encoded below
+                    cohort = [frame, None if cohort is None else cohort[1]]
+                    held.append(cohort)
+                    if cohort[1] is None:
+                        missed.append(cohort)
+                        cohorts.append(cohort)
+                        continue
+                cohorts.append(cohort)
+                self.templates_reused += 1
+                if self.metrics is not None:
+                    self.metrics.inc("delta.frames_reused")
+            keyed.append(cohorts)
+        if missed:
+            for cohort, tpl in zip(missed, self._encode_templates(
+                plane, [cohort[0] for cohort in missed],
+            )):
+                cohort[1] = tpl
+
         pairs = []
         entries_sent = 0
         now = self._clock()
         self.last_delta_frames = self.last_full_frames = 0
-        for u, st, frames, new_state, is_resync, complete in specs:
-            encoded = []
-            nbytes = 0
-            for kind, wid, keys, pos, tomb in frames:
-                ckey = (kind, wid, keys.tobytes(), pos.tobytes(),
-                        tomb.tobytes())
-                tpl = next_templates.get(ckey)
-                if tpl is None:
-                    tpl = self._templates.get(ckey)
-                    if tpl is not None:
-                        self.templates_reused += 1
-                        if self.metrics is not None:
-                            self.metrics.inc("delta.frames_reused")
-                else:
-                    self.templates_reused += 1
-                    if self.metrics is not None:
-                        self.metrics.inc("delta.frames_reused")
-                if tpl is None:
-                    tpl = self._encode_template(
-                        plane, kind, wid, keys, pos, tomb,
-                    )
-                next_templates[ckey] = tpl
-                encoded.append((kind, tpl))
-                nbytes += len(tpl[0])
+        for (u, st, frames, new_state, is_resync, complete), cohorts in zip(
+            specs, keyed,
+        ):
+            encoded = [cohort[1] for cohort in cohorts]
+            nbytes = sum(tpl[2] for tpl in encoded)
 
             if self.bandwidth_bytes and not self._afford(st, nbytes, now):
                 # lossless deferral: nothing sent, nothing committed —
@@ -841,7 +880,7 @@ class InterestManager:
                     st.demote += 1
                     self.last_demoted += 1
                 elif is_resync or st.resync or not any(
-                    k == PARAM_DELTA for k, _ in encoded
+                    frame[0] == PARAM_DELTA for frame in frames
                 ):
                     # bottom of the ladder AND the keyframe itself is
                     # unaffordable: the ONLY shed point, counted
@@ -854,17 +893,18 @@ class InterestManager:
                 st.epoch += 1
                 st.seq = 0
                 st.resync = False
-            for kind, (tpl, e_off, s_off) in encoded:
-                buf = bytearray(tpl)
-                buf[e_off:e_off + 8] = b"%08x" % (st.epoch & 0xFFFFFFFF)
-                buf[s_off:s_off + 8] = b"%08x" % (st.seq & 0xFFFFFFFF)
+            epoch = st.epoch & 0xFFFFFFFF
+            for frame, (head, tail, _size) in zip(frames, encoded):
+                # ONE copy a recipient: the template around its stamp
+                pairs.append((_WireFrame(b"".join((
+                    head, b"%08x:%08x" % (epoch, st.seq & 0xFFFFFFFF), tail,
+                ))), [u]))
                 st.seq += 1
-                pairs.append((_WireFrame(bytes(buf)), [u]))
-                if kind == PARAM_DELTA:
+                if frame[0] == PARAM_DELTA:
                     self.last_delta_frames += 1
                 else:
                     self.last_full_frames += 1
-            entries_sent += sum(len(frame[2]) for frame in frames)
+                entries_sent += len(frame[2])
             if new_state is not None:
                 # a walked ledger; when it is the peer's whole view
                 # the peer is synced and the snapshot stands for it
@@ -888,49 +928,77 @@ class InterestManager:
             return True
         return False
 
-    def _encode_template(self, plane, kind: str, wid: int, keys, pos,
-                         tomb):
-        """One cohort's wire bytes with a zeroed stamp, plus the byte
-        offsets of the epoch/seq hex fields for per-peer patching.
-        Native single-pass encode when the library has the symbol; the
-        object path is byte-identical (pinned by test)."""
-        world = plane._world_names[wid] if 0 <= wid < len(
-            plane._world_names
-        ) else ""
-        placeholder = stamp(kind, 0, 0)
+    def _encode_templates(self, plane, frames) -> list:
+        """The cohort templates of ``[(kind, world, keys, pos, tomb)]``:
+        a frame's wire bytes around a zeroed stamp, ``(head, tail,
+        size)`` with the 17 stamp bytes ``<epoch hex8>:<seq hex8>``
+        between ``head`` and ``tail``. ONE native call for them all
+        when the library has the symbol (entities stamped from
+        fixed-layout records); the object path, frame by frame, is
+        byte-identical (pinned by test)."""
+        names = plane._world_names
+        worlds = [names[frame[1]] if 0 <= frame[1] < len(names) else ""
+                  for frame in frames]
+        params = [_PLACEHOLDER[frame[0]] for frame in frames]
+        counts = [len(frame[2]) for frame in frames]
+        if self.metrics is not None:
+            self.metrics.inc("interest.entries_encoded", sum(counts))
         wire = getattr(plane, "_wire", None)
         if wire is not None and getattr(wire, "can_encode_interest", False):
-            buf = wire.encode_interest_frame(
-                placeholder.encode(), world.encode(),
-                np.ascontiguousarray(keys), pos.astype(np.float64),
-                np.ascontiguousarray(tomb),
+            bounds = np.zeros(len(frames) + 1, np.int64)
+            np.cumsum(counts, out=bounds[1:])
+            bufs, at, recorded = wire.encode_interest_frames(
+                params, [world.encode() for world in worlds], bounds,
+                np.concatenate([frame[2] for frame in frames]),
+                # float64 once for the tick, not a frame
+                np.concatenate([frame[3] for frame in frames],
+                               dtype=np.float64),
+                np.concatenate([frame[4] for frame in frames]),
             )
+            if self.metrics is not None:
+                self.metrics.inc("interest.encode_calls")
+                self.metrics.inc("interest.entries_recorded", recorded)
         else:
-            ents = [
-                Entity(
-                    uuid=uuid_mod.UUID(bytes=key.tobytes()),
-                    position=Vector3(float(p[0]), float(p[1]), float(p[2])),
-                    world_name=world,
-                    flex=TOMBSTONE_FLEX if dead else None,
-                )
-                for key, p, dead in zip(keys, pos, tomb)
-            ]
             from ..protocol import serialize_message
 
-            buf = serialize_message(Message(
-                instruction=Instruction.LOCAL_MESSAGE,
-                parameter=placeholder,
-                sender_uuid=NIL_UUID,
-                world_name=world,
-                entities=ents,
-            ))
-        needle = placeholder.encode()
-        idx = buf.find(needle)
-        if idx < 0:  # unreachable: the stamp is always encoded
-            raise RuntimeError("stamp placeholder missing from frame")
-        e_off = idx + len(kind) + 1
-        s_off = e_off + 9
-        return bytes(buf), e_off, s_off
+            bufs = [
+                serialize_message(Message(
+                    instruction=Instruction.LOCAL_MESSAGE,
+                    parameter=param.decode(),
+                    sender_uuid=NIL_UUID,
+                    world_name=world,
+                    entities=[
+                        Entity(
+                            uuid=uuid_mod.UUID(bytes=key.tobytes()),
+                            position=Vector3(
+                                float(p[0]), float(p[1]), float(p[2])),
+                            world_name=world,
+                            flex=TOMBSTONE_FLEX if dead else None,
+                        )
+                        for key, p, dead in zip(keys, pos, tomb)
+                    ],
+                ))
+                for param, world, (_kind, _wid, keys, pos, tomb)
+                in zip(params, worlds, frames)
+            ]
+            # the stamp is the caller's own bytes: always encoded
+            at = [buf.index(param) for buf, param in zip(bufs, params)]
+        templates = []
+        for frame, buf, param_at in zip(frames, bufs, at):
+            e_off = param_at + len(frame[0]) + 1
+            templates.append((buf[:e_off], buf[e_off + 17:], len(buf)))
+        return templates
+
+    def _encode_template(self, plane, kind: str, wid: int, keys, pos,
+                         tomb):
+        """One cohort's template, a batch of one, as ``(wire bytes with
+        a zeroed stamp, offset of the epoch field, of the seq
+        field)``."""
+        (head, tail, _size), = self._encode_templates(
+            plane, [(kind, wid, keys, pos, tomb)],
+        )
+        return (b"".join((head, b"%08x:%08x" % (0, 0), tail)),
+                len(head), len(head) + 9)
 
     # endregion
 

@@ -1,6 +1,6 @@
 """Columnar entity wire codec (ctypes binding for the PR 11 natives).
 
-Two GIL-releasing siblings of ``wql_encode_queries`` live in
+Three GIL-releasing siblings of ``wql_encode_queries`` live in
 ``native/codec.cpp`` (they need its FlatBuffers reader/writer):
 
 * ``wql_decode_entities`` — batch-decode the ``entities`` lists of a
@@ -13,6 +13,11 @@ Two GIL-releasing siblings of ``wql_encode_queries`` live in
   frame encoding: N ``entity.frame`` LocalMessages sharing one world
   encode in one native pass, byte-identical to ``wql_encode`` of the
   equivalent ``Message``.
+* ``wql_encode_interest_frames`` — a tick's interest-managed frames
+  (``--interest on``: stamped parameter, NIL sender, n entities of one
+  world a frame, tombstones) in ONE native pass over shared columns,
+  entities stamped from fixed-layout records, byte-identical to
+  ``serialize_message`` of each frame's ``Message``.
 
 Symbol-probe discipline matches spatial/native_keys.py: each symbol is
 probed independently so a stale ``.so`` built before PR 11 degrades
@@ -141,15 +146,16 @@ class EntityWire:
                 ctypes.POINTER(_c_u8p), _c_i64p, _c_i64p,
             ]
         self._encode_interest = getattr(
-            lib, "wql_encode_interest_frame", None
+            lib, "wql_encode_interest_frames", None
         )
         if self._encode_interest is not None:
-            self._encode_interest.restype = ctypes.c_int
+            self._encode_interest.restype = ctypes.c_int64
             self._encode_interest.argtypes = [
-                ctypes.c_char_p, ctypes.c_int32,
-                ctypes.c_char_p, ctypes.c_int32,
-                _c_u8p, _c_f64p, _c_u8p, ctypes.c_int64,
-                ctypes.POINTER(_c_u8p), _c_i64p,
+                ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_char_p), _c_i32p,
+                ctypes.POINTER(ctypes.c_char_p), _c_i32p,
+                _c_i64p, _c_u8p, _c_f64p, _c_u8p,
+                ctypes.POINTER(_c_u8p), _c_i64p, _c_i64p, _c_i64p,
             ]
         self._free = lib.wql_buffer_free
         self._free.argtypes = [_c_u8p]
@@ -257,36 +263,64 @@ class EntityWire:
             for o, ln in zip(off.tolist(), lens.tolist())
         ]
 
-    def encode_interest_frame(self, param: bytes, world: bytes,
-                              ent_keys: np.ndarray, pos: np.ndarray,
-                              tomb: np.ndarray) -> bytes:
-        """Encode ONE interest-managed frame (ISSUE 18) natively:
-        stamped parameter + shared world + ``[n,16]u8`` entity keys +
-        ``[n,3]f64`` positions + ``[n]u8`` tombstone flags → wire
-        bytes, byte-identical to ``serialize_message`` of the
-        equivalent Message (the cohort template the manager patches
-        per peer)."""
-        n = len(ent_keys)
+    def encode_interest_frames(self, params: list[bytes],
+                               worlds: list[bytes], bounds: np.ndarray,
+                               ent_keys: np.ndarray, pos: np.ndarray,
+                               tomb: np.ndarray):
+        """Encode a batch of interest-managed frames (ISSUE 18, 44) in
+        ONE native pass. Frame ``f`` carries the stamped parameter
+        ``params[f]``, the world ``worlds[f]`` and the entities
+        ``[bounds[f], bounds[f + 1])`` of three shared columns:
+        ``[N,16]u8`` uuid keys, ``[N,3]f64`` positions, ``[N]u8``
+        tombstone flags. Returns ``(frames, param_at, recorded)``:
+        ``frames[f]`` is a view of frame ``f``'s bytes (one buffer
+        holds the batch), byte-identical to ``serialize_message`` of
+        the equivalent Message whatever its neighbours in the batch,
+        with its parameter's first byte at ``param_at[f]``;
+        ``recorded`` is how many entities the encoder wrote from its
+        fixed-layout records."""
+        n = len(params)
         ek = np.ascontiguousarray(ent_keys, np.uint8)
         p = np.ascontiguousarray(pos, np.float64)
         tb = np.ascontiguousarray(tomb, np.uint8)
+        bd = np.ascontiguousarray(bounds, np.int64)
+        rows = len(tb)
+        if (len(worlds) != n or bd.shape != (n + 1,) or bd[0] < 0
+                or bd[-1] > rows or (np.diff(bd) < 0).any()
+                or ek.shape != (rows, 16) or p.shape != (rows, 3)):
+            raise ValueError("interest frame batch: columns do not fit")
+        plens = np.fromiter(map(len, params), np.int32, count=n)
+        wlens = np.fromiter(map(len, worlds), np.int32, count=n)
+        meta = np.empty((3, n), np.int64)
         out = _c_u8p()
-        out_len = ctypes.c_int64()
         rc = self._encode_interest(
-            param, len(param), world, len(world),
+            n,
+            (ctypes.c_char_p * n)(*params), plens.ctypes.data_as(_c_i32p),
+            (ctypes.c_char_p * n)(*worlds), wlens.ctypes.data_as(_c_i32p),
+            bd.ctypes.data_as(_c_i64p),
             ek.ctypes.data_as(_c_u8p),
             p.ctypes.data_as(_c_f64p),
             tb.ctypes.data_as(_c_u8p),
-            n,
             ctypes.byref(out),
-            ctypes.byref(out_len),
+            meta[0].ctypes.data_as(_c_i64p),
+            meta[1].ctypes.data_as(_c_i64p),
+            meta[2].ctypes.data_as(_c_i64p),
         )
-        if rc != 0:
+        if rc < 0:
             raise RuntimeError(f"native interest encode failed (rc {rc})")
         try:
-            return ctypes.string_at(out, out_len.value)
+            # frames lie in order, frame 0 lowest: one copy of what the
+            # encoder used of its buffer
+            off, size, param_at = meta.tolist()
+            base = off[0] if n else 0
+            blob = memoryview(ctypes.string_at(
+                ctypes.addressof(out.contents) + base,
+                off[-1] + size[-1] - base,
+            ) if n else b"")
         finally:
             self._free(out)
+        return ([blob[o - base:o - base + ln] for o, ln in zip(off, size)],
+                param_at, int(rc))
 
     # endregion
 
